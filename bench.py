@@ -1679,11 +1679,11 @@ def main():
         # exponent recovered by L-BFGS-lite) plus the one-launch
         # grad-of-sweep pin and the GradTelemetry snapshot
         "grad_calibration": grad_cal,
-        # ISSUE-9 rows: hybrid space-parallel weak scaling (fixed work
-        # per PDES rank, paired measurement) and the replica axis over
-        # N jax.distributed processes (bit-equal process slicing)
+        # ISSUE-9 row: hybrid space-parallel weak scaling (fixed work
+        # per PDES rank, paired measurement).  The N-process
+        # distributed_mesh row stays under --ranks: its children need
+        # a device this process already holds
         "hybrid_weak_scaling": bench_hybrid_weak_scaling(max_ranks=4),
-        "distributed_mesh": bench_distributed_mesh(),
         # tpudes.obs compile telemetry: per-engine XLA compile count +
         # wall time over the whole bench process (sweeps must not add
         # compiles — the single-executable property as a metric)

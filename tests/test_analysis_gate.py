@@ -115,6 +115,7 @@ def test_missing_default_roots_is_an_error_not_a_green_gate(tmp_path):
     assert "default roots" in proc.stderr
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: CI's analysis steps run this exact gate
 def test_jaxpr_gate_is_clean_against_baseline():
     """ISSUE-12 acceptance: the trace manifests cover every device
     engine and the JXL pass family reports zero unbaselined findings
@@ -128,6 +129,7 @@ def test_jaxpr_gate_is_clean_against_baseline():
     )
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: CI's analysis steps run this exact gate
 def test_jaxpr_flag_composes_with_select_and_json():
     # --select JXL005 --no-baseline must surface exactly the known
     # egress-donation findings, machine-readably
@@ -173,6 +175,7 @@ def test_sarif_output_is_schema_shaped(tmp_path):
         assert loc["region"]["startColumn"] >= 1
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
 def test_ast_cache_cold_then_warm(tmp_path):
     """The per-file content-hash cache: a warm run re-parses nothing,
     reports full hits, produces identical findings, and is measurably
@@ -341,6 +344,7 @@ def test_engine_serves_and_invalidates_cached_jaxpr_findings(
     assert not (tmp_path / "cache2.json").exists()
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: CI's analysis steps run this exact gate
 def test_jaxpr_warm_cache_analysis_is_subsecond():
     """ISSUE-16 satellite: CI reruns the --jaxpr gate between rounds;
     with the default cache warm it must answer in under a second (no

@@ -169,6 +169,7 @@ def test_corpus_entry_replays_clean(path):
 # --- planted bug: detect -> shrink -> artifact -> replay ------------------
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: CI's fuzz step runs the planted-bug drill
 def test_planted_bug_detected_shrunk_and_replayed(monkeypatch, tmp_path):
     from tpudes.fuzz import replay, run_scenario, shrink_divergence
     from tpudes.fuzz.artifact import (

@@ -5,6 +5,7 @@ lte-test-rlc-am-e2e.cc (AM delivers under loss), lte-test-handover-*
 (X2 handover moves a UE between cells without losing bearers).
 """
 
+import pytest
 
 from tpudes.core import MilliSeconds, Seconds, Simulator
 from tpudes.helper.containers import NodeContainer
@@ -217,6 +218,7 @@ def _two_cell_moving_ue(rlc_mode="am", start_x=220.0, speed=100.0, ttt=160):
     return lte, enb_devs, ue_devs
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
 def test_a3_handover_moves_ue_between_cells():
     lte, enb_devs, ue_devs = _two_cell_moving_ue(rlc_mode="sm")
     assert ue_devs.Get(0).rrc.serving_enb is enb_devs.Get(0)
@@ -234,6 +236,7 @@ def test_a3_handover_moves_ue_between_cells():
     assert c.stats["dl_ok"] > tti * 0.8
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
 def test_handover_is_lossless_for_am_bearers():
     lte, enb_devs, ue_devs = _two_cell_moving_ue(rlc_mode="am")
     bearer = next(iter(ue_devs.Get(0).rrc.bearers.values()))

@@ -82,6 +82,7 @@ def test_interpret_mode_bit_parity_every_scheduler(monkeypatch, sched):
     _assert_same(on, off, sched)
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_step_fn_bit_parity_at_kernel_level():
     """Below the engine: one fused step, both lowerings, same state in,
     bit-identical state out (including the f32 accumulators)."""
@@ -99,6 +100,58 @@ def test_step_fn_bit_parity_at_kernel_level():
                 np.asarray(s_p[k]), np.asarray(s_x[k]), err_msg=k
             )
         s = s_p
+
+
+@pytest.mark.slow  # loads libtpu into the process; the multi-device CI step runs it
+def test_kernel_compiles_for_v5e_through_mosaic(monkeypatch):
+    """The non-interpret ``pallas_call`` — the branch only a TPU runs —
+    lowered and compiled for a v5e by libtpu's compile-only client (no
+    chip needed): the 7 x 210 program's fused step, unbatched and
+    replica-vmapped, plus the dynamic-row form the mobile and traffic
+    runners feed.  A primitive Mosaic cannot lower (jax 0.9.0 has no
+    erf/erfc rule — the BLER tail used to call ``lax.erfc``) fails
+    here, on CPU, instead of on the first chip run."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from tpudes.parallel.lte_sm import SM_DYNAMIC_ROWS
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # noqa: BLE001 - no libtpu on this box
+        pytest.skip(f"no TPU compile-only client: {e}")
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def abstract(v):
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding)
+
+    prog = _prog(n_enb=7, n_ue=210)
+    consts = build_sm_consts(prog)
+    jnp = jax.numpy
+    state = sm_init_state(prog.n_enb, prog.n_ue)
+    coin = jnp.zeros((1, prog.n_ue), jnp.float32)
+    t = sid = jnp.int32(0)
+    dyn = {k: jnp.asarray(consts[k]) for k in SM_DYNAMIC_ROWS}
+    # the builder picks interpret mode from the backend it runs on
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    static = build_sm_step_fn(consts, True)
+    dynamic = build_sm_step_fn(consts, True, dynamic=SM_DYNAMIC_ROWS)
+    batched = jax.vmap(static, in_axes=(0, 0, None, None))
+    stack = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda v: jnp.broadcast_to(v, (4,) + v.shape), tree
+    )
+    for fn, args in (
+        (static, (state, coin, t, sid)),
+        (dynamic, (state, coin, t, sid, dyn)),
+        (batched, (stack(state), stack(coin), t, sid)),
+    ):
+        compiled = (
+            jax.jit(fn)
+            .trace(*jax.tree_util.tree_map(abstract, args))
+            .lower(lowering_platforms=("tpu",))
+            .compile()
+        )
+        assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("bucketing", ["1", "0"])
@@ -208,6 +261,7 @@ def test_bf16_and_f32_share_no_executable(monkeypatch):
 # --- per-stage profile harness ----------------------------------------
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_profile_sm_stages_records_every_stage():
     from tpudes.parallel.kernels_pallas import profile_sm_stages
 

@@ -125,9 +125,6 @@ def test_pss_priority_set_meets_target_then_yields():
 def test_all_schedulers_run_in_the_full_lena_loop():
     """End-to-end: each registered algorithm drives a small lena grid
     for 30 TTIs without error and serves every UE's buffer."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     from tests.test_lte import _build_lena
     from tpudes.core import Seconds, Simulator
     from tpudes.core.world import reset_world
@@ -147,10 +144,6 @@ def test_sm_engine_lowers_every_registered_scheduler():
     r6 closes the gap the right way: every registered FF-MAC scheduler
     now lowers to the traced-id dispatch (tests/test_lte_sm.py pins the
     per-family behavior) while a custom class still refuses loudly."""
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
     from tests.test_lte import _build_lena
     from tpudes.core.world import reset_world
     from tpudes.parallel.lte_sm import lower_lte_sm

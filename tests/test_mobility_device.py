@@ -283,6 +283,7 @@ def _bss_mobile_prog(mobility="const_velocity", speed=1.0, stride=1,
 
 
 class TestBssMobile:
+    @pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
     def test_stride1_bit_identical_to_per_step_recompute(self):
         from tpudes.parallel.replicated import run_replicated_bss
 

@@ -6,6 +6,7 @@ per source (VERDICT r4 #8's 'faster than global SPF repair' pin)."""
 
 import time
 
+import pytest
 
 from tpudes.core import Seconds, Simulator
 from tpudes.helper.applications import UdpEchoClientHelper, UdpEchoServerHelper
@@ -105,6 +106,7 @@ def test_origin_caches_one_bfs_per_destination():
     _reset()
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
 def test_scales_better_than_global_spf_on_big_graph():
     """The VERDICT pin: on a 2000-node graph, nix-vector route setup for
     a few flows (one BFS each) beats global SPF's per-source Dijkstra

@@ -215,6 +215,49 @@ def test_tb_bler_ecr_bf16_budget():
     assert f32[-10:].max() < 1e-12 and bf16[-10:].max() < 1e-12
 
 
+def test_erfc_within_f32_budget_of_math_erfc():
+    """The BLER tail's erfc is built from ops every lowering has (the
+    Pallas-TPU lowering has no erf/erfc in jax 0.9.0), shared by the
+    fused kernel and its XLA twin.  Its absolute error against
+    ``math.erfc`` stays below 2^-21 over the whole waterfall (XLA's own
+    f32 erfc is the same size) — orders of magnitude inside the bf16
+    BLER band this file budgets, and below the decode coin's 2^-23
+    granularity times a handful."""
+    import math
+
+    from tpudes.ops.lte import erfc
+
+    x = np.linspace(-6.0, 10.0, 40001).astype(np.float32)
+    ref = np.array([math.erfc(float(v)) for v in x])
+    got = np.asarray(jax.jit(erfc)(jnp.asarray(x)), np.float64)
+    assert np.abs(got - ref).max() <= 2.0 ** -21
+    # and relative accuracy where a TB actually decodes (BLER > 1e-6)
+    live = ref > 2e-6
+    assert (np.abs(got - ref)[live] / ref[live]).max() <= 2e-6
+    # through the waterfall: device BLER vs the float64 closed form
+    # (z itself is f32 arithmetic: |Δz| ~ 1e-6 at the sweep's edges
+    # times the Gaussian slope ≤ 0.4)
+    from tpudes.ops.lte import BLER_DISPERSION, BLER_TARGET_Q, tb_bler_ecr
+
+    mi = np.linspace(0.3, 0.7, 101).astype(np.float32)
+    dev = np.asarray(
+        tb_bler_ecr(
+            jnp.asarray(mi), jnp.full((101,), 0.5, jnp.float32),
+            jnp.full((101,), 5000.0, jnp.float32),
+        ),
+        np.float64,
+    )
+    sigma = BLER_DISPERSION / math.sqrt(5000.0)
+    host = np.array([
+        0.5 * math.erfc(
+            (float(m) - (0.5 - BLER_TARGET_Q * sigma)) / sigma
+            / math.sqrt(2.0)
+        )
+        for m in mi
+    ])
+    assert np.abs(dev - host).max() <= 5e-6
+
+
 def test_dtype_none_and_f32_identical():
     """dtype=jnp.float32 must be the EXACT legacy arithmetic — the
     casts are no-ops, not a third rounding mode."""

@@ -54,6 +54,7 @@ def test_higher_order_modulation_needs_more_snr():
     assert bpsk > 0.99 and qam64 < 0.05
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
 def test_vmap_over_modes_and_snr_grid():
     snr = 10 ** (jnp.linspace(0, 30, 16) / 10)
     modes = jnp.arange(len(WE.ALL_MODES), dtype=jnp.int32)

@@ -7,6 +7,7 @@ trace equivalence is the determinism oracle."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tpudes.core import GlobalValue, Seconds, Simulator
 from tpudes.parallel import (
@@ -128,6 +129,7 @@ def test_jax_engine_runs_wifi_with_cached_windows():
     assert windows and windows > 0
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_wifi_phy_window_kernel_basics():
     # two close nodes, node 0 transmitting: node 1 decodes; a lone far
     # node below sensitivity does not
@@ -142,6 +144,7 @@ def test_wifi_phy_window_kernel_basics():
     assert float(sinr[0, 1]) > 100  # strong link
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_wifi_phy_window_interference_symmetry():
     # two simultaneous transmitters near one receiver: mutual interference
     # drives SINR to ~0 dB and both frames die at high order modulation
@@ -156,6 +159,7 @@ def test_wifi_phy_window_interference_symmetry():
     assert not bool(ok[0, 1]) and not bool(ok[1, 0])
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_replicated_vmap_axis():
     r, n = 8, 16
     positions, tx, mode, size, keys = make_replica_batch(r, n)
@@ -289,93 +293,3 @@ def test_lte_window_cache_beats_per_event_dispatch():
     assert {"YansWifiChannel", "LteTtiController"} <= kinds, kinds
     del ch, lte
     reset_world()
-
-
-# --- ISSUE-9 satellite: shard_map compat shim, both kwarg spellings -------
-
-
-def test_resolve_shard_map_new_jax_top_level():
-    """jax.shard_map exists -> top-level fn + check_vma spelling."""
-    import types
-
-    from tpudes.parallel.mesh import resolve_shard_map
-
-    def fake_shard_map(f, **kw):  # pragma: no cover - never called
-        return f
-
-    stub = types.SimpleNamespace(shard_map=fake_shard_map)
-    fn, kw = resolve_shard_map(stub)
-    assert fn is fake_shard_map
-    assert kw == {"check_vma": False}
-
-
-def test_resolve_shard_map_experimental_check_vma():
-    """Newer experimental home: signature speaks check_vma."""
-    import types
-
-    from tpudes.parallel.mesh import resolve_shard_map
-
-    def exp_shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                      check_vma=True):  # pragma: no cover
-        return f
-
-    stub = types.SimpleNamespace(
-        __name__="fakejax",
-        experimental=types.SimpleNamespace(
-            shard_map=types.SimpleNamespace(shard_map=exp_shard_map)
-        ),
-    )
-    fn, kw = resolve_shard_map(stub)
-    assert fn is exp_shard_map
-    assert kw == {"check_vma": False}
-
-
-def test_resolve_shard_map_experimental_check_rep():
-    """Older experimental home: the check_rep spelling (previously the
-    `# pragma: no cover` branch) resolves without importing real jax."""
-    import types
-
-    from tpudes.parallel.mesh import resolve_shard_map
-
-    def exp_shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                      check_rep=True):  # pragma: no cover
-        return f
-
-    stub = types.SimpleNamespace(
-        __name__="fakejax",
-        experimental=types.SimpleNamespace(
-            shard_map=types.SimpleNamespace(shard_map=exp_shard_map)
-        ),
-    )
-    fn, kw = resolve_shard_map(stub)
-    assert fn is exp_shard_map
-    assert kw == {"check_rep": False}
-
-
-def test_resolve_shard_map_unintrospectable_signature_defaults_rep():
-    """A C-accelerated callable whose signature cannot be inspected
-    falls back to the conservative check_rep spelling."""
-    import types
-
-    from tpudes.parallel.mesh import resolve_shard_map
-
-    stub = types.SimpleNamespace(
-        __name__="fakejax",
-        experimental=types.SimpleNamespace(
-            shard_map=types.SimpleNamespace(shard_map=len)  # builtin
-        ),
-    )
-    fn, kw = resolve_shard_map(stub)
-    assert fn is len
-    assert kw == {"check_rep": False}
-
-
-def test_resolve_shard_map_real_jax_resolves():
-    """Whatever the installed jax vintage, the shim must resolve to a
-    callable + exactly one replication-check kwarg."""
-    from tpudes.parallel.mesh import resolve_shard_map
-
-    fn, kw = resolve_shard_map()
-    assert callable(fn)
-    assert list(kw.values()) == [False]
-    assert set(kw) <= {"check_vma", "check_rep"}

@@ -1,10 +1,9 @@
 """Multi-process device meshes (ISSUE-9, ROADMAP item 4(a)).
 
 ``jax.distributed``-backed scale-out: N local CPU processes join one
-coordinator (the identical code path is the multi-host TPU path), the
-replica axis splits into contiguous per-process blocks that are
-BIT-equal to the single-launch rows, and the serving layer routes
-coalesced batches across member processes.
+coordinator, the replica axis splits into contiguous per-process
+blocks that are BIT-equal to the single-launch rows, and the serving
+layer routes coalesced batches across member processes.
 """
 
 import numpy as np
@@ -43,9 +42,52 @@ def test_process_mesh_slice_bounds():
 
 
 def test_supports_global_computation_gates_cpu():
-    # the test harness pins the CPU backend; accelerator backends take
-    # the one-computation global-mesh path instead
+    # the test harness pins the CPU backend
     assert supports_global_computation() is False
+
+
+# --- one process per chip (no processes spawned) ---------------------------
+
+
+def test_device_launchers_refuse_at_once_without_a_chip_each(monkeypatch):
+    """Every launcher whose children run device engines raises
+    IMMEDIATELY (no spawn, no wait to the launch timeout) when told the
+    parent already holds an accelerator backend — and, with no chip
+    held, when several unpinned ranks would contend for one.  CPU-pinned
+    members (the configuration CI runs) pass the check."""
+    import time
+
+    from tpudes.chaos.scenario import run_scenario
+    from tpudes.parallel import procmesh
+    from tpudes.parallel.hybrid import run_hybrid
+    from tpudes.parallel.wired import wired_chain
+    from tpudes.serving import ProcessRouter
+
+    prog = wired_chain(n_links=4, n_flows=2, n_slots=100, ranks=2)
+    launches = [
+        lambda: launch_process_mesh(targets.procmesh_devices, 1),
+        lambda: run_hybrid(prog, jax.random.key(0), transport="mpi"),
+        lambda: run_scenario(seed=0, procs=2),
+        lambda: ProcessRouter({1: object()}),
+    ]
+    # the children would inherit an environment that is not CPU-pinned
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(procmesh, "held_accelerator", lambda: "tpu")
+    t0 = time.monotonic()
+    for launch in launches:
+        with pytest.raises(RuntimeError, match="holds its chip"):
+            launch()
+    monkeypatch.setattr(procmesh, "held_accelerator", lambda: None)
+    for launch in launches[1:]:  # the multi-rank ones
+        with pytest.raises(RuntimeError, match="one chip per rank"):
+            launch()
+    assert time.monotonic() - t0 < 5.0, "a refusal must not wait"
+    procmesh.require_one_process_per_chip(
+        "cpu members", 4, env={"JAX_PLATFORMS": "cpu"}
+    )
+    # this (CPU) test process holds no accelerator
+    monkeypatch.undo()
+    assert procmesh.held_accelerator() is None
 
 
 # --- 2-process jax.distributed smoke ---------------------------------------

@@ -9,6 +9,7 @@ is statistical (SURVEY.md §4) on delivered-frame counts.
 
 import jax
 import numpy as np
+import pytest
 from dataclasses import replace
 
 from tpudes.core import Seconds, Simulator
@@ -77,6 +78,7 @@ def test_ht_lowering_fields():
     assert prog.max_mpdus == 64
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
 def test_ht_statistical_parity_moderate_load():
     """At ~70% utilization both engines deliver close to the offered
     load — a tight cross-engine pin of the HT timing + decode path."""
@@ -95,6 +97,7 @@ def test_ht_statistical_parity_moderate_load():
     )
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
 def test_ht_statistical_parity_saturated():
     """Deep saturation: same order of delivered traffic.  The host DES
     has high run-to-run spread here (a collided ADDBA handshake stalls

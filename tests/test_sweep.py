@@ -172,11 +172,13 @@ def test_sweep_unstacking_matches_per_point_launches():
     _sweep_vs_per_point()
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_sweep_unstacking_exact_with_bucketing_disabled(monkeypatch):
     monkeypatch.setenv("TPUDES_BUCKETING", "0")
     _sweep_vs_per_point()
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_sweep_unstacking_exact_on_virtual_mesh():
     from tpudes.parallel.mesh import replica_mesh
 
@@ -188,6 +190,7 @@ def test_sweep_unstacking_exact_on_virtual_mesh():
 # --- async submission ----------------------------------------------------
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_submit_keeps_at_least_two_in_flight_and_bounds_the_window(
     monkeypatch,
 ):
@@ -215,6 +218,7 @@ def test_submit_keeps_at_least_two_in_flight_and_bounds_the_window(
         _assert_point_equal(fut_res, blocking)
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_submit_overflow_retires_oldest_first(monkeypatch):
     from tpudes.parallel.tcp_dumbbell import run_tcp_dumbbell
 

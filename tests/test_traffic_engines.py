@@ -172,6 +172,7 @@ class TestLteSm:
         )
         assert _eq(full, out, LTE_FIELDS)
 
+    @pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
     def test_finite_backlog_bounds_and_chunk_sweep_bit_equal(self):
         from tpudes.parallel.lte_sm import run_lte_sm
 
@@ -269,6 +270,7 @@ class TestDumbbell:
         )
         assert _eq(bulk, out, TCP_FIELDS)
 
+    @pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
     def test_app_limited_flows_and_chunk_variant_sweep(self):
         from tpudes.parallel.tcp_dumbbell import run_tcp_dumbbell
         from tpudes.traffic.host import offered_packets

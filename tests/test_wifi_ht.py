@@ -289,6 +289,7 @@ def test_sequence_counters_are_per_destination():
     assert seqs_b == [1, 2, 3]
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: runs in CI's slow-overflow step
 def test_window_kernel_table_error_model():
     """The synthetic window kernel's LUT option must track the NIST form
     on the same batch within LUT resolution."""

@@ -70,6 +70,25 @@ def test_device_matches_host_des_exactly():
     assert dev["delivered"].sum() > 0
 
 
+def test_obs_carry_never_holds_one_buffer_under_two_leaves():
+    """The carry is donated on accelerators: one device buffer reachable
+    from two carry leaves is "Attempt to donate the same buffer twice"
+    there (the first chip run hit it — ``fm_birth`` WAS ``ready``).
+    XLA:CPU does not donate, so pin the structure instead."""
+    import jax
+
+    from tpudes.parallel.wired import build_wired_advance
+
+    prog = wired_chain(n_links=4, n_flows=2, n_slots=100, jitter_slots=2)
+    init_state, _ = build_wired_advance(prog, 2, obs=True)
+    leaves = [
+        leaf for leaf in jax.tree_util.tree_leaves(init_state(KEY))
+        if leaf.ndim > 0
+    ]
+    pointers = {leaf.unsafe_buffer_pointer() for leaf in leaves}
+    assert len(pointers) == len(leaves)
+
+
 def test_windowed_run_bit_identical_to_single_shot():
     """window_slots cuts the horizon into advance() segments — the
     grant-schedule-indifference the hybrid window protocol relies on."""
@@ -96,6 +115,7 @@ def test_jitter_replicas_differ_and_match_host_per_row():
     assert jit.any()
 
 
+@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
 def test_replica_offset_slices_bit_equal():
     """Process p computing [lo, hi) with the global offset reproduces
     the same rows of one big launch — the multi-process replica

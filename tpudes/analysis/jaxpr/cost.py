@@ -125,7 +125,7 @@ def _internal_peak(jaxpr) -> int:
     (and, recursively, sub-jaxpr internals at their call eqn) enter
     the live set.  A var is live from its defining eqn to its last
     use; outputs that escape the jaxpr stay live to the end."""
-    from jax import core
+    from jax.extend import core
 
     n = len(jaxpr.eqns)
     if n == 0:

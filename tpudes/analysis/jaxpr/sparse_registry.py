@@ -123,7 +123,7 @@ class _Frame:
 
     def __init__(self, jaxpr, outer_eqn=None, outer_frame=None,
                  const_ids=()):
-        from jax import core
+        from jax.extend import core
 
         self.defs = {}
         for eqn in jaxpr.eqns:
@@ -154,7 +154,7 @@ def classify_roots(var, frame) -> set:
     """Terminal-root kinds of the value ``var`` within ``frame``.
     Literal roots are dropped (a literal index is trivially audited by
     shape checking at trace time)."""
-    from jax import core
+    from jax.extend import core
 
     kinds = set()
     stack = [(var, frame)]

@@ -25,10 +25,10 @@ def trace_entries_x64(build):
     reveal an unpinned dtype when the builder itself runs under x64
     semantics — tracing pre-built f32 arrays would hide them."""
     import jax  # noqa: F401  (jax must import before the context)
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     out = []
-    with enable_x64():
+    with enable_x64(True):
         for entry in build():
             out.append((entry, trace_entry(entry)))
     return out
@@ -38,7 +38,7 @@ def _sub_jaxprs(value):
     """Jaxprs nested anywhere in one eqn-param value (while/cond/scan
     bodies, pjit, custom_* rules, pallas_call kernels — any primitive
     that closes over sub-jaxprs, present or future)."""
-    from jax import core
+    from jax.extend import core
 
     out = []
     stack = [value]
@@ -234,7 +234,7 @@ def _diff_walk(jaxpr, live: set) -> None:
     recurse precisely; other sub-jaxpr carriers (scan/while/cond) are
     treated as differentiable pass-through — conservative: a hard op
     hidden inside a loop body is missed, one outside is not."""
-    from jax import core
+    from jax.extend import core
 
     for eqn in jaxpr.eqns:
         in_live = any(

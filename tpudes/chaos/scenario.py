@@ -161,7 +161,9 @@ def run_scenario(seed: int, procs: int = 3,
     ranks are optional (the schedule SIGKILLs one).  Returns per-rank
     results (None for the killed member)."""
     from tpudes.parallel.mpi import LaunchDistributed
+    from tpudes.parallel.procmesh import require_one_process_per_chip
 
+    require_one_process_per_chip("chaos.run_scenario", procs)
     return LaunchDistributed(
         chaos_serving_rank,
         procs,

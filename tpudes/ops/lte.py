@@ -32,7 +32,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as _np
-from jax.scipy.special import erfc
 
 # --- constants (3GPP TS 36.211/36.213 public values) -----------------------
 
@@ -278,6 +277,27 @@ def mi_per_rb(sinr: jax.Array, qm: jax.Array, dtype=None) -> jax.Array:
     x = sinr if dtype is None else sinr.astype(dtype)
     cap = jnp.log2((1.0 + x / SNR_GAP).astype(jnp.float32))
     return jnp.minimum(cap, qm) / qm
+
+
+def erfc(x: jax.Array) -> jax.Array:
+    """Complementary error function from ops every lowering has (abs,
+    mul/add, one divide, one exp, one select): ``lax.erfc`` has no
+    Pallas-TPU (Mosaic) lowering in jax 0.9.0, and the fused TTI kernel
+    and its plain-XLA twin must run ONE definition of the BLER tail.
+
+    Chebyshev fit of ``erfc(|x|) = t·exp(-x² + P(t))``, ``t = 1/(1 +
+    |x|/2)`` (Numerical Recipes ``erfcc``; fractional error < 1.2e-7 in
+    exact arithmetic).  In f32 the absolute error against
+    ``math.erfc`` stays below 2^-21 — the same size as XLA's own f32
+    ``erfc`` — pinned by tests/test_ops_lte_kernels.py."""
+    a = jnp.abs(x)
+    t = 1.0 / (1.0 + 0.5 * a)
+    poly = -1.26551223 + t * (1.00002368 + t * (0.37409196 + t * (
+        0.09678418 + t * (-0.18628806 + t * (0.27886807 + t * (
+            -1.13520398 + t * (1.48851587 + t * (
+                -0.82215223 + t * 0.17087277))))))))
+    tail = t * jnp.exp(poly - a * a)
+    return jnp.where(x >= 0.0, tail, 2.0 - tail)
 
 
 def tb_bler_ecr(

@@ -435,10 +435,8 @@ def trajectory_positions(prog: MobilityProgram, t_grid_us) -> np.ndarray:
     if fn is None:
         pos_fn = build_position_fn(prog)
         # ONE vmapped dispatch for the whole grid (a per-t loop would
-        # pay T dispatches + D2H round trips — seconds at stride=1
-        # horizons, worse over a tunneled accelerator); pinned
-        # bit-equal to the scan's in-loop evaluation by the
-        # device_geom_off tests
+        # pay T dispatches + D2H round trips); pinned bit-equal to the
+        # scan's in-loop evaluation by the device_geom_off tests
         fn = jax.jit(jax.vmap(pos_fn, in_axes=(None, 0)))
         _TRAJ_SAMPLERS[prog.shape_key()] = fn
     return np.asarray(

@@ -794,7 +794,9 @@ def run_hybrid(
     elif transport == "mpi":
         from tpudes.obs.distributed import DistributedTelemetry, wall_now
         from tpudes.parallel.mpi import LaunchDistributed
+        from tpudes.parallel.procmesh import require_one_process_per_chip
 
+        require_one_process_per_chip('run_hybrid(transport="mpi")', size)
         rank_outs = LaunchDistributed(
             _hybrid_rank_main, size,
             args=(prog, key_np, replicas, window_slots),

@@ -37,9 +37,13 @@ with three structural properties the tests pin:
   the SINR chain, MI/BLER budget) and tests/test_lte_sm.py (host
   parity holds under bf16 at the same tolerances).
 
-``TPUDES_PALLAS=0`` is the kill switch: the engine falls back to the
-plain XLA lowering of the same math core (and the runtime cache keys
-the flag, so A/B flips never collide on a stale executable).
+``TPUDES_PALLAS=0`` is the kill switch: the engine takes the plain XLA
+lowering of the same math core (and the runtime cache keys the flag,
+so A/B flips never collide on a stale executable).  Mesh-sharded
+launches take the XLA lowering on every backend — GSPMD cannot
+partition a Mosaic call (``lte_sm._sm_use_pallas``);
+``lte_sm.compiled_step_lowering`` reads which step an executable
+actually holds.
 """
 
 from __future__ import annotations
@@ -457,9 +461,12 @@ def build_sm_step_fn(consts: dict, use_pallas: bool, dynamic: tuple = ()):
         for k, r in zip(keys, refs[nc + ns:]):
             r[...] = new[k]
 
+    # never interpret on a TPU: there the kernel compiles through
+    # Mosaic or the launch fails with the compiler's own error (this
+    # branch is AOT-compiled for a v5e by tests/test_lte_pallas.py)
     interpret = jax.default_backend() != "tpu"
     kwargs = {}
-    if not interpret:  # pragma: no cover - exercised on TPU only
+    if not interpret:
         from jax.experimental.pallas import tpu as pltpu
 
         smem = pl.BlockSpec((1, 1), memory_space=pltpu.SMEM)

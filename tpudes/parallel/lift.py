@@ -233,23 +233,34 @@ def lift(sim_end_s: float):
     raise UnliftableScenarioError("; ".join(reasons))
 
 
-def run_lifted(kind: str, prog, replicas: int, key=None, mesh=None):
+def lifted_key():
+    """The PRNG key a lifted run draws from when none is passed: a pure
+    function of the scenario's ``RngSeed`` / ``RngRun`` globals, so the
+    same script arguments reproduce the same replicas."""
+    import jax
+
+    from tpudes.core.rng import RngSeedManager
+
+    return jax.random.PRNGKey(
+        (RngSeedManager.GetSeed() * 2654435761 + RngSeedManager.GetRun())
+        & 0x7FFFFFFF
+    )
+
+
+def run_lifted(kind: str, prog, replicas: int, key=None, mesh=None,
+               **engine_kwargs):
     """Execute a lifted program on the replica axis.
 
     ``mesh=None`` auto-selects: a 1-axis replica mesh over all local
     devices when more than one is visible and divides ``replicas``.
-    Returns the program's per-replica outcome dict (see
-    run_replicated_bss / run_lte_sm).
+    ``engine_kwargs`` pass through to the engine's ``run_*`` entry
+    (its chunk argument, ``block=False``, …).  Returns the program's
+    per-replica outcome dict (see run_replicated_bss / run_lte_sm).
     """
     import jax
 
     if key is None:
-        from tpudes.core.rng import RngSeedManager
-
-        key = jax.random.PRNGKey(
-            (RngSeedManager.GetSeed() * 2654435761 + RngSeedManager.GetRun())
-            & 0x7FFFFFFF
-        )
+        key = lifted_key()
     if mesh is None:
         import math
 
@@ -272,17 +283,25 @@ def run_lifted(kind: str, prog, replicas: int, key=None, mesh=None):
     if kind == "bss":
         from tpudes.parallel.replicated import run_replicated_bss
 
-        return run_replicated_bss(prog, replicas, key, mesh=mesh)
+        return run_replicated_bss(
+            prog, replicas, key, mesh=mesh, **engine_kwargs
+        )
     if kind == "lte_sm":
         from tpudes.parallel.lte_sm import run_lte_sm
 
-        return run_lte_sm(prog, key, replicas=replicas, mesh=mesh)
+        return run_lte_sm(
+            prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
+        )
     if kind == "dumbbell":
         from tpudes.parallel.tcp_dumbbell import run_tcp_dumbbell
 
-        return run_tcp_dumbbell(prog, key, replicas=replicas, mesh=mesh)
+        return run_tcp_dumbbell(
+            prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
+        )
     if kind == "as_flows":
         from tpudes.parallel.as_flows import run_as_flows
 
-        return run_as_flows(prog, key, replicas=replicas, mesh=mesh)
+        return run_as_flows(
+            prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
+        )
     raise ValueError(f"unknown lifted program kind {kind!r}")

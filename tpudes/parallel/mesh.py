@@ -25,42 +25,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpudes.parallel.kernels import WindowParams
 
-# shard_map's public home moved across jax releases: jax.shard_map
-# (check_vma kwarg) on new jax, jax.experimental.shard_map (check_rep,
-# later also check_vma) before that — resolve once so the window step
-# builds on both.  Factored so the compat test can resolve against
-# stub modules of either vintage (tests/test_parallel.py).
-
-
-def resolve_shard_map(jax_module=None):
-    """Return ``(shard_map, replication-check kwargs)`` for the given
-    jax module (default: the installed one).  Top-level ``jax.shard_map``
-    speaks ``check_vma``; the experimental home is probed for whichever
-    of the two spellings its signature accepts."""
-    import inspect
-
-    jx = jax if jax_module is None else jax_module
-    if hasattr(jx, "shard_map"):
-        return jx.shard_map, {"check_vma": False}
-    try:
-        mod = jx.experimental.shard_map
-    except AttributeError:
-        # the real experimental submodule needs an explicit import
-        import importlib
-
-        mod = importlib.import_module(f"{jx.__name__}.experimental.shard_map")
-    fn = mod.shard_map
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        params = {}
-    if "check_vma" in params:
-        return fn, {"check_vma": False}
-    return fn, {"check_rep": False}
-
-
-_shard_map, _SHARD_MAP_KW = resolve_shard_map()
-
 
 def replica_mesh(n_devices: int | None = None, axis: str = "replica") -> Mesh:
     """1-D mesh over all (or the first n) local devices."""
@@ -89,12 +53,12 @@ def sharded_window_step(mesh: Mesh, params: WindowParams = WindowParams()):
     next_ts, lookahead) -> (ok, sinr, delivered_total, grant)``.
     """
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("replica"), P("replica"), P("replica"), P("replica"),
                   P("replica"), P("replica"), P()),
         out_specs=(P("replica"), P("replica"), P(), P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )
     def step(positions, tx_active, mode_idx, frame_bytes, keys, next_ts, lookahead):
         from tpudes.parallel.kernels import replicated

@@ -1,20 +1,18 @@
 """Multi-process device meshes: ``jax.distributed``-backed scale-out.
 
 ROADMAP item 4(a): the mesh rows used to stop at one host's visible
-devices.  This module joins N **processes** (CPU processes in CI; the
-identical code path is the multi-host TPU path) into one jax
+devices.  This module joins N **CPU processes** into one jax
 distributed runtime so the replica and config axes can shard across
-them:
+them.  It has not been run on more than one chip: the launcher hands
+every rank the same environment, so it cannot pin one chip per rank
+and :func:`require_one_process_per_chip` refuses instead.
 
 - :func:`init_process_mesh` — ``jax.distributed.initialize`` against a
   local coordinator; afterwards ``jax.devices()`` enumerates EVERY
-  process's devices (the global view a multi-host TPU slice gives).
+  process's devices.
 - :func:`global_replica_mesh` — a 1-D mesh over the global device set.
-  On TPU/GPU backends the engines take it straight through their
-  ``mesh=`` argument (``shard_replica_axis`` → GSPMD does the rest —
-  the same code that shards single-host meshes today).  XLA:CPU does
-  **not** implement cross-process computations, so
-  :func:`supports_global_computation` gates that path and CI instead
+  XLA:CPU does **not** implement cross-process computations, so
+  :func:`supports_global_computation` gates that path and CI
   exercises the **process-sliced** contract below.
 - **Process-sliced axes** (:func:`process_slice`): replica/config axes
   split into contiguous per-process blocks.  The engines' randomness is
@@ -45,8 +43,65 @@ __all__ = [
     "init_process_mesh",
     "launch_process_mesh",
     "process_slice",
+    "require_one_process_per_chip",
     "supports_global_computation",
 ]
+
+
+def held_accelerator() -> str | None:
+    """Platform of the non-CPU jax backend THIS process has already
+    initialised, else None.  Never initialises a backend itself — a
+    launcher that asked ``jax.default_backend()`` would take the chip
+    its children need."""
+    import sys
+
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    # jax has no public "is a backend up yet" query; this is the one
+    # jax.distributed.initialize itself checks
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
+def require_one_process_per_chip(what: str, n_device_procs: int,
+                                 env: dict | None = None) -> None:
+    """Raise at once when spawning ``n_device_procs`` processes that
+    each run a device engine would leave one of them without a chip —
+    instead of letting the child fail or hang to the launch timeout.
+
+    A chip belongs to one process: a parent that has initialised a
+    non-CPU backend holds it, and ranks that all inherit the same
+    environment all ask for the same chip(s).  Processes pinned to CPU
+    (``JAX_PLATFORMS=cpu`` in ``env`` or inherited) share the host
+    freely — the only configuration these launchers have been run in.
+    """
+    platforms = (env or {}).get(
+        "JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", "")
+    )
+    if platforms.strip().lower() == "cpu":
+        return
+    held = held_accelerator()
+    if held is not None:
+        raise RuntimeError(
+            f"{what}: this process has already initialised the {held!r} "
+            "jax backend and holds its chip(s); a child process that "
+            "needs the device would fail or hang.  Launch from a process "
+            "that has not touched jax, or pin the children to CPU "
+            "(JAX_PLATFORMS=cpu)."
+        )
+    if n_device_procs > 1:
+        raise RuntimeError(
+            f"{what}: {n_device_procs} processes would initialise the "
+            "default jax backend from one shared environment; nothing "
+            "pins one chip per rank, so on an accelerator host they "
+            "contend for the same chip(s).  Only CPU member processes "
+            "have been run: set JAX_PLATFORMS=cpu."
+        )
 
 
 @dataclass(frozen=True)
@@ -73,10 +128,10 @@ def process_slice(n: int, num_processes: int, process_id: int
 
 
 def supports_global_computation() -> bool:
-    """True when the active backend can run ONE computation over a
-    multi-process mesh (TPU/GPU).  XLA:CPU raises ``Multiprocess
-    computations aren't implemented`` — CI uses the process-sliced
-    contract there instead."""
+    """True when the active backend is not XLA:CPU, which raises
+    ``Multiprocess computations aren't implemented`` for ONE
+    computation over a multi-process mesh — CI uses the process-sliced
+    contract there instead.  (The accelerator side has not been run.)"""
     import jax
 
     return jax.default_backend() != "cpu"
@@ -107,9 +162,8 @@ def init_process_mesh(coordinator_address: str, num_processes: int,
 
 def global_replica_mesh(axis: str = "replica"):
     """1-D mesh over the GLOBAL device set (every member process).  On
-    accelerator backends this drops into the engines' ``mesh=``
-    argument unchanged; on CPU it still constructs (device enumeration
-    works) but executing a computation over it raises — gate with
+    CPU it constructs (device enumeration works) but executing a
+    computation over it raises — gate with
     :func:`supports_global_computation`."""
     import jax
     import numpy as np
@@ -139,9 +193,13 @@ def launch_process_mesh(worker, num_processes: int, args: tuple = (),
     """Run ``worker(pmesh, *args)`` in ``num_processes`` spawned local
     processes sharing one ``jax.distributed`` coordinator plus the
     all-to-all :class:`~tpudes.parallel.mpi.MpiInterface` control
-    pipes; returns the per-process results in rank order."""
+    pipes; returns the per-process results in rank order.  Every rank
+    gets the same ``env``; see :func:`require_one_process_per_chip`."""
     from tpudes.parallel.mpi import LaunchDistributed
 
+    require_one_process_per_chip(
+        "launch_process_mesh", num_processes, env
+    )
     port = _free_port()
     return LaunchDistributed(
         _procmesh_main,

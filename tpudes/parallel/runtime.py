@@ -39,11 +39,13 @@ conventions.  This module is the one runtime they all route through:
   XLA:CPU does not implement donation and warns per call, so the CPU
   backend gets an empty donate list.
 
-- :func:`configure_persistent_cache` — ``TPUDES_CACHE_DIR`` opts into
-  jax's persistent compilation cache, so a *second process* running the
-  same engines skips the XLA compiles entirely (the in-memory runner
-  cache only ever amortized within one process).  Wired lazily on the
-  first runner build; harmless no-op when the env var is unset.
+- :func:`configure_persistent_cache` — arms jax's persistent
+  compilation cache, so a *second process* running the same engines
+  skips the XLA compiles entirely (the in-memory runner cache only ever
+  amortized within one process).  The directory comes from
+  ``JAX_COMPILATION_CACHE_DIR`` when set, else (accelerator backends
+  only) the fixed ``<checkout>/.jax_cache``.  Wired lazily on the
+  first runner build.
 
 - **Async submission** (:meth:`EngineRuntime.submit` /
   :class:`EngineFuture`): every ``run_*`` entry point takes
@@ -295,24 +297,36 @@ def donate_argnums(*argnums: int) -> tuple[int, ...]:
 
 
 def configure_persistent_cache() -> str | None:
-    """Wire ``TPUDES_CACHE_DIR`` into jax's persistent compilation
-    cache so a fresh process reuses the previous process's XLA
-    compiles.  Returns the directory when armed, None otherwise (unset
-    env, or a jax too old to know the knobs — gated, never fatal)."""
-    path = os.environ.get("TPUDES_CACHE_DIR")
-    if not path:
-        return None
+    """Arm jax's persistent compilation cache so a fresh process reuses
+    the previous process's XLA compiles; returns its directory (None
+    when not armed).
+
+    The directory is placed from OUTSIDE: when
+    ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads it and this
+    function sets no directory.  Otherwise an accelerator backend
+    caches at the fixed ``<checkout>/.jax_cache`` (the path is part of
+    jax's cache key, so it never carries a temp name, pid or time),
+    and XLA:CPU stays uncached — its AOT loader logs a machine-feature
+    mismatch on every hit, and CPU is the test backend, which must
+    leave the checkout clean."""
     import jax
 
-    try:
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        if jax.default_backend() == "cpu":
+            return None
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)
+            ))),
+            ".jax_cache",
+        )
         jax.config.update("jax_compilation_cache_dir", path)
-        # cache every engine program: the default thresholds skip
-        # fast-compiling entries, which is exactly the sweep traffic
-        # the engines generate on CPU test backends
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except (AttributeError, ValueError):  # pragma: no cover - old jax
-        return None
+    # cache every engine program: the default thresholds skip
+    # fast-compiling entries, which is exactly the sweep traffic
+    # the engines generate
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return path
 
 
@@ -335,6 +349,12 @@ class EngineFuture:
         self._result = None
         self._done = False
         self._runtime: "EngineRuntime | None" = None
+
+    @property
+    def device_out(self):
+        """The on-device output tree (None once ``result()`` fetched
+        it) — where a caller reads the outputs' shardings."""
+        return self._device_out
 
     def done(self) -> bool:
         """True once the device work has finished (never blocks)."""

@@ -687,7 +687,10 @@ def build_wired_advance(prog: WiredProgram, replicas: int, owned=None,
             # births were folded into fm_tx (exactly-once accounting
             # across event steps AND window boundaries)
             state.update(flow_carry(F, lead=(R,)))
-            state["fm_birth"] = state["ready"]
+            # a COPY: the carry is donated, and one buffer under two
+            # carry leaves is "donate the same buffer twice" on a
+            # backend that donates
+            state["fm_birth"] = jnp.copy(state["ready"])
             state["fm_mark"] = jnp.int32(-1)
         return state
 

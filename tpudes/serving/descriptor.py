@@ -28,8 +28,8 @@ engine knows which of its fields are traced) returning a
 - ``warm(n_points)`` — compile the executable a batch of ``n_points``
   would use, against a minimal-horizon copy of the program (horizons
   are traced operands, so the minimal-horizon compile IS the real
-  one); the server's warm pool calls this at start, where
-  ``TPUDES_CACHE_DIR`` turns it into a persistent-cache disk hit.
+  one); the server's warm pool calls this at start, where a warm
+  persistent compile cache turns it into a disk hit.
 - ``solo`` — True marks a study the sweep equality guarantee cannot
   cover (e.g. a dumbbell program whose ``ecn`` disagrees with the
   variants' ``REQUIRES_ECN`` flags — sweep points derive ECN from the

@@ -165,6 +165,12 @@ class ProcessRouter:
     on them."""
 
     def __init__(self, conns: dict, member_timeout_s: float = 60.0):
+        if conns:
+            # the serving process launches block 0 itself and every
+            # member launches its own block: all of them need a device
+            from tpudes.parallel.procmesh import require_one_process_per_chip
+
+            require_one_process_per_chip("ProcessRouter", 1 + len(conns))
         self._members = [(m, c) for m, c in sorted(conns.items())]
         self.member_timeout_s = float(member_timeout_s)
         self.routed_batches = 0

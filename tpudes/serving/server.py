@@ -52,8 +52,8 @@ Operating behavior:
   single studies ride the engines' plain entry points and share the
   common non-sweep executables.
 - **Warm pool** (:meth:`warm`): pre-compiles the hot engine/bucket set
-  at server start — with ``TPUDES_CACHE_DIR`` armed these become
-  persistent-cache disk hits instead of fresh XLA compiles.
+  at server start — against a warm persistent compile cache these
+  are disk hits instead of fresh XLA compiles.
 - **Metrics**: every decision is recorded in
   :class:`tpudes.obs.serving.ServingTelemetry` (queue depth, coalesce
   rate, batch occupancy, launch latency p50/p99, failure/recovery
@@ -299,8 +299,8 @@ class StudyServer:
         serving path.  ``studies`` holds :class:`StudyDescriptor`
         objects or dicts of :meth:`submit_study` keyword arguments.
         Returns the number of warm launches performed (each a
-        minimal-horizon run — a persistent-cache disk hit when
-        ``TPUDES_CACHE_DIR`` is set)."""
+        minimal-horizon run — a disk hit when the persistent compile
+        cache is warm)."""
         top = _pow2(max(1, self.max_batch))
         if buckets is None:
             buckets = tuple(1 << i for i in range(top.bit_length()))
