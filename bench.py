@@ -51,11 +51,8 @@ the MULTICHIP harness both use that path).  The timed repetitions ride
 pipelined steady-state throughput, not launch+sync round trips.
 
 ISSUE-6 rows:
-  - the `lte` row now carries `ttis_per_wall_s` + the pallas/precision
-    flags, and `lte_kernel_profile` reports per-stage device timings of
-    the fused TTI kernel chain (coin PRNG, retx admission, scheduler
-    dispatch, SINR/CQI/HARQ decode, fused step) with the dominating
-    stage named — the measurement behind the Pallas fusion tentpole.
+  - the `lte` row carries `ttis_per_wall_s` + the pallas/precision
+    flags.
 
 ISSUE-5 rows:
   - sweep_vectorized: the 8-point LTE scheduler sweep and 8-point TCP
@@ -686,46 +683,6 @@ def bench_grad_calibration(smoke: bool = False):
         "grad_sweep_losses": [round(float(v), 6) for v in sweep["loss"]],
         "grad_telemetry": GradTelemetry.snapshot(),
     }
-
-
-def bench_lte_kernel_profile():
-    """ISSUE-6 tentpole row: per-stage device timing of the fused LTE
-    TTI kernel chain at the bench scenario's scale, so the dominating
-    stage is measured, not asserted.  Each stage is the MARGINAL cost
-    of adding it to the compiled chain (delta between consecutive
-    prefix programs, clamped at 0 — see profile_sm_stages); the
-    fused_step row is the ground-truth per-TTI total, and the implied
-    TTI throughput is the ceiling the scan overhead eats into."""
-    import jax
-
-    from tpudes.core.world import reset_world
-    from tpudes.obs.device import KernelProfile
-    from tpudes.parallel.kernels_pallas import profile_sm_stages
-    from tpudes.parallel.lte_sm import lower_lte_sm
-    from tpudes.scenarios import build_lena
-
-    reset_world()
-    lte, _ = build_lena(LTE_ENBS, LTE_UES_PER_CELL)
-    prog = lower_lte_sm(lte, LTE_SIM_S)
-    reset_world()
-
-    stages = profile_sm_stages(
-        prog, replicas=LTE_REPLICAS, iters=30, key=jax.random.PRNGKey(0)
-    )
-    walls = {k: v for k, v in stages.items() if isinstance(v, float)}
-    fused = walls["fused_step"]
-    dominating = max(
-        (k for k in walls if k != "fused_step"), key=lambda k: walls[k]
-    )
-    return dict(
-        stage_us={k: round(v * 1e6, 1) for k, v in walls.items()},
-        dominating_stage=dominating,
-        # per-launch ceiling: R replicas advance one TTI per fused call
-        ttis_per_wall_s_fused=round(LTE_REPLICAS / fused, 1),
-        pallas=stages["pallas"],
-        precision=stages["precision"],
-        obs_kernel_profile=KernelProfile.snapshot().get("lte_sm", {}),
-    )
 
 
 def bench_lte_sched_sweep():
@@ -1608,7 +1565,6 @@ def main():
     traffic_burst = bench_traffic_burst()
     lte = bench_lte()
     lte_mobility = bench_lte_mobility()
-    lte_profile = bench_lte_kernel_profile()
     lte_sweep = bench_lte_sched_sweep()
     tcp = bench_tcp()
     tcp_sweep = bench_tcp_variant_sweep()
@@ -1654,10 +1610,6 @@ def main():
         # workload sweep with its launch/compile/demux pins, and the
         # workload telemetry naming which models ran
         "traffic_burst": r3(traffic_burst),
-        # ISSUE-6: per-stage timing of the fused TTI kernel chain — the
-        # row that says WHERE the LTE budget goes (dominating stage,
-        # fusion ratio, per-launch TTI ceiling)
-        "lte_kernel_profile": lte_profile,
         "lte_sched_sweep": r3(lte_sweep),
         "tcp": r3(tcp),
         "tcp_variant_sweep": r3(tcp_sweep),

@@ -12,8 +12,6 @@
   launch (the CI multi-device smoke rides this), stays within the
   engine-level throughput budget of the f32 mode, and holds the same
   HARQ conservation laws.
-- **Per-stage profile harness**: profile_sm_stages times every stage
-  of the chain and records to obs.KernelProfile.
 - **lower_lte_sm horizon warning**: the compile-amortization boundary
   (COMPILE_AMORTIZE_TTIS) warns below the line, not at it.
 """
@@ -25,7 +23,7 @@ import jax
 import numpy as np
 import pytest
 
-from tpudes.obs.device import CompileTelemetry, KernelProfile
+from tpudes.obs.device import CompileTelemetry
 from tpudes.parallel.kernels_pallas import (
     build_sm_consts,
     build_sm_step_fn,
@@ -256,32 +254,6 @@ def test_bf16_and_f32_share_no_executable(monkeypatch):
                 dataclasses.replace(prog, precision=precision), KEY
             )
     assert RUNTIME.size("lte_sm") == 4
-
-
-# --- per-stage profile harness ----------------------------------------
-
-
-@pytest.mark.slow  # ISSUE-21 tier-1 budget: the multi-device CI step runs the full file
-def test_profile_sm_stages_records_every_stage():
-    from tpudes.parallel.kernels_pallas import profile_sm_stages
-
-    KernelProfile.reset()
-    out = profile_sm_stages(_prog(), replicas=2, iters=2, warm_ttis=4)
-    expect = {
-        "coin_prng", "admit_retx", "sched_dispatch", "sinr_cqi_harq",
-        "harq_update", "fused_step",
-    }
-    assert expect <= set(out)
-    # measured programs are strictly positive; the marginal deltas are
-    # clamped at 0 (separately compiled prefixes can fuse differently)
-    assert out["coin_prng"] > 0 and out["admit_retx"] > 0
-    assert out["fused_step"] > 0
-    assert all(out[k] >= 0.0 for k in expect)
-    assert out["pallas"] == pallas_enabled()
-    recorded = KernelProfile.stages("lte_sm")
-    assert expect <= set(recorded)
-    snap = KernelProfile.snapshot()["lte_sm"]
-    assert snap["fused_step"]["batch"] == 2
 
 
 # --- the lower_lte_sm compile-amortization warning ---------------------

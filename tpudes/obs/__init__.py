@@ -28,8 +28,25 @@ One GlobalValue knob, ``TpudesObs`` (bound like every engine knob:
 
 With the knob at 0 the engines run their pre-obs code paths unchanged
 (pinned by the overhead test in tests/test_obs.py).
+
+Independent of the knob and always on, the simulator's own launch path
+is measured (:mod:`tpudes.obs.spans`, about 2 us a span, a bounded ring
+in memory): host spans ``lifted_run`` > ``lift``, ``launch`` >
+``launch.runner`` / ``.operands`` / ``.enqueue``, and ``result.wait`` /
+``.fetch`` / ``.unpack`` tied to their launch by its id; and
+:meth:`CompileTelemetry.xla_events` keeps every XLA trace, compile and
+persistent-cache hit with its time.  They differ from ``TpudesObs=1``,
+which adds carry buffers and so compiles a DIFFERENT device program
+that measures the simulated network: these measure the simulator and
+change no executable.  To read them: ``tpudes.obs.spans.snapshot()``
+in process, or any ``jax.profiler`` trace, where the spans are the
+host-plane events ``tpudes:*`` and every engine's loop carries the
+scopes ``tpudes.<engine>.step`` / ``.cond`` (LTE also
+``tpudes.lte_sm.rng`` and the kernel ``tpudes_lte_sm_tti``) on its
+device operations.
 """
 
+from tpudes.obs import spans
 from tpudes.obs.device import (
     ChunkStream,
     CompileTelemetry,
@@ -93,6 +110,7 @@ __all__ = [
     "host_reference_stats",
     "reduce_flow_stats",
     "serialize_flow_stats_xml",
+    "spans",
     "validate_chrome_trace",
     "validate_distributed_metrics",
     "validate_flowmon_xml",

@@ -12,7 +12,10 @@ the part that must be process-global:
   *metric*: a 9-scheduler LTE sweep must show ``compiles == 1``.
   Recording is always on (a dict update per compile is free); the
   registry deliberately survives ``reset_world`` because XLA's compile
-  caches do too.
+  caches do too.  Beside the per-engine counts it keeps every XLA
+  trace/compile/cache-hit of the process with its time
+  (:meth:`CompileTelemetry.xla_events`, fed by ``jax.monitoring``), so
+  "which function recompiled, and when" has an answer.
 - :func:`device_metrics_enabled` — the engines consult this at
   lowering/build time; the extra carry buffers exist only when the
   ``TpudesObs`` knob is up, so a disabled run compiles the exact
@@ -26,6 +29,7 @@ the part that must be process-global:
 
 from __future__ import annotations
 
+import collections
 import time
 from contextlib import contextmanager
 
@@ -38,10 +42,54 @@ def device_metrics_enabled() -> bool:
     return enabled()
 
 
+#: the jax.monitoring duration events kept by `CompileTelemetry.listen`:
+#: a trace of a jitted function, a backend compile (it also fires when
+#: the persistent cache answers), and that cache's hit
+XLA_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/backend_compile_duration",
+    "/jax/compilation_cache/cache_retrieval_time_sec",
+)
+
+
 class CompileTelemetry:
-    """Process-wide per-engine compile counters."""
+    """Process-wide per-engine compile counters, and every XLA compile
+    of the process with its time."""
 
     _entries: dict[str, dict] = {}
+    _xla: collections.deque = collections.deque(maxlen=1 << 12)
+    _listening = False
+
+    @classmethod
+    def listen(cls) -> None:
+        """Register (once) the ``jax.monitoring`` listener behind
+        :meth:`xla_events`; the runtime calls it at its first runner
+        lookup, next to ``configure_persistent_cache``."""
+        if cls._listening:
+            return
+        import jax.monitoring
+
+        def on_duration(event: str, seconds: float, **kwargs) -> None:
+            if event in XLA_EVENTS:
+                cls._xla.append((
+                    time.perf_counter(), event, float(seconds),
+                    kwargs.get("fun_name"),
+                ))
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        cls._listening = True
+
+    @classmethod
+    def xla_events(cls, since: float | None = None) -> list[tuple]:
+        """``(perf_counter at the event's end, event, seconds,
+        fun_name)`` of the last 4096 :data:`XLA_EVENTS`, oldest first;
+        with ``since``, those at or after that ``perf_counter`` time.
+        Unlike :meth:`record` this sees EVERY program jax builds, the
+        eager ``jnp`` calls of the launch path included (each is a
+        ``jit`` of its own), and says which function it was."""
+        return [
+            e for e in list(cls._xla) if since is None or e[0] >= since
+        ]
 
     @classmethod
     def record(cls, engine: str, wall_s: float) -> None:
@@ -80,48 +128,6 @@ class CompileTelemetry:
         t0 = time.monotonic()
         yield
         cls.record(engine, time.monotonic() - t0)
-
-
-class KernelProfile:
-    """Per-stage device-kernel timings (the ISSUE-6 measurement seam).
-
-    The engines' profiling harnesses (e.g.
-    :func:`tpudes.parallel.kernels_pallas.profile_sm_stages`) record
-    the median wall time of each stage of a fused kernel chain here, so
-    "the win is measured, not asserted": bench's ``lte_kernel_profile``
-    row and any interactive session read the same registry.  Like
-    :class:`CompileTelemetry`, the registry survives ``reset_world``
-    (it describes executables, not simulation state)."""
-
-    _entries: dict[str, dict[str, dict]] = {}
-
-    @classmethod
-    def record(
-        cls, engine: str, stage: str, wall_s: float, batch: int
-    ) -> None:
-        cls._entries.setdefault(engine, {})[stage] = {
-            "wall_s": float(wall_s),
-            "batch": int(batch),
-        }
-
-    @classmethod
-    def stages(cls, engine: str) -> dict[str, dict]:
-        return dict(cls._entries.get(engine, {}))
-
-    @classmethod
-    def snapshot(cls) -> dict[str, dict]:
-        return {
-            engine: {
-                stage: {"wall_us": round(e["wall_s"] * 1e6, 1),
-                        "batch": e["batch"]}
-                for stage, e in stages.items()
-            }
-            for engine, stages in sorted(cls._entries.items())
-        }
-
-    @classmethod
-    def reset(cls) -> None:
-        cls._entries.clear()
 
 
 class ChunkStream:

@@ -31,9 +31,6 @@ _LAZY = {
     # import it from tpudes.parallel.kernels directly
     "wifi_phy_window": ("tpudes.parallel.kernels", "wifi_phy_window"),
     "pallas_enabled": ("tpudes.parallel.kernels_pallas", "pallas_enabled"),
-    "profile_sm_stages": (
-        "tpudes.parallel.kernels_pallas", "profile_sm_stages",
-    ),
     "RUNTIME": ("tpudes.parallel.runtime", "RUNTIME"),
     "EngineFuture": ("tpudes.parallel.runtime", "EngineFuture"),
     "EngineRuntime": ("tpudes.parallel.runtime", "EngineRuntime"),

@@ -457,6 +457,8 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
     exactly as :func:`run_as_flows` jits it — factored out so the trace
     manifest (:func:`trace_manifest`) abstractly traces the same
     program the runner cache compiles."""
+    from tpudes.parallel.runtime import scoped_while_loop
+
     TRAFFIC = prog.traffic is not None
     if TRAFFIC:
         from tpudes.traffic.device import avg_mult
@@ -500,8 +502,8 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
             lf2, lg2, util2 = _fluid_round(prog, path, hs, rate, cap, lf)
             return i + 1, lf2, lg2, util2
 
-        i, lfrac, lg, util = jax.lax.while_loop(
-            lambda c: c[0] < rounds_end, body, carry
+        i, lfrac, lg, util = scoped_while_loop(
+            "as_flows", lambda c: c[0] < rounds_end, body, carry
         )
 
         dl = _fluid_delay(prog, path, hs, util, cap, dly)
@@ -681,15 +683,16 @@ def run_as_flows(
     :class:`~tpudes.parallel.runtime.EngineFuture`.
     """
     from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
+    from tpudes.obs.spans import span
     from tpudes.parallel.checkpoint import checkpoint_ctx
     from tpudes.parallel.runtime import (
         RUNTIME,
         EngineFuture,
         bucket_replicas,
         chunk_bounds,
-        donate_argnums,
         drive_chunks,
         finalize_with_flush,
+        jit_advance,
         shard_replica_axis,
         stack_axis,
         unstack_points,
@@ -698,47 +701,54 @@ def run_as_flows(
     r_pad = bucket_replicas(replicas, mesh)
     n_cfg = None if rate_scale is None else len(rate_scale)
     obs = device_metrics_enabled()
+
+    def build():
+        return jit_advance(
+            "as_flows",
+            build_as_run(prog, r_pad, n_cfg=n_cfg, obs=obs, mesh=mesh),
+        )
+
     # prog.sim_s is deliberately ABSENT (see as_prog_key).  mesh IS
     # present: device_spf shards its tables via the mesh closure,
     # unlike the engines whose sharding flows from inputs
-    ck = as_prog_key(prog) + (r_pad, mesh, n_cfg, obs)
+    run, compiling = RUNTIME.runner(
+        "as_flows",
+        lambda: as_prog_key(prog) + (r_pad, mesh, n_cfg, obs),
+        build,
+    )
 
-    def build():
-        return jax.jit(
-            build_as_run(prog, r_pad, n_cfg=n_cfg, obs=obs, mesh=mesh),
-            donate_argnums=donate_argnums(0),
+    with span("launch.operands"):
+        # per-replica jitter draws keyed by fold_in(key, r): replica
+        # r's z-row is independent of the padded axis size, so
+        # bucketing is exact
+        z = shard_replica_axis(
+            _as_replica_draws(prog, key, r_pad), mesh, r_pad, 0
+        )
+        scale = (
+            jnp.float32(1.0) if n_cfg is None
+            else jnp.asarray([float(v) for v in rate_scale], jnp.float32)
+        )
+        E2 = 2 * prog.edges.shape[0]
+        F = len(prog.src)
+        carry = (
+            jnp.int32(0),
+            jnp.zeros((r_pad, E2 + 1), jnp.float32),
+            jnp.zeros((r_pad, F), jnp.float32),
+            jnp.zeros((r_pad, E2), jnp.float32),
+        )
+        carry = stack_axis(carry, n_cfg)
+        carry = shard_replica_axis(
+            carry, mesh, r_pad, 0 if n_cfg is None else 1
         )
 
-    run, compiling = RUNTIME.runner("as_flows", ck, build)
-
-    # per-replica jitter draws keyed by fold_in(key, r): replica r's
-    # z-row is independent of the padded axis size, so bucketing is exact
-    z = shard_replica_axis(
-        _as_replica_draws(prog, key, r_pad), mesh, r_pad, 0
-    )
-    scale = (
-        jnp.float32(1.0) if n_cfg is None
-        else jnp.asarray([float(v) for v in rate_scale], jnp.float32)
-    )
-    E2 = 2 * prog.edges.shape[0]
-    F = len(prog.src)
-    carry = (
-        jnp.int32(0),
-        jnp.zeros((r_pad, E2 + 1), jnp.float32),
-        jnp.zeros((r_pad, F), jnp.float32),
-        jnp.zeros((r_pad, E2), jnp.float32),
-    )
-    carry = stack_axis(carry, n_cfg)
-    carry = shard_replica_axis(carry, mesh, r_pad, 0 if n_cfg is None else 1)
-
-    # workload operands (traced; None = the constant-rate path).  The
-    # horizon the fluid multiplier averages over is a traced operand
-    # too — sim_s stays out of the cache key even with traffic on
-    tr = None if prog.traffic is None else prog.traffic.operands()
-    horizon_us = (
-        None if prog.traffic is None
-        else jnp.int32(min(int(prog.sim_s * 1e6), 2**30 - 1))
-    )
+        # workload operands (traced; None = the constant-rate path).  The
+        # horizon the fluid multiplier averages over is a traced operand
+        # too — sim_s stays out of the cache key even with traffic on
+        tr = None if prog.traffic is None else prog.traffic.operands()
+        horizon_us = (
+            None if prog.traffic is None
+            else jnp.int32(min(int(prog.sim_s * 1e6), 2**30 - 1))
+        )
 
     with CompileTelemetry.timed("as_flows", compiling):
         def launch(c, bound):

@@ -90,30 +90,34 @@ class JaxSimulatorImpl(DefaultSimulatorImpl):
             )
             return False
         sim_end_s = self._scheduled_stop_ts / 1e9
+        from tpudes.obs.spans import span
         from tpudes.parallel.lift import (
             UnliftableScenarioError,
             lift,
             run_lifted,
         )
 
-        try:
-            kind, prog, commit = lift(sim_end_s)
-        except UnliftableScenarioError as e:
-            import warnings
+        # one script study on the device, lift() call to results set:
+        # the root span of `lift`, `launch`, `launch.*` and `result.*`
+        with span("lifted_run", replicas=replicas):
+            try:
+                kind, prog, commit = lift(sim_end_s)
+            except UnliftableScenarioError as e:
+                import warnings
 
-            warnings.warn(
-                f"JaxReplicas={replicas} requested but no lowering can "
-                f"represent this object graph ({e}); falling back to the "
-                f"windowed scalar engine",
-                stacklevel=2,
+                warnings.warn(
+                    f"JaxReplicas={replicas} requested but no lowering "
+                    f"can represent this object graph ({e}); falling "
+                    f"back to the windowed scalar engine",
+                    stacklevel=2,
+                )
+                return False
+            out = run_lifted(kind, prog, replicas)
+            commit()  # only a *successful* device run disarms the host path
+            self.replicated_result = dict(
+                kind=kind, replicas=replicas, out=out, sim_end_s=sim_end_s,
+                program=prog,
             )
-            return False
-        out = run_lifted(kind, prog, replicas)
-        commit()  # only a *successful* device run disarms the host path
-        self.replicated_result = dict(
-            kind=kind, replicas=replicas, out=out, sim_end_s=sim_end_s,
-            program=prog,
-        )
         self.current_ts = self._scheduled_stop_ts
         return True
 

@@ -199,6 +199,9 @@ def build_sm_consts(prog) -> dict:
     )
 
 
+#: the Pallas TTI kernel's stable name (``pl.pallas_call(name=...)``)
+SM_KERNEL_NAME = "tpudes_lte_sm_tti"
+
 #: carry layout of the fused step: (key, shape-suffix, dtype).  Per-UE
 #: state rides (1, U) lane rows, the RR pointer (E, 1) sublane columns.
 SM_STATE = (
@@ -477,8 +480,11 @@ def build_sm_step_fn(consts: dict, use_pallas: bool, dynamic: tuple = ()):
             out_specs=tuple(vmem for _ in keys),
         )
 
+    # the name is the kernel's in every profile (a device-trace reader
+    # finds the TTI by it, not by what the compiler calls the call site)
     call = pl.pallas_call(
-        kernel, out_shape=out_shape, interpret=interpret, **kwargs
+        kernel, out_shape=out_shape, interpret=interpret,
+        name=SM_KERNEL_NAME, **kwargs
     )
 
     def step(s, coin, t, sid, dyn=None):
@@ -493,126 +499,3 @@ def build_sm_step_fn(consts: dict, use_pallas: bool, dynamic: tuple = ()):
         return dict(zip(keys, out))
 
     return step
-
-
-# --------------------------------------------------------------------------
-# per-stage device timing harness (the bench `lte_kernel_profile` row)
-# --------------------------------------------------------------------------
-
-
-def profile_sm_stages(
-    prog, replicas: int = 64, iters: int = 50, warm_ttis: int = 32, key=None
-):
-    """Per-stage timing of the fused chain on the current backend — the
-    measurement that says WHERE the TTI budget goes instead of
-    asserting it.
-
-    Runs ``warm_ttis`` real TTIs first so the profiled state is a
-    steady-state HARQ mix, then medians ``iters`` timed calls over the
-    ``(R, 1, U)`` batch of each PREFIX program of the chain (admit;
-    admit+dispatch; admit+dispatch+decode; the full fused step) and
-    reports each stage as the DELTA between consecutive prefixes — the
-    marginal cost of adding that stage to the compiled program.  Deltas
-    are clamped at 0 (separately compiled prefixes can fuse
-    differently, so a delta is an attribution estimate, not an exact
-    decomposition; the ``fused_step`` row is the ground truth total).
-    The coin PRNG is timed independently — it runs outside the kernel.
-    Results are recorded to :class:`tpudes.obs.device.KernelProfile`
-    and returned as ``{stage: seconds}``.
-    """
-    import statistics
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from tpudes.obs.device import KernelProfile
-
-    if key is None:
-        key = jax.random.PRNGKey(0)
-    consts = build_sm_consts(prog)
-    cj = _as_jnp_consts(consts)
-    E, U = consts["E"], consts["U"]
-    sid = jnp.int32(0)
-    use_pallas = pallas_enabled()
-    fused = build_sm_step_fn(consts, use_pallas)
-
-    def one_step(s, k, t):
-        coin = jax.random.uniform(jax.random.fold_in(k, t), (U,))[None, :]
-        return fused(s, coin, t, sid)
-
-    # steady-state warm-up: a real HARQ mix, not the all-zeros state
-    @jax.jit
-    def warm(s, k):
-        def body(t, s):
-            return one_step(s, k, t)
-
-        return jax.lax.fori_loop(0, warm_ttis, body, s)
-
-    keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
-        jnp.arange(replicas)
-    )
-    state = jax.vmap(lambda k: warm(sm_init_state(E, U), k))(keys)
-    coin = jax.vmap(
-        lambda k: jax.random.uniform(k, (U,))[None, :]
-    )(keys)
-    t = jnp.int32(warm_ttis)
-
-    def stage_coin(s, k):
-        return jax.random.uniform(jax.random.fold_in(k, t), (U,))[None, :]
-
-    def prefix_admit(s, c):
-        return sm_admit_retx(cj, s, t)
-
-    def prefix_dispatch(s, c):
-        pend, _, rem_c = sm_admit_retx(cj, s, t)
-        return sm_dispatch(cj, s, pend, rem_c, sid)
-
-    def prefix_decode(s, c):
-        pend, retx_fit, rem_c = sm_admit_retx(cj, s, t)
-        d = sm_dispatch(cj, s, pend, rem_c, sid)
-        return sm_decode(cj, s, retx_fit, d["new_nrbg"], d["is_winner"], c)
-
-    def full_step(s, c):
-        return fused(s, c, t, sid)
-
-    programs = {
-        "coin_prng": (jax.jit(jax.vmap(stage_coin)), keys),
-        "admit_retx": (jax.jit(jax.vmap(prefix_admit)), coin),
-        "sched_dispatch": (jax.jit(jax.vmap(prefix_dispatch)), coin),
-        "sinr_cqi_harq": (jax.jit(jax.vmap(prefix_decode)), coin),
-        "fused_step": (jax.jit(jax.vmap(full_step)), coin),
-    }
-    prefix_walls = {}
-    for name, (jitted, arg) in programs.items():
-        fn = lambda: jitted(state, arg)  # noqa: E731
-        jax.block_until_ready(fn())  # compile
-        walls = []
-        for _ in range(iters):
-            # never-traced wall-clock harness around a blocked device
-            # call — the one legitimate time.* shape on the device path
-            t0 = time.monotonic()  # tpudes: ignore[JP001]
-            jax.block_until_ready(fn())
-            walls.append(time.monotonic() - t0)  # tpudes: ignore[JP001]
-        prefix_walls[name] = statistics.median(walls)
-    # prefix walls → per-stage marginal costs (see docstring)
-    out = {
-        "coin_prng": prefix_walls["coin_prng"],
-        "admit_retx": prefix_walls["admit_retx"],
-        "sched_dispatch": max(
-            prefix_walls["sched_dispatch"] - prefix_walls["admit_retx"], 0.0
-        ),
-        "sinr_cqi_harq": max(
-            prefix_walls["sinr_cqi_harq"] - prefix_walls["sched_dispatch"],
-            0.0,
-        ),
-        "harq_update": max(
-            prefix_walls["fused_step"] - prefix_walls["sinr_cqi_harq"], 0.0
-        ),
-        "fused_step": prefix_walls["fused_step"],
-    }
-    for name, wall in out.items():
-        KernelProfile.record("lte_sm", name, wall, replicas)
-    out["pallas"] = use_pallas
-    out["precision"] = prog.precision
-    return out

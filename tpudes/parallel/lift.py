@@ -22,6 +22,7 @@ simulator-impl.{h,cc}'s ObjectFactory (SURVEY.md §1, §7 step 7).
 
 from __future__ import annotations
 
+from tpudes.obs.spans import OWN, span
 from tpudes.parallel.replicated import UnliftableScenarioError
 
 
@@ -225,11 +226,12 @@ def lift(sim_end_s: float):
     (it disarms any host-side duplicate of the scenario) — or raises
     UnliftableScenarioError with every reason collected."""
     reasons = []
-    for discover in LOWERINGS:
-        try:
-            return discover(sim_end_s)
-        except UnliftableScenarioError as e:
-            reasons.append(f"{discover.__name__}: {e}")
+    with span("lift"):
+        for discover in LOWERINGS:
+            try:
+                return discover(sim_end_s)
+            except UnliftableScenarioError as e:
+                reasons.append(f"{discover.__name__}: {e}")
     raise UnliftableScenarioError("; ".join(reasons))
 
 
@@ -257,51 +259,59 @@ def run_lifted(kind: str, prog, replicas: int, key=None, mesh=None,
     (its chunk argument, ``block=False``, …).  Returns the program's
     per-replica outcome dict (see run_replicated_bss / run_lte_sm).
     """
-    import jax
+    # `launch`: from here until the EngineFuture exists.  The future's
+    # constructor closes it (so a blocking caller's wait and fetch are
+    # never inside); the finally only matters when an engine raised
+    # before it made one
+    launch = span("launch", OWN, kind=kind, replicas=int(replicas)).open()
+    try:
+        import jax
 
-    if key is None:
-        key = lifted_key()
-    if mesh is None:
-        import math
+        if key is None:
+            key = lifted_key()
+        if mesh is None:
+            import math
 
-        n_dev = len(jax.devices())
-        n_use = math.gcd(replicas, n_dev)
-        if n_use > 1:
-            from tpudes.parallel.mesh import replica_mesh
+            n_dev = len(jax.devices())
+            n_use = math.gcd(replicas, n_dev)
+            if n_use > 1:
+                from tpudes.parallel.mesh import replica_mesh
 
-            mesh = replica_mesh(n_use)
-        if 1 < n_use < n_dev or (n_use == 1 < n_dev and replicas > 1):
-            import warnings
+                mesh = replica_mesh(n_use)
+            if 1 < n_use < n_dev or (n_use == 1 < n_dev and replicas > 1):
+                import warnings
 
-            warnings.warn(
-                f"JaxReplicas={replicas} is not divisible by the "
-                f"{n_dev} visible devices; running on {n_use} — "
-                f"pick a multiple of {n_dev} to use the whole mesh",
-                RuntimeWarning,
-                stacklevel=2,
+                warnings.warn(
+                    f"JaxReplicas={replicas} is not divisible by the "
+                    f"{n_dev} visible devices; running on {n_use} — "
+                    f"pick a multiple of {n_dev} to use the whole mesh",
+                    RuntimeWarning,
+                    stacklevel=2,
+                )
+        if kind == "bss":
+            from tpudes.parallel.replicated import run_replicated_bss
+
+            return run_replicated_bss(
+                prog, replicas, key, mesh=mesh, **engine_kwargs
             )
-    if kind == "bss":
-        from tpudes.parallel.replicated import run_replicated_bss
+        if kind == "lte_sm":
+            from tpudes.parallel.lte_sm import run_lte_sm
 
-        return run_replicated_bss(
-            prog, replicas, key, mesh=mesh, **engine_kwargs
-        )
-    if kind == "lte_sm":
-        from tpudes.parallel.lte_sm import run_lte_sm
+            return run_lte_sm(
+                prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
+            )
+        if kind == "dumbbell":
+            from tpudes.parallel.tcp_dumbbell import run_tcp_dumbbell
 
-        return run_lte_sm(
-            prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
-        )
-    if kind == "dumbbell":
-        from tpudes.parallel.tcp_dumbbell import run_tcp_dumbbell
+            return run_tcp_dumbbell(
+                prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
+            )
+        if kind == "as_flows":
+            from tpudes.parallel.as_flows import run_as_flows
 
-        return run_tcp_dumbbell(
-            prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
-        )
-    if kind == "as_flows":
-        from tpudes.parallel.as_flows import run_as_flows
-
-        return run_as_flows(
-            prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
-        )
-    raise ValueError(f"unknown lifted program kind {kind!r}")
+            return run_as_flows(
+                prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
+            )
+        raise ValueError(f"unknown lifted program kind {kind!r}")
+    finally:
+        launch.close()

@@ -58,6 +58,7 @@ import numpy as np
 
 from tpudes.fuzz.envelope import FuzzEnvelope
 from tpudes.models.lte.scheduler import SCHEDULERS
+from tpudes.obs.spans import span
 from tpudes.parallel.kernels_pallas import (
     SM_PRECISIONS,
     SM_SCHED_IDS,
@@ -66,6 +67,7 @@ from tpudes.parallel.kernels_pallas import (
     pallas_enabled,
     sm_init_state,
 )
+from tpudes.parallel.runtime import jit_advance, scoped_while_loop
 
 
 class UnliftableLteScenarioError(ValueError):
@@ -394,6 +396,12 @@ def _lift_lte_mobility(ctrl, n_ttis: int, geom_stride: int):
     return mobility, pathloss_desc
 
 
+#: device name of the per-TTI ``fold_in`` + uniform HARQ coin draw, the
+#: one part of the TTI outside the fused kernel (inside
+#: ``tpudes.lte_sm.step``, see ``runtime.scoped_while_loop``)
+RNG_SCOPE = "tpudes.lte_sm.rng"
+
+
 def build_sm_step(prog: LteSmProgram, use_pallas: bool | None = None):
     """Returns ``(consts, init_state, step_fn)`` for the per-TTI scan
     body (single replica; vmapped by run_lte_sm).
@@ -420,7 +428,8 @@ def build_sm_step(prog: LteSmProgram, use_pallas: bool | None = None):
         t, key = xs
         # coin dtype pinned f32: ambient x64 must not widen the HARQ
         # stream (JXL002)
-        coin = jax.random.uniform(key, (U,), jnp.float32)[None, :]
+        with jax.named_scope(RNG_SCOPE):
+            coin = jax.random.uniform(key, (U,), jnp.float32)[None, :]
         return fused(s, coin, t, sid)
 
     consts = dict(
@@ -675,7 +684,8 @@ def build_sm_advance(prog: LteSmProgram, r_pad: int | None = None,
         # run re-entering at t>0 draws the same per-TTI streams
         def body(c):
             t, s = c
-            kt = jax.random.fold_in(k, t)
+            with jax.named_scope(RNG_SCOPE):
+                kt = jax.random.fold_in(k, t)
             if not obs:
                 return t + 1, step_fn(s, (t, kt), sid)
             # the fused TTI core builds exact-key state dicts, so the
@@ -738,8 +748,8 @@ def build_sm_advance(prog: LteSmProgram, r_pad: int | None = None,
             )
             return t + 1, dict(s2, **fm)
 
-        t, s = jax.lax.while_loop(
-            lambda c: c[0] < t_end, body, carry
+        t, s = scoped_while_loop(
+            "lte_sm", lambda c: c[0] < t_end, body, carry
         )
         # small per-chunk summaries (fresh buffers, NOT aliased to
         # the carry — the next chunk donates the carry away); only
@@ -802,9 +812,10 @@ def build_sm_mobile_advance(prog: LteSmProgram, r_pad: int | None = None,
             dyn = {k: g2[k] for k in SM_DYNAMIC_ROWS}
 
             def one(s_r, k_r, sid_s):
-                coin = jax.random.uniform(
-                    jax.random.fold_in(k_r, t), (U,), jnp.float32
-                )[None, :]
+                with jax.named_scope(RNG_SCOPE):
+                    coin = jax.random.uniform(
+                        jax.random.fold_in(k_r, t), (U,), jnp.float32
+                    )[None, :]
                 return fused(s_r, coin, t, sid_s, dyn)
 
             if r_pad is None:
@@ -817,8 +828,8 @@ def build_sm_mobile_advance(prog: LteSmProgram, r_pad: int | None = None,
                 s2 = jax.vmap(step, in_axes=(0, None, 0))(s, keys, sid)
             return t + 1, g2, s2
 
-        t, g, s = jax.lax.while_loop(
-            lambda c: c[0] < t_end, body, carry
+        t, g, s = scoped_while_loop(
+            "lte_sm", lambda c: c[0] < t_end, body, carry
         )
         metrics = (
             dict(
@@ -886,9 +897,10 @@ def build_sm_traffic_advance(prog: LteSmProgram, r_pad: int | None = None,
                     * (bl > 0.0).astype(elig0.dtype)
                 }
                 prev_lo, prev_hi = core["rx_lo"], core["rx_hi"]
-                coin = jax.random.uniform(
-                    jax.random.fold_in(k_r, t), (U,), jnp.float32
-                )[None, :]
+                with jax.named_scope(RNG_SCOPE):
+                    coin = jax.random.uniform(
+                        jax.random.fold_in(k_r, t), (U,), jnp.float32
+                    )[None, :]
                 s2 = fused(core, coin, t, sid_s, dyn)
                 served = (
                     (s2["rx_hi"] - prev_hi).astype(jnp.float32)
@@ -923,8 +935,8 @@ def build_sm_traffic_advance(prog: LteSmProgram, r_pad: int | None = None,
                 s2 = jax.vmap(step, in_axes=(0, None, 0))(s, keys, sid)
             return t + 1, s2
 
-        t, s = jax.lax.while_loop(
-            lambda c: c[0] < t_end, body, carry
+        t, s = scoped_while_loop(
+            "lte_sm", lambda c: c[0] < t_end, body, carry
         )
         metrics = (
             dict(
@@ -972,7 +984,6 @@ def _run_lte_sm_traffic(
         EngineFuture,
         bucket_replicas,
         chunk_bounds,
-        donate_argnums,
         drive_chunks,
         finalize_with_flush,
         replica_keys,
@@ -993,30 +1004,32 @@ def _run_lte_sm_traffic(
             prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
             use_pallas=use_pallas,
         )
-        return init_carry, jax.jit(fn, donate_argnums=donate_argnums(0))
+        return init_carry, jit_advance("lte_sm", fn)
 
     (init_carry, fn), compiling = RUNTIME.runner(
         "lte_sm",
-        _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas) + ("traffic",),
+        lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas)
+        + ("traffic",),
         build,
     )
 
     sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
     sids = [SM_SCHED_IDS[s] for s in sched_names]
-    sid = (
-        jnp.int32(sids[0]) if n_cfg is None
-        else jnp.asarray(sids, jnp.int32)
-    )
-    keys = key if r_pad is None else shard_replica_axis(
-        replica_keys(key, r_pad), mesh, r_pad, 0
-    )
-    tr = prog.traffic.operands()
-    tr_key = jax.random.fold_in(key, TRAFFIC_KEY_TAG)
+    with span("launch.operands"):
+        sid = (
+            jnp.int32(sids[0]) if n_cfg is None
+            else jnp.asarray(sids, jnp.int32)
+        )
+        keys = key if r_pad is None else shard_replica_axis(
+            replica_keys(key, r_pad), mesh, r_pad, 0
+        )
+        tr = prog.traffic.operands()
+        tr_key = jax.random.fold_in(key, TRAFFIC_KEY_TAG)
 
-    t0, s0 = init_carry()
-    s0 = stack_axis(stack_axis(s0, r_pad), n_cfg)
-    s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
-    carry = (t0, s0)
+        t0, s0 = init_carry()
+        s0 = stack_axis(stack_axis(s0, r_pad), n_cfg)
+        s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
+        carry = (t0, s0)
 
     ckpt = checkpoint_ctx(
         checkpoint, engine="lte_sm", key=key, replicas=replicas,
@@ -1153,7 +1166,6 @@ def _run_lte_sm_mobile(
         EngineFuture,
         bucket_replicas,
         chunk_bounds,
-        donate_argnums,
         drive_chunks,
         finalize_with_flush,
         replica_keys,
@@ -1176,43 +1188,45 @@ def _run_lte_sm_mobile(
             prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
             use_pallas=use_pallas,
         )
-        return init_carry, jax.jit(fn, donate_argnums=donate_argnums(0))
+        return init_carry, jit_advance("lte_sm", fn)
 
     (init_carry, fn), compiling = RUNTIME.runner(
         "lte_sm",
-        _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas)
+        lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas)
         + ("mobile", dg_on, k_ref),
         build,
     )
 
     sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
     sids = [SM_SCHED_IDS[s] for s in sched_names]
-    sid = (
-        jnp.int32(sids[0]) if n_cfg is None
-        else jnp.asarray(sids, jnp.int32)
-    )
-    keys = key if r_pad is None else shard_replica_axis(
-        replica_keys(key, r_pad), mesh, r_pad, 0
-    )
-    mob_ops = prog.mobility.operands()
-    pos_table = None
-    if k_ref is not None:
-        # host-materialized refresh schedule (the per-window fresh
-        # operands of the legacy path) through the SAME position kernel
-        from tpudes.ops.mobility import trajectory_positions
-
-        pos_table = jnp.asarray(
-            trajectory_positions(
-                prog.mobility,
-                [t * 1000 for t in range(0, prog.n_ttis, stride)],
-            ),
-            jnp.float32,
+    with span("launch.operands"):
+        sid = (
+            jnp.int32(sids[0]) if n_cfg is None
+            else jnp.asarray(sids, jnp.int32)
         )
+        keys = key if r_pad is None else shard_replica_axis(
+            replica_keys(key, r_pad), mesh, r_pad, 0
+        )
+        mob_ops = prog.mobility.operands()
+        pos_table = None
+        if k_ref is not None:
+            # host-materialized refresh schedule (the per-window fresh
+            # operands of the legacy path) through the SAME position
+            # kernel
+            from tpudes.ops.mobility import trajectory_positions
 
-    t0, g0, s0 = init_carry()
-    s0 = stack_axis(stack_axis(s0, r_pad), n_cfg)
-    s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
-    carry = (t0, g0, s0)
+            pos_table = jnp.asarray(
+                trajectory_positions(
+                    prog.mobility,
+                    [t * 1000 for t in range(0, prog.n_ttis, stride)],
+                ),
+                jnp.float32,
+            )
+
+        t0, g0, s0 = init_carry()
+        s0 = stack_axis(stack_axis(s0, r_pad), n_cfg)
+        s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
+        carry = (t0, g0, s0)
 
     from tpudes.parallel.checkpoint import checkpoint_ctx
 
@@ -1301,7 +1315,6 @@ def _sm_launch(prog: LteSmProgram, key, replicas, mesh, schedulers):
     from tpudes.parallel.runtime import (
         RUNTIME,
         bucket_replicas,
-        donate_argnums,
         replica_keys,
         shard_replica_axis,
         stack_axis,
@@ -1317,30 +1330,33 @@ def _sm_launch(prog: LteSmProgram, key, replicas, mesh, schedulers):
             prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
             use_pallas=use_pallas,
         )
-        return consts, init_state, jax.jit(
-            fn, donate_argnums=donate_argnums(0)
-        )
+        return consts, init_state, jit_advance("lte_sm", fn)
 
     (consts, init_state, fn), compiling = RUNTIME.runner(
-        "lte_sm", _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas), build
+        "lte_sm",
+        lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas),
+        build,
     )
 
     sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
     sids = [SM_SCHED_IDS[s] for s in sched_names]
-    sid = (
-        jnp.int32(sids[0]) if n_cfg is None
-        else jnp.asarray(sids, jnp.int32)
-    )
-    if r_pad is None:
-        keys = key
-    else:
-        keys = shard_replica_axis(replica_keys(key, r_pad), mesh, r_pad, 0)
-    carry = (jnp.int32(0), init_state())
-    carry = stack_axis(carry, r_pad)
-    carry = stack_axis(carry, n_cfg)
-    carry = shard_replica_axis(
-        carry, mesh, r_pad, 0 if n_cfg is None else 1
-    )
+    with span("launch.operands"):
+        sid = (
+            jnp.int32(sids[0]) if n_cfg is None
+            else jnp.asarray(sids, jnp.int32)
+        )
+        if r_pad is None:
+            keys = key
+        else:
+            keys = shard_replica_axis(
+                replica_keys(key, r_pad), mesh, r_pad, 0
+            )
+        carry = (jnp.int32(0), init_state())
+        carry = stack_axis(carry, r_pad)
+        carry = stack_axis(carry, n_cfg)
+        carry = shard_replica_axis(
+            carry, mesh, r_pad, 0 if n_cfg is None else 1
+        )
     return SimpleNamespace(
         consts=consts, fn=fn, carry=carry, keys=keys, sid=sid, sids=sids,
         r_pad=r_pad, n_cfg=n_cfg, obs=obs, compiling=compiling,

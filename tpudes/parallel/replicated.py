@@ -1149,6 +1149,8 @@ def build_bss_advance(prog: "BssProgram", replicas: int, obs: bool = False,
     classic horizon sweep — while ``"traffic"`` vmaps (state, traffic
     operands): an 8-point WORKLOAD sweep (mixed cbr/mmpp/onoff/trace
     points sharing one traffic shape key) is one (C, R, …) launch."""
+    from tpudes.parallel.runtime import scoped_while_loop
+
     init_state, pending, step_fn = build_bss_step(
         prog, replicas, obs=obs, geom_per_step=geom_per_step
     )
@@ -1164,7 +1166,8 @@ def build_bss_advance(prog: "BssProgram", replicas: int, obs: bool = False,
                 s["step"] < max_steps, jnp.any(pending(s, sim_end))
             )
 
-        out = jax.lax.while_loop(
+        out = scoped_while_loop(
+            "bss",
             cond,
             lambda st: step_fn(st, k, sim_end, geom, tr, tr_keys),
             s,
@@ -1204,7 +1207,7 @@ def build_bss_advance(prog: "BssProgram", replicas: int, obs: bool = False,
 
 
 def _compiled_bss_runner(
-    prog_key, prog, replicas, mesh, obs=False, n_cfg=None,
+    prog, replicas, mesh, obs=False, n_cfg=None,
     geom_per_step=False, sweep: str = "horizon",
 ):
     """Jitted runner via the shared :data:`~tpudes.parallel.runtime.RUNTIME`
@@ -1223,7 +1226,7 @@ def _compiled_bss_runner(
     ``compiled_new`` tells the caller this call populated the cache (the
     compile-telemetry trigger), so the cache key is derived in exactly
     one place."""
-    from tpudes.parallel.runtime import RUNTIME, donate_argnums
+    from tpudes.parallel.runtime import RUNTIME, jit_advance
 
     del mesh
 
@@ -1234,13 +1237,13 @@ def _compiled_bss_runner(
             prog, replicas, obs=obs, n_cfg=n_cfg,
             geom_per_step=geom_per_step, sweep=sweep,
         )
-        run = jax.jit(fn, donate_argnums=donate_argnums(0))
+        run = jit_advance("bss", fn)
         return init_state, pending, run
 
     (init_state, pending, run), compiled_new = RUNTIME.runner(
         "bss",
-        (prog_key, replicas, obs, n_cfg, mobile, geom_per_step,
-         sweep if n_cfg is not None else None),
+        lambda: (_prog_cache_key(prog), replicas, obs, n_cfg, mobile,
+                 geom_per_step, sweep if n_cfg is not None else None),
         build,
     )
     return init_state, pending, run, compiled_new
@@ -1394,6 +1397,7 @@ def run_replicated_bss(
     import dataclasses
 
     from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
+    from tpudes.obs.spans import span
     from tpudes.parallel.checkpoint import checkpoint_ctx
     from tpudes.parallel.runtime import (
         EngineFuture,
@@ -1441,40 +1445,41 @@ def run_replicated_bss(
     # iterations the padding may cause cannot corrupt real replicas)
     r_pad = bucket_replicas(replicas, mesh)
     init_state, pending, run, compiling = _compiled_bss_runner(
-        _prog_cache_key(prog), prog, r_pad, mesh, obs=obs, n_cfg=n_cfg,
+        prog, r_pad, mesh, obs=obs, n_cfg=n_cfg,
         geom_per_step=geom_per_step, sweep=sweep,
     )
 
-    # mobility/traffic params ride as TRACED operands (None for the
-    # legacy paths); the cache key above carries only shapes
-    geom = (
-        None if prog.mobility is None
-        else dict(
-            stride=jnp.int32(max(1, int(prog.geom_stride))),
-            **prog.mobility.operands(),
-        )
-    )
-    if traffic_sweep is not None:
-        from tpudes.traffic.device import stack_traffic_operands
-
-        if prog.traffic is None or any(
-            tp.shape_key() != prog.traffic.shape_key()
-            for tp in traffic_sweep
-        ):
-            raise ValueError(
-                "a workload sweep needs prog.traffic set and every "
-                "point sharing its traffic shape key (one executable "
-                "serves the sweep; pad tables to a common capacity)"
+    with span("launch.operands"):
+        # mobility/traffic params ride as TRACED operands (None for the
+        # legacy paths); the cache key above carries only shapes
+        geom = (
+            None if prog.mobility is None
+            else dict(
+                stride=jnp.int32(max(1, int(prog.geom_stride))),
+                **prog.mobility.operands(),
             )
-        tr = stack_traffic_operands(traffic_sweep)
-    else:
-        tr = None if prog.traffic is None else prog.traffic.operands()
-    sim_end = (
-        jnp.int32(ends[0]) if n_cfg is None or sweep == "traffic"
-        else jnp.asarray(ends, jnp.int32)
-    )
-    s0 = stack_axis(init_state(), n_cfg)
-    s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
+        )
+        if traffic_sweep is not None:
+            from tpudes.traffic.device import stack_traffic_operands
+
+            if prog.traffic is None or any(
+                tp.shape_key() != prog.traffic.shape_key()
+                for tp in traffic_sweep
+            ):
+                raise ValueError(
+                    "a workload sweep needs prog.traffic set and every "
+                    "point sharing its traffic shape key (one executable "
+                    "serves the sweep; pad tables to a common capacity)"
+                )
+            tr = stack_traffic_operands(traffic_sweep)
+        else:
+            tr = None if prog.traffic is None else prog.traffic.operands()
+        sim_end = (
+            jnp.int32(ends[0]) if n_cfg is None or sweep == "traffic"
+            else jnp.asarray(ends, jnp.int32)
+        )
+        s0 = stack_axis(init_state(), n_cfg)
+        s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
 
     with CompileTelemetry.timed("bss", compiling):
         def launch(carry, bound):

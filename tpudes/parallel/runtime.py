@@ -70,12 +70,24 @@ conventions.  This module is the one runtime they all route through:
   Results are bit-identical to a single-shot run because every step's
   randomness is ``fold_in(key, t)`` — pure in t, indifferent to where
   the segment boundaries fall.
+
+- **Launch-path spans and device names**: the shared halves of the
+  launch are timed here, always on (:mod:`tpudes.obs.spans`):
+  ``launch.runner`` in :meth:`EngineRuntime.runner`,
+  ``launch.enqueue`` in :func:`drive_chunks`, ``result.wait`` /
+  ``.fetch`` / ``.unpack`` in :class:`EngineFuture`, whose constructor
+  also ends the ``launch`` span ``run_lifted`` opened (each ``run_*``
+  adds ``launch.operands`` around its own carry set-up).
+  :func:`scoped_while_loop` gives every engine's outermost loop the
+  stable device names ``tpudes.<engine>.step`` / ``.cond``.
 """
 
 from __future__ import annotations
 
 import os
 from collections import OrderedDict
+
+from tpudes.obs import spans
 
 __all__ = [
     "RUNTIME",
@@ -89,8 +101,10 @@ __all__ = [
     "drive_chunks",
     "finalize_with_flush",
     "inflight_window",
+    "jit_advance",
     "pow2_bucket",
     "replica_keys",
+    "scoped_while_loop",
     "shard_replica_axis",
     "stack_axis",
     "unstack_points",
@@ -204,15 +218,20 @@ def drive_chunks(engine: str, bounds, carry, launch, obs: bool,
             start = bounds.index(done_bound) + 1
     stream = obs and len(bounds) > 1
     prev = None
-    for bound in bounds[start:]:
-        carry, metrics = launch(carry, bound)
-        RUNTIME.record_launch(engine)
-        if checkpoint is not None:
-            checkpoint.ckpt.save(checkpoint, bound, bounds, carry)
-        if stream:
-            if prev is not None:
-                ChunkStream.record(engine, prev[0], jax.device_get(prev[1]))
-            prev = (bound, metrics)
+    with spans.span(
+        "launch.enqueue", engine=engine, chunks=len(bounds) - start
+    ):
+        for bound in bounds[start:]:
+            carry, metrics = launch(carry, bound)
+            RUNTIME.record_launch(engine)
+            if checkpoint is not None:
+                checkpoint.ckpt.save(checkpoint, bound, bounds, carry)
+            if stream:
+                if prev is not None:
+                    ChunkStream.record(
+                        engine, prev[0], jax.device_get(prev[1])
+                    )
+                prev = (bound, metrics)
     if not (stream and prev is not None):
         return carry, None
 
@@ -296,6 +315,47 @@ def donate_argnums(*argnums: int) -> tuple[int, ...]:
     return argnums if jax.default_backend() != "cpu" else ()
 
 
+def scoped_while_loop(engine: str, cond, body, init):
+    """``lax.while_loop`` whose ``body`` and ``cond`` trace under the
+    stable device names ``tpudes.<engine>.step`` / ``.cond``
+    (``jax.named_scope``): every operation of the loop carries them in
+    its metadata, so a profile reads the engine step by OUR name
+    whatever the compiler calls the fusions.  Every engine's outermost
+    loop, the one a step time divides, goes through here.  Scopes
+    change metadata only; results are bit-identical."""
+    import jax
+
+    def scoped(part, fn):
+        def inner(c):
+            with jax.named_scope(f"tpudes.{engine}.{part}"):
+                return fn(c)
+
+        return inner
+
+    return jax.lax.while_loop(scoped("cond", cond), scoped("step", body), init)
+
+
+def jit_advance(engine: str, fn):
+    """The engines' one way to jit an advance function: the carry
+    (argument 0) donated on accelerators, and the program NAMED
+    ``tpudes_<engine>_advance``.  The name is what a profile's ``XLA
+    Modules`` line shows (every engine's used to read ``jit_advance``),
+    and it is part of jax's persistent-cache key, which ignores
+    operation metadata: without it an executable cached before
+    :func:`scoped_while_loop` existed is served again with its old,
+    scope-less operation names (seen on the chip, PERF.md PR 25)."""
+    import functools
+
+    import jax
+
+    @functools.wraps(fn)
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = f"tpudes_{engine}_advance"
+    return jax.jit(named, donate_argnums=donate_argnums(0))
+
+
 def configure_persistent_cache() -> str | None:
     """Arm jax's persistent compilation cache so a fresh process reuses
     the previous process's XLA compiles; returns its directory (None
@@ -339,8 +399,8 @@ class EngineFuture:
     future is created; ``result()`` performs the deferred D2H transfer
     and unpack exactly once."""
 
-    __slots__ = ("engine", "_device_out", "_finalize", "_result", "_done",
-                 "_runtime")
+    __slots__ = ("engine", "launch_id", "_device_out", "_finalize",
+                 "_result", "_done", "_runtime")
 
     def __init__(self, engine: str, device_out, finalize):
         self.engine = engine
@@ -349,6 +409,14 @@ class EngineFuture:
         self._result = None
         self._done = False
         self._runtime: "EngineRuntime | None" = None
+        # the future's existence ends the `launch` span that run_lifted
+        # opened, so a blocking caller's wait and fetch stay outside
+        # it; the id ties result.* to their launch from any thread
+        launch = spans.current()
+        self.launch_id = None
+        if launch is not None and launch.name == "launch":
+            launch.close()
+            self.launch_id = launch.id
 
     @property
     def device_out(self):
@@ -373,7 +441,8 @@ class EngineFuture:
         if not self._done:
             import jax
 
-            jax.block_until_ready(self._device_out)
+            with spans.span("result.wait", self.launch_id):
+                jax.block_until_ready(self._device_out)
         return self
 
     def result(self):
@@ -386,8 +455,21 @@ class EngineFuture:
             import jax
 
             try:
-                host = jax.device_get(self._device_out)
-                self._result = self._finalize(host)
+                # start the D2H copies BEFORE the wait, as device_get
+                # alone would: the transfer then follows the compute
+                # with no host round trip between them (waiting first
+                # cost 0.3-0.5 ms a launch on a v5e).  result.fetch is
+                # therefore two spans a launch: starting the copies,
+                # and what is left of the transfer after the wait
+                with spans.span("result.fetch", self.launch_id):
+                    for leaf in jax.tree_util.tree_leaves(self._device_out):
+                        if hasattr(leaf, "copy_to_host_async"):
+                            leaf.copy_to_host_async()
+                self.block()
+                with spans.span("result.fetch", self.launch_id):
+                    host = jax.device_get(self._device_out)
+                with spans.span("result.unpack", self.launch_id):
+                    self._result = self._finalize(host)
             finally:
                 if self._runtime is not None:
                     self._runtime._retire(self)
@@ -416,25 +498,36 @@ class EngineRuntime:
         self.max_in_flight = 0
         self._launches: dict[str, int] = {}
 
-    def runner(self, engine: str, key: tuple, build):
+    def runner(self, engine: str, key, build):
         """Return ``(value, compiled_new)``: the cached runner for
         ``(engine, *key)``, building (and recording a miss) when absent.
-        ``compiled_new`` is the engines' CompileTelemetry trigger."""
+        ``compiled_new`` is the engines' CompileTelemetry trigger.
+        ``key`` is the tuple or a thunk that makes it: the engines pass
+        a thunk, so that the ``launch.runner`` span times the key's
+        construction (``tobytes()`` of every table) with the lookup."""
         if not self._cache_wired:
+            from tpudes.obs.device import CompileTelemetry
+
             configure_persistent_cache()
+            CompileTelemetry.listen()
             self._cache_wired = True
-        full = (engine, *key)
-        hit = self._runners.get(full)
-        if hit is not None:
-            self._runners.move_to_end(full)  # true LRU: hot entries survive
-            self.hits += 1
-            return hit, False
-        self.misses += 1
-        value = build()
-        self._runners[full] = value
-        while len(self._runners) > self.capacity:
-            self._runners.popitem(last=False)
-        return value, True
+        with spans.span("launch.runner", engine=engine) as sp:
+            if callable(key):
+                key = key()
+            full = (engine, *key)
+            hit = self._runners.get(full)
+            sp.args["hit"] = hit is not None
+            if hit is not None:
+                # true LRU: hot entries survive
+                self._runners.move_to_end(full)
+                self.hits += 1
+                return hit, False
+            self.misses += 1
+            value = build()
+            self._runners[full] = value
+            while len(self._runners) > self.capacity:
+                self._runners.popitem(last=False)
+            return value, True
 
     def size(self, engine: str | None = None) -> int:
         """Resident runner count, optionally for one engine."""

@@ -1183,6 +1183,8 @@ def build_dumbbell_advance(prog: DumbbellProgram, r_pad: int,
     operand tables instead (ISSUE-15: the BSS ``traffic_sweep`` seam
     mirrored — var/ecn are shared across points, the (C, …) traffic
     tables fan out)."""
+    from tpudes.parallel.runtime import scoped_while_loop
+
     init_state, step_fn = build_dumbbell_step(prog, r_pad, obs=obs)
 
     def advance(carry, key, var, ecn, t_end, tr=None):
@@ -1196,8 +1198,8 @@ def build_dumbbell_advance(prog: DumbbellProgram, r_pad: int,
             )
             return t + 1, s
 
-        t, s = jax.lax.while_loop(
-            lambda c: c[0] < t_end, body, carry
+        t, s = scoped_while_loop(
+            "dumbbell", lambda c: c[0] < t_end, body, carry
         )
         # chunk summaries only under TpudesObs (obs is in the
         # cache key): a disabled run compiles the pre-obs program
@@ -1425,15 +1427,16 @@ def run_tcp_dumbbell(
     returns an :class:`~tpudes.parallel.runtime.EngineFuture`.
     """
     from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
+    from tpudes.obs.spans import span
     from tpudes.parallel.checkpoint import checkpoint_ctx
     from tpudes.parallel.runtime import (
         RUNTIME,
         EngineFuture,
         bucket_replicas,
         chunk_bounds,
-        donate_argnums,
         drive_chunks,
         finalize_with_flush,
+        jit_advance,
         shard_replica_axis,
         stack_axis,
         unstack_points,
@@ -1452,67 +1455,70 @@ def run_tcp_dumbbell(
         len(variants) if variants is not None
         else (len(traffic_sweep) if traffic_sweep is not None else None)
     )
-    # see dumbbell_prog_key for what is (deliberately) absent; the
-    # sweep KIND is a cache-key component (the two sweeps vmap
-    # different operands — different executables)
-    ck = dumbbell_prog_key(prog) + (r_pad, obs, n_cfg, sweep)
-
     def build():
         init_state, fn = build_dumbbell_advance(
             prog, r_pad, obs=obs, n_cfg=n_cfg, sweep=sweep
         )
-        return init_state, jax.jit(fn, donate_argnums=donate_argnums(0))
+        return init_state, jit_advance("dumbbell", fn)
 
-    (init_state, fn), compiling = RUNTIME.runner("dumbbell", ck, build)
+    # see dumbbell_prog_key for what is (deliberately) absent; the
+    # sweep KIND is a cache-key component (the two sweeps vmap
+    # different operands — different executables)
+    (init_state, fn), compiling = RUNTIME.runner(
+        "dumbbell",
+        lambda: dumbbell_prog_key(prog) + (r_pad, obs, n_cfg, sweep),
+        build,
+    )
 
-    if variants is None:
-        points = [np.asarray(prog.variant_idx, np.int32)]
-        ecns = [
-            np.asarray(prog.ecn, bool)
-            if prog.ecn is not None
-            else np.zeros(prog.n_flows, bool)
-        ]
-    else:
-        points = [_variant_point(p) for p in variants]
-        ecns = [_variant_ecn(p) for p in points]
-        for p in points:
-            if p.shape != (prog.n_flows,):
+    with span("launch.operands"):
+        if variants is None:
+            points = [np.asarray(prog.variant_idx, np.int32)]
+            ecns = [
+                np.asarray(prog.ecn, bool)
+                if prog.ecn is not None
+                else np.zeros(prog.n_flows, bool)
+            ]
+        else:
+            points = [_variant_point(p) for p in variants]
+            ecns = [_variant_ecn(p) for p in points]
+            for p in points:
+                if p.shape != (prog.n_flows,):
+                    raise ValueError(
+                        f"each sweep point assigns all {prog.n_flows} flows "
+                        f"(got shape {p.shape})"
+                    )
+        var = jnp.asarray(
+            points[0] if n_cfg is None or sweep == "traffic"
+            else np.stack(points)
+        )
+        ecn = jnp.asarray(
+            ecns[0] if n_cfg is None or sweep == "traffic"
+            else np.stack(ecns)
+        )
+
+        carry = (jnp.int32(0), init_state())
+        carry = stack_axis(carry, n_cfg)
+        carry = shard_replica_axis(
+            carry, mesh, r_pad, 0 if n_cfg is None else 1
+        )
+
+        # workload params ride as TRACED operands (None = the bulk path);
+        # the runner cache key above carries only the traffic shape key
+        if traffic_sweep is not None:
+            from tpudes.traffic.device import stack_traffic_operands
+
+            if prog.traffic is None or any(
+                tp.shape_key() != prog.traffic.shape_key()
+                for tp in traffic_sweep
+            ):
                 raise ValueError(
-                    f"each sweep point assigns all {prog.n_flows} flows "
-                    f"(got shape {p.shape})"
+                    "a workload sweep needs prog.traffic set and every "
+                    "point sharing its traffic shape key (one executable "
+                    "serves the sweep; pad tables to a common capacity)"
                 )
-    var = jnp.asarray(
-        points[0] if n_cfg is None or sweep == "traffic"
-        else np.stack(points)
-    )
-    ecn = jnp.asarray(
-        ecns[0] if n_cfg is None or sweep == "traffic"
-        else np.stack(ecns)
-    )
-
-    carry = (jnp.int32(0), init_state())
-    carry = stack_axis(carry, n_cfg)
-    carry = shard_replica_axis(
-        carry, mesh, r_pad, 0 if n_cfg is None else 1
-    )
-
-    # workload params ride as TRACED operands (None = the bulk path);
-    # the runner cache key above carries only the traffic shape key
-    if traffic_sweep is not None:
-        from tpudes.traffic.device import stack_traffic_operands
-
-        if prog.traffic is None or any(
-            tp.shape_key() != prog.traffic.shape_key()
-            for tp in traffic_sweep
-        ):
-            raise ValueError(
-                "a workload sweep needs prog.traffic set and every "
-                "point sharing its traffic shape key (one executable "
-                "serves the sweep; pad tables to a common capacity)"
-            )
-        tr = stack_traffic_operands(traffic_sweep)
-    else:
-        tr = None if prog.traffic is None else prog.traffic.operands()
+            tr = stack_traffic_operands(traffic_sweep)
+        else:
+            tr = None if prog.traffic is None else prog.traffic.operands()
     ckpt = checkpoint_ctx(
         checkpoint, engine="dumbbell", key=key, replicas=replicas,
         r_pad=r_pad, n_cfg=n_cfg, obs=obs,

@@ -601,6 +601,8 @@ def build_wired_advance(prog: WiredProgram, replicas: int, owned=None,
     import jax
     import jax.numpy as jnp
 
+    from tpudes.parallel.runtime import scoped_while_loop
+
     R = int(replicas)
     L = int(prog.n_links)
     pkt_flow_np, pkt_birth_np, pkt_nhops_np = packet_table(prog)
@@ -788,7 +790,7 @@ def build_wired_advance(prog: WiredProgram, replicas: int, owned=None,
             loop0 = loop0 + (
                 {k: v for k, v in carry.items() if k.startswith("fm_")},
             )
-        out = jax.lax.while_loop(cond, body, loop0)
+        out = scoped_while_loop("wired", cond, body, loop0)
         (t, n_steps, hop, ready, free, deliver, eg_hop, eg_ready,
          served, nxt) = out[:10]
         carry = dict(
@@ -872,6 +874,8 @@ def build_wired_space_advance(prog: WiredProgram, replicas: int):
     """
     import jax
     import jax.numpy as jnp
+
+    from tpudes.parallel.runtime import scoped_while_loop
 
     R = int(replicas)
     L = int(prog.n_links)
@@ -981,8 +985,9 @@ def build_wired_space_advance(prog: WiredProgram, replicas: int):
 
         nxt0 = jnp.full((K, R), INF_SLOT, jnp.int32)  # tpudes: ignore[SHP001]
         (t, n_steps, hop, ready, free, deliver, eg_hop, eg_ready,
-         served, nxt) = jax.lax.while_loop(
-            cond, body, (carry["t"], jnp.int32(0), *state, nxt0)
+         served, nxt) = scoped_while_loop(
+            "wired", cond, body,
+            (carry["t"], jnp.int32(0), *state, nxt0),
         )
         carry = dict(
             t=t, hop=hop, ready=ready, free=free, deliver=deliver,
@@ -1075,36 +1080,38 @@ def run_wired(
     import jax.numpy as jnp
 
     from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
+    from tpudes.obs.spans import span
     from tpudes.parallel.runtime import (
         RUNTIME,
         EngineFuture,
         bucket_replicas,
         chunk_bounds,
-        donate_argnums,
         drive_chunks,
         finalize_with_flush,
+        jit_advance,
         shard_replica_axis,
     )
 
     r_pad = bucket_replicas(replicas, mesh)
     obs = device_metrics_enabled()
-    # see wired_cache_key for what is (deliberately) absent;
-    # replica_offset only shifts host-side init-state construction
-    ck = wired_cache_key(prog) + (r_pad, obs)
 
     def build():
         init_state, advance = build_wired_advance(prog, r_pad, obs=obs)
-        fn = jax.jit(advance, donate_argnums=donate_argnums(0))
-        return init_state, fn
+        return init_state, jit_advance("wired", advance)
 
-    (init_state, fn), compiling = RUNTIME.runner("wired", ck, build)
-
-    carry = init_state(key, replica_offset)
-    carry = shard_replica_axis(carry, mesh, r_pad, 0)
-    no_ingress = (
-        jnp.full((r_pad, carry["hop"].shape[1]), -1, jnp.int32),
-        jnp.full((r_pad, carry["hop"].shape[1]), -1, jnp.int32),
+    # see wired_cache_key for what is (deliberately) absent;
+    # replica_offset only shifts host-side init-state construction
+    (init_state, fn), compiling = RUNTIME.runner(
+        "wired", lambda: wired_cache_key(prog) + (r_pad, obs), build
     )
+
+    with span("launch.operands"):
+        carry = init_state(key, replica_offset)
+        carry = shard_replica_axis(carry, mesh, r_pad, 0)
+        no_ingress = (
+            jnp.full((r_pad, carry["hop"].shape[1]), -1, jnp.int32),
+            jnp.full((r_pad, carry["hop"].shape[1]), -1, jnp.int32),
+        )
     bounds = chunk_bounds(prog.n_slots, window_slots or prog.n_slots)
     with CompileTelemetry.timed("wired", compiling):
         carry, flush = drive_chunks(
