@@ -239,3 +239,53 @@ def test_checkpoint_telemetry_counters(tmp_path):
     assert f["checkpoint_restores"] == 1
     assert f["injected_checkpoint_kill"] == 1
     ServingTelemetry.reset()
+
+
+# --- ISSUE 26: the base LTE carry holds ONE scalar clock -------------------
+
+#: what the parent of ISSUE 26 wrote into a checkpoint of ``_lte()``
+#: (read from a file it saved): no carry-layout tag in the identity
+_LTE_STACKED_CLOCK_FINGERPRINT = (
+    "c2d0416623946ba98235e3684e7aadd1c3e052f143c0bb55d0341624c0b23065"
+)
+
+
+@pytest.mark.parametrize("layout", ["scalar-clock", "stacked-clock"])
+def test_lte_base_carry_layout(layout, tmp_path):
+    """A file of this layout resumes bit-equal with the scalar clock
+    restored as a scalar; a file written while the clock was stacked
+    per replica is refused by the fingerprint, not loaded into the
+    scalar-clock carry."""
+    import pickle
+
+    from tpudes.parallel.checkpoint import checkpoint_ctx
+    from tpudes.parallel.kernels_pallas import SM_SCHED_IDS
+    from tpudes.parallel.lte_sm import _sm_cache_key
+    from tpudes.parallel.programs import toy_lte_program
+
+    ckpt = CarryCheckpoint(tmp_path / "layout.ckpt")
+    chaos.arm(ChaosSchedule([
+        ChaosEvent("checkpoint_kill", "checkpoint_save", nth=2),
+    ]))
+    with pytest.raises(ChaosInjected):
+        _lte(checkpoint=ckpt)
+    chaos.disarm()
+    with open(ckpt.path, "rb") as f:
+        doc = pickle.load(f)
+    t, s = doc["carry"]
+    assert np.asarray(t).shape == () and int(t) == doc["bound"] == 40
+    assert doc["replica_leaf"][0] is False
+    if layout == "scalar-clock":
+        _assert_equal(_lte(checkpoint=ckpt), _lte())
+        return
+    prog = toy_lte_program(n_enb=2, n_ue=4, n_ttis=60)
+    old = checkpoint_ctx(
+        ckpt, engine="lte_sm", key=KEY, replicas=3, r_pad=4, n_cfg=None,
+        obs=False, axis=0,
+        extra=_sm_cache_key(prog, None, None, False, False)
+        + ((SM_SCHED_IDS[prog.scheduler],),),
+    )
+    assert old.fingerprint == _LTE_STACKED_CLOCK_FINGERPRINT
+    ckpt.save(old, 40, [20, 40, 60], (np.full((4,), t, np.int32), s))
+    with pytest.raises(CheckpointError, match="fingerprint"):
+        _lte(checkpoint=ckpt)

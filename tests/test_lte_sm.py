@@ -7,7 +7,10 @@ host TTI controller on an identical scenario (the SURVEY.md §7 step-8
 "same scenario, two engines" check).
 """
 
+import hashlib
+import json
 import math
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -530,3 +533,175 @@ class TestHostDeviceParity:
         Simulator.Run()
         host_cqi = np.asarray(lte.controller._cqi_dl)
         np.testing.assert_array_equal(out["cqi"], host_cqi)
+
+
+# --- ISSUE 26: the TTI loop is unbatched in all three advance builders ------
+
+
+def _advance_and_args(variant, n_cfg):
+    """One builder's unjitted advance with concrete tiny operands at
+    ``r_pad=2`` (and ``n_cfg`` config points)."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from tpudes.parallel import lte_sm
+    from tpudes.parallel.runtime import replica_keys, stack_axis
+
+    keys = replica_keys(jax.random.PRNGKey(0), 2)
+    sid = jnp.int32(0) if n_cfg is None else jnp.zeros((n_cfg,), jnp.int32)
+    stack = lambda s: stack_axis(stack_axis(s, 2), n_cfg)  # noqa: E731
+    kw = dict(r_pad=2, n_cfg=n_cfg, use_pallas=False)
+    if variant == "base":
+        _, init_state, fn = lte_sm.build_sm_advance(
+            lte_sm._trace_prog(), **kw
+        )
+        carry = (jnp.int32(0), stack(init_state()))
+        return fn, (carry, keys, sid, jnp.int32(8))
+    if variant == "traffic":
+        prog = lte_sm._trace_traffic_prog()
+        init_carry, fn = lte_sm.build_sm_traffic_advance(prog, **kw)
+        t0, s0 = init_carry()
+        return fn, (
+            (t0, stack(s0)), keys, sid, jnp.int32(8),
+            prog.traffic.operands(), jax.random.PRNGKey(1),
+        )
+    from tpudes.ops.mobility import MobilityProgram
+
+    base = lte_sm._trace_prog()
+    prog = dataclasses.replace(
+        base,
+        mobility=MobilityProgram.constant_velocity(
+            np.full((base.n_ue, 3), 100.0), np.ones((base.n_ue, 3))
+        ),
+        enb_pos=np.array([[0.0, 0.0, 30.0], [500.0, 0.0, 30.0]]),
+        pathloss=("friis", 2.12e9, 1.0, 0.0),
+    )
+    init_carry, fn = lte_sm.build_sm_mobile_advance(prog, **kw)
+    t0, g0, s0 = init_carry()
+    return fn, (
+        (t0, g0, stack(s0)), keys, sid, jnp.int32(8),
+        prog.mobility.operands(), jnp.int32(1), None,
+    )
+
+
+@pytest.mark.parametrize("n_cfg", [None, 2], ids=["r2", "r2-c2"])
+@pytest.mark.parametrize("variant", ["base", "mobile", "traffic"])
+def test_advance_runs_one_unbatched_tti_loop(variant, n_cfg):
+    """The lanes are vmapped over the per-TTI step only: the advance
+    holds ONE outermost ``while`` whose clock is a rank-0 int32 and
+    whose condition is all scalars — a ``vmap`` of the loop would make
+    the predicate per-lane, which lowers to a select of every carry
+    leaf and an ``any`` (an all-reduce on a mesh) per TTI."""
+    import jax
+    import jax.numpy as jnp
+
+    fn, args = _advance_and_args(variant, n_cfg)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    whiles = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    assert len(whiles) == 1
+    (loop,) = whiles
+    n_consts = loop.params["cond_nconsts"] + loop.params["body_nconsts"]
+    clock = loop.invars[n_consts].aval
+    assert clock.shape == () and clock.dtype == jnp.int32
+    cond = loop.params["cond_jaxpr"].jaxpr
+    assert cond.outvars[0].aval.shape == ()
+    for eqn in cond.eqns:
+        assert not eqn.primitive.name.startswith("reduce"), eqn
+        assert eqn.primitive.name not in ("any", "argmax"), eqn
+        for v in list(eqn.invars) + list(eqn.outvars):
+            assert v.aval.shape == (), eqn
+
+
+#: the digests below were written by the PARENT of ISSUE 26 (the base
+#: advance still a vmap of the while_loop, per-lane clock): the
+#: unbatched loop must reproduce every one of them exactly
+_GOLDEN = Path(__file__).parent / "golden" / "lte_sm_base_advance.json"
+GOLDEN_PROGS = ("toy", "spread")
+GOLDEN_CASES = {
+    "solo": dict(replicas=4),
+    "sweep": dict(replicas=4, schedulers=["pf", "rr"]),
+    "chunked": dict(replicas=4, chunk_ttis=16),
+    "sweep_chunked": dict(
+        replicas=4, schedulers=["pf", "rr"], chunk_ttis=16
+    ),
+}
+
+
+def _sha(a):
+    a = np.ascontiguousarray(np.asarray(a))
+    h = hashlib.sha256(f"{a.dtype}{a.shape}".encode() + a.tobytes())
+    return [list(a.shape), h.hexdigest()[:16]]
+
+
+def golden_entry(prog_name, lowering, obs, case):
+    """What one ``run_lte_sm`` call gives, as JSON: the integer result
+    arrays verbatim, the FlowMonitor columns and the per-chunk metrics
+    as ``[shape, sha256]``.  ``toy`` is the issue's program (every
+    replica draws the same outcome at its 30 dB dominance); ``spread``
+    has UEs near a BLER cliff, so replicas differ by their keys."""
+    import os
+
+    import jax
+
+    from tpudes.core.global_value import GlobalValue
+    from tpudes.obs.device import ChunkStream
+    from tpudes.parallel.programs import toy_lte_program
+    from tpudes.parallel.runtime import RUNTIME
+
+    prog = (
+        toy_lte_program(n_enb=2, n_ue=3, n_ttis=40)
+        if prog_name == "toy" else _toy_prog(n_ttis=40)
+    )
+    saved = os.environ.get("TPUDES_PALLAS")
+    os.environ["TPUDES_PALLAS"] = "1" if lowering == "pallas" else "0"
+    GlobalValue.Bind("TpudesObs", int(obs))
+    RUNTIME.clear("lte_sm")
+    ChunkStream.reset()
+    try:
+        out = run_lte_sm(prog, jax.random.PRNGKey(26), **GOLDEN_CASES[case])
+    finally:
+        if saved is None:
+            del os.environ["TPUDES_PALLAS"]
+        else:
+            os.environ["TPUDES_PALLAS"] = saved
+        GlobalValue.Bind("TpudesObs", 0)
+        RUNTIME.clear("lte_sm")
+    points = []
+    for p in out if isinstance(out, list) else [out]:
+        e = {
+            k: np.asarray(p[k]).tolist()
+            for k in ("rx_bits", "new_tbs", "retx", "drops", "ok")
+        }
+        if "flow" in p:
+            e["flow"] = {k: _sha(v) for k, v in sorted(p["flow"].items())}
+        points.append(e)
+    entry = {"result": points}
+    if obs:
+        entry["chunks"] = [
+            [c["t_end"],
+             {k: _sha(v) for k, v in sorted(c["metrics"].items())}]
+            for c in ChunkStream.entries("lte_sm")
+        ]
+        ChunkStream.reset()
+    return entry
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN_CASES))
+@pytest.mark.parametrize("obs", [0, 1], ids=["obs0", "obs1"])
+@pytest.mark.parametrize("lowering", ["xla", "pallas"])
+def test_base_advance_reproduces_the_vmapped_while(lowering, obs, case):
+    golden = json.loads(_GOLDEN.read_text())
+    for prog_name in GOLDEN_PROGS:
+        want = golden[f"{prog_name}.obs{obs}.{case}"]
+        got = golden_entry(prog_name, lowering, obs, case)
+        assert got == want, prog_name
+        if obs and "chunk" in case:
+            # one ok / drops / retx / flipped ring per lane and chunk,
+            # not one sum over all lanes
+            lanes = [2, 4] if "sweep" in case else [4]
+            assert [c[0] for c in got["chunks"]] == [16, 32, 40]
+            for _, m in got["chunks"]:
+                assert m["ok"][0] == lanes and m["retx"][0] == lanes
+                assert m["fm_ring"][0][:-3] == lanes

@@ -326,7 +326,7 @@ def test_pallas_lowering_carries_the_kernel_name():
     _, init_state, fn = lte_sm.build_sm_advance(
         prog, r_pad=2, use_pallas=True
     )
-    carry = stack_axis((jnp.int32(0), init_state()), 2)
+    carry = (jnp.int32(0), stack_axis(init_state(), 2))
     text = jax.jit(fn).lower(
         carry, replica_keys(jax.random.PRNGKey(0), 2), jnp.int32(0),
         jnp.int32(8),
@@ -334,3 +334,7 @@ def test_pallas_lowering_carries_the_kernel_name():
     assert SM_KERNEL_NAME == "tpudes_lte_sm_tti"
     assert SM_KERNEL_NAME in text
     assert lte_sm.RNG_SCOPE in text and "tpudes.lte_sm.step" in text
+    # the replica vmap of the step wraps the lane's scope, not ours: a
+    # wrapped kernel name reads `vmap_tpudes_lte_sm_tti_` on the device
+    assert f"vmap({lte_sm.LANE_SCOPE})/{SM_KERNEL_NAME}" in text
+    assert f"vmap({lte_sm.LANE_SCOPE})/{lte_sm.RNG_SCOPE}" in text

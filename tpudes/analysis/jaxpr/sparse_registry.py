@@ -396,8 +396,8 @@ SPARSE_SITES: tuple = (
     # engine's monotonic step counter reduced by lax.rem, so every
     # write is in-bounds by the modulus; XLA clamps DUS starts anyway
     # (mode clip).  LTE is the exception that proves the vmap hazard:
-    # its advance is replica-vmapped with a batched carry, so the DUS
-    # batching rule lowers the ring write to a scatter (still mod-
+    # its per-TTI step is replica-vmapped with a batched ring, so the
+    # DUS batching rule lowers the ring write to a scatter (still mod-
     # rooted, still clip-moded).
     SparseSite(
         site="dumbbell.flow_ring",
@@ -421,7 +421,7 @@ SPARSE_SITES: tuple = (
         primitive="scatter", mode="clip",
         provenance=("operand", "mod"),
         note="FlowMonitor ring write at slot t % FLOW_RING_CAP; the "
-             "replica vmap batches the DUS start index, so the "
+             "replica vmap of the step batches the ring, so the DUS "
              "batching rule lowers it to scatter — indices stay "
              "mod-bounded",
     ),

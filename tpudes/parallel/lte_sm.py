@@ -4,7 +4,10 @@ The LTE counterpart of :mod:`tpudes.parallel.replicated` (SURVEY.md §7
 step 8 + hard-part 6): instead of one simulator event per TTI making a
 host↔device round trip, the WHOLE multi-TTI simulation — FF-MAC
 scheduling, HARQ-IR, decode draws, PF averaging, for every cell at once
-— runs as one ``lax.scan`` on the accelerator.  The replica axis is one ``vmap`` over PRNG keys.
+— runs as one ``lax.while_loop`` on the accelerator, its horizon a
+traced operand.  The loop itself is unbatched (one scalar TTI clock);
+the replica axis (and a scheduler sweep's config axis) is a ``vmap``
+of the per-TTI step over per-replica PRNG keys (:func:`_vmap_lanes`).
 
 This is sound because under RLC saturation mode every buffer is always
 full, so the only evolving state is scheduler/HARQ bookkeeping — pure
@@ -401,15 +404,20 @@ def _lift_lte_mobility(ctrl, n_ttis: int, geom_stride: int):
 #: ``tpudes.lte_sm.step``, see ``runtime.scoped_while_loop``)
 RNG_SCOPE = "tpudes.lte_sm.rng"
 
+#: device name of one lane's (replica's, config point's) share of a TTI
+#: — what :func:`_vmap_lanes` maps; it keeps the names inside it whole
+LANE_SCOPE = "tpudes.lte_sm.lane"
+
 
 def build_sm_step(prog: LteSmProgram, use_pallas: bool | None = None):
-    """Returns ``(consts, init_state, step_fn)`` for the per-TTI scan
-    body (single replica; vmapped by run_lte_sm).
+    """Returns ``(consts, init_state, step_fn)`` for the per-TTI loop
+    body (single replica; :func:`build_sm_advance` vmaps it over the
+    lanes inside its unbatched ``while_loop``).
 
     The TTI math itself lives in :mod:`tpudes.parallel.kernels_pallas`
     (one math core, two lowerings — the fused Pallas kernel and the
-    plain-XLA fallback); this builder only owns the scan plumbing: the
-    per-TTI ``fold_in`` coin draw and the carry layout.
+    plain-XLA fallback); this builder only owns the loop plumbing: the
+    per-TTI coin draw and the state layout.
 
     ``step_fn(state, (t, key), sid)`` — ``sid`` is the traced scheduler
     id (:data:`SM_SCHED_IDS`), so the compiled program is
@@ -560,6 +568,12 @@ def _sm_cache_key(prog: LteSmProgram, replicas, n_cfg, obs, use_pallas) -> tuple
     )
 
 
+#: carry layout of the base advance, part of its checkpoint fingerprint:
+#: ``(t, s)`` with ONE scalar clock for all lanes.  A file written
+#: while the clock was stacked per lane (no tag in its fingerprint) is
+#: refused as a different study, not loaded into this carry.
+_SM_CARRY_LAYOUT = "scalar-clock"
+
 #: the state-dict keys fetched back to the host at run end
 _SM_FETCH = ("rx_lo", "rx_hi", "new_tbs", "retx", "drops", "ok_cnt")
 
@@ -652,14 +666,48 @@ def lte_sm_study(prog: LteSmProgram, key, replicas=None, mesh=None):
     )
 
 
+def _vmap_lanes(one, r_pad: int | None, n_cfg: int | None):
+    """``one(s_r, k_r, sid_s)``, one lane's work for one TTI, lifted
+    to the stacked ``(n_cfg,) (r_pad,)`` state: the replica axis maps
+    state and key (one scheduler id for all), the config axis maps
+    state and scheduler id (one key array for all); ``None`` leaves an
+    axis out.  The three advance builders vmap ONLY this — the TTI
+    ``while_loop`` around it stays unbatched (scalar clock, scalar
+    predicate): ``vmap`` of a ``while_loop`` whose predicate is
+    per-replica selects every carry leaf by it and reduces it with
+    ``any`` each iteration (on a mesh, an all-reduce per TTI), for
+    replicas that all share one ``t_end``.
+
+    The lane traces under :data:`LANE_SCOPE`: jax's name stack wraps
+    the first scope after a ``vmap`` (``vmap(<scope>)``), and that
+    scope is the sacrifice — without it the kernel's event name reads
+    ``vmap_tpudes_lte_sm_tti_`` and the coin draw's scope
+    ``vmap(tpudes.lte_sm.rng)``."""
+    def lane(s_r, k_r, sid_s):
+        with jax.named_scope(LANE_SCOPE):
+            return one(s_r, k_r, sid_s)
+
+    step = lane if r_pad is None else jax.vmap(lane, in_axes=(0, 0, None))
+    if n_cfg is not None:
+        step = jax.vmap(step, in_axes=(0, None, 0))
+    return step
+
+
 def build_sm_advance(prog: LteSmProgram, r_pad: int | None = None,
                      n_cfg: int | None = None, obs: bool = False,
                      use_pallas: bool = False):
-    """``(consts, init_state, fn)`` with ``fn(carry, k, sid, t_end)``
-    the UNJITTED (but replica/config-vmapped) advance exactly as
-    :func:`run_lte_sm` jits it — factored out so the trace manifest
-    (:func:`trace_manifest`) abstractly traces the same program the
-    runner cache compiles."""
+    """``(consts, init_state, fn)`` with ``fn(carry, keys, sid, t_end)``
+    the UNJITTED advance exactly as :func:`run_lte_sm` jits it —
+    factored out so the trace manifest (:func:`trace_manifest`)
+    abstractly traces the same program the runner cache compiles.
+
+    ``carry = (t, s)``: ``t`` the scalar ``int32`` TTI clock every lane
+    shares, ``s`` the state dict stacked on ``(n_cfg,) (r_pad,)``;
+    ``keys`` the whole ``(r_pad, 2)`` replica key array.  The TTI
+    ``while_loop`` runs unbatched and :func:`_vmap_lanes` maps the
+    per-TTI step (and, under ``obs``, the per-chunk summary) over the
+    lanes — the same shape as :func:`build_sm_mobile_advance` and
+    :func:`build_sm_traffic_advance`."""
     consts, init_state, step_fn = build_sm_step(prog, use_pallas)
     if obs:
         from tpudes.obs.flowmon import (
@@ -676,105 +724,107 @@ def build_sm_advance(prog: LteSmProgram, r_pad: int | None = None,
         def init_state():  # noqa: F811 — obs variant shadows on purpose
             return dict(base_init(), **flow_carry(U, lead=(1,)))
 
-    def advance(carry, k, sid, t_end):
-        # per-TTI key = fold_in(k, t): a pure function of (k, t),
-        # so the traced horizon needs no key-array shape at all —
-        # one executable serves every n_ttis (split(k, n_ttis)
-        # would bake the horizon into the program), and a chunked
-        # run re-entering at t>0 draws the same per-TTI streams
+    def advance(carry, keys, sid, t_end):
         def body(c):
             t, s = c
-            with jax.named_scope(RNG_SCOPE):
-                kt = jax.random.fold_in(k, t)
-            if not obs:
-                return t + 1, step_fn(s, (t, kt), sid)
-            # the fused TTI core builds exact-key state dicts, so the
-            # FlowMonitor columns ride AROUND it: split them off the
-            # carry, diff the cumulative counters across the TTI, and
-            # merge them back (flow = UE; one observation per TTI)
-            fm = {kk: v for kk, v in s.items() if kk.startswith("fm_")}
-            core = {kk: v for kk, v in s.items()
-                    if not kk.startswith("fm_")}
-            s2 = step_fn(core, (t, kt), sid)
-            d_ok = s2["ok_cnt"] - core["ok_cnt"]            # (1, U)
-            d_tx = (
-                (s2["new_tbs"] - core["new_tbs"])
-                + (s2["retx"] - core["retx"])
-            )
-            d_drop = s2["drops"] - core["drops"]
-            # acked bits this TTI, split-counter diff (bits far below
-            # 2^31 per TTI, so plain i32 arithmetic is exact)
-            d_bytes = (
-                ((s2["rx_hi"] - core["rx_hi"]) << jnp.int32(20))
-                + (s2["rx_lo"] - core["rx_lo"])
-            ) // jnp.int32(8)
-            tti_s = jnp.float32(1e-3)
-            fm = flow_accumulate(
-                fm,
-                t_s=t.astype(jnp.float32) * tti_s,
-                tx=d_tx,
-                # bytes are metered at ACK (the rx counters are the
-                # only byte stream the TTI core keeps) — documented
-                # coarsening: tx_bytes counts acknowledged bytes
-                tx_bytes=d_bytes,
-                rx=d_ok,
-                rx_bytes=d_bytes,
-                # MAC-to-ACK latency is one TTI by construction in the
-                # sub-band model — delay is exact, jitter is zero
-                delay_s=jnp.full((1, U), tti_s, jnp.float32),
-                lost=d_drop,
-                bin_width_s=1e-3,
-            )
-            got = jnp.sum(d_ok) > 0
-            sent = jnp.sum(d_tx) > 0
-            ev_flow = jnp.where(
-                got, jnp.argmax(d_ok[0]), jnp.argmax(d_tx[0])
-            ).astype(jnp.int32)
-            oh = (jnp.arange(U, dtype=jnp.int32) == ev_flow)
-            ev_bytes = jnp.sum(
-                d_bytes[0] * oh.astype(jnp.int32), dtype=jnp.int32
-            )
-            row = jnp.stack([
-                jnp.where(got | sent, t, jnp.int32(-1)),
-                t * jnp.int32(1000),
-                ev_flow,
-                ev_bytes,
-                jnp.where(
-                    got, jnp.int32(VERDICT_RX), jnp.int32(VERDICT_TX)
-                ),
-            ])
-            fm["fm_ring"] = flow_ring_write(
-                fm["fm_ring"], t, row[None, :]
-            )
-            return t + 1, dict(s2, **fm)
+
+            # per-TTI key = fold_in(k, t): a pure function of (k, t),
+            # so the traced horizon needs no key-array shape at all —
+            # one executable serves every n_ttis (split(k, n_ttis)
+            # would bake the horizon into the program), and a chunked
+            # run re-entering at t>0 draws the same per-TTI streams
+            def one(s_r, k_r, sid_s):
+                with jax.named_scope(RNG_SCOPE):
+                    kt = jax.random.fold_in(k_r, t)
+                if not obs:
+                    return step_fn(s_r, (t, kt), sid_s)
+                # the fused TTI core builds exact-key state dicts, so
+                # the FlowMonitor columns ride AROUND it: split them
+                # off the carry, diff the cumulative counters across
+                # the TTI, and merge them back (flow = UE; one
+                # observation per TTI)
+                fm = {kk: v for kk, v in s_r.items()
+                      if kk.startswith("fm_")}
+                core = {kk: v for kk, v in s_r.items()
+                        if not kk.startswith("fm_")}
+                s2 = step_fn(core, (t, kt), sid_s)
+                d_ok = s2["ok_cnt"] - core["ok_cnt"]            # (1, U)
+                d_tx = (
+                    (s2["new_tbs"] - core["new_tbs"])
+                    + (s2["retx"] - core["retx"])
+                )
+                d_drop = s2["drops"] - core["drops"]
+                # acked bits this TTI, split-counter diff (bits far
+                # below 2^31 per TTI, so plain i32 arithmetic is exact)
+                d_bytes = (
+                    ((s2["rx_hi"] - core["rx_hi"]) << jnp.int32(20))
+                    + (s2["rx_lo"] - core["rx_lo"])
+                ) // jnp.int32(8)
+                tti_s = jnp.float32(1e-3)
+                fm = flow_accumulate(
+                    fm,
+                    t_s=t.astype(jnp.float32) * tti_s,
+                    tx=d_tx,
+                    # bytes are metered at ACK (the rx counters are the
+                    # only byte stream the TTI core keeps) — documented
+                    # coarsening: tx_bytes counts acknowledged bytes
+                    tx_bytes=d_bytes,
+                    rx=d_ok,
+                    rx_bytes=d_bytes,
+                    # MAC-to-ACK latency is one TTI by construction in
+                    # the sub-band model — delay is exact, jitter zero
+                    delay_s=jnp.full((1, U), tti_s, jnp.float32),
+                    lost=d_drop,
+                    bin_width_s=1e-3,
+                )
+                got = jnp.sum(d_ok) > 0
+                sent = jnp.sum(d_tx) > 0
+                ev_flow = jnp.where(
+                    got, jnp.argmax(d_ok[0]), jnp.argmax(d_tx[0])
+                ).astype(jnp.int32)
+                oh = (jnp.arange(U, dtype=jnp.int32) == ev_flow)
+                ev_bytes = jnp.sum(
+                    d_bytes[0] * oh.astype(jnp.int32), dtype=jnp.int32
+                )
+                row = jnp.stack([
+                    jnp.where(got | sent, t, jnp.int32(-1)),
+                    t * jnp.int32(1000),
+                    ev_flow,
+                    ev_bytes,
+                    jnp.where(
+                        got, jnp.int32(VERDICT_RX), jnp.int32(VERDICT_TX)
+                    ),
+                ])
+                fm["fm_ring"] = flow_ring_write(
+                    fm["fm_ring"], t, row[None, :]
+                )
+                return dict(s2, **fm)
+
+            return t + 1, _vmap_lanes(one, r_pad, n_cfg)(s, keys, sid)
 
         t, s = scoped_while_loop(
             "lte_sm", lambda c: c[0] < t_end, body, carry
         )
-        # small per-chunk summaries (fresh buffers, NOT aliased to
-        # the carry — the next chunk donates the carry away); only
-        # under TpudesObs, so a disabled run compiles the exact
-        # pre-obs program
-        metrics = (
-            dict(
-                ok=jnp.sum(s["ok_cnt"]), drops=jnp.sum(s["drops"]),
-                retx=jnp.sum(s["retx"]),
+        if not obs:
+            return (t, s), {}
+
+        # small per-chunk summaries, one per lane (fresh buffers, NOT
+        # aliased to the carry — the next chunk donates the carry
+        # away); only under TpudesObs, so a disabled run compiles the
+        # exact pre-obs program
+        def summary(s_r, _k_r, _sid_s):
+            return dict(
+                ok=jnp.sum(s_r["ok_cnt"]), drops=jnp.sum(s_r["drops"]),
+                retx=jnp.sum(s_r["retx"]),
                 # lax.rev is a real op XLA cannot fold into an alias of
                 # the donated carry; the decoder sorts by step, so the
                 # flipped order never needs undoing
-                fm_ring=jnp.flip(s["fm_ring"], axis=-2),
+                fm_ring=jnp.flip(s_r["fm_ring"], axis=-2),
             )
-            if obs
-            else {}
-        )
-        return (t, s), metrics
 
-    fn = advance
-    if r_pad is not None:
-        fn = jax.vmap(fn, in_axes=(0, 0, None, None))
-    if n_cfg is not None:
-        fn = jax.vmap(fn, in_axes=(0, None, 0, None))
-    return consts, init_state, fn
+        return (t, s), _vmap_lanes(summary, r_pad, n_cfg)(s, keys, sid)
+
+    return consts, init_state, advance
 
 
 def build_sm_mobile_advance(prog: LteSmProgram, r_pad: int | None = None,
@@ -818,14 +868,7 @@ def build_sm_mobile_advance(prog: LteSmProgram, r_pad: int | None = None,
                     )[None, :]
                 return fused(s_r, coin, t, sid_s, dyn)
 
-            if r_pad is None:
-                step = one
-            else:
-                step = jax.vmap(one, in_axes=(0, 0, None))
-            if n_cfg is None:
-                s2 = step(s, keys, sid)
-            else:
-                s2 = jax.vmap(step, in_axes=(0, None, 0))(s, keys, sid)
+            s2 = _vmap_lanes(one, r_pad, n_cfg)(s, keys, sid)
             return t + 1, g2, s2
 
         t, g, s = scoped_while_loop(
@@ -854,8 +897,8 @@ def build_sm_traffic_advance(prog: LteSmProgram, r_pad: int | None = None,
     the UNJITTED finite-backlog advance exactly as
     :func:`_run_lte_sm_traffic` jits it.
 
-    Structure mirrors :func:`build_sm_mobile_advance`: the TTI
-    ``while_loop`` runs UNBATCHED and only the fused kernel is vmapped
+    Structure as in all three builders (:func:`_vmap_lanes`): the TTI
+    ``while_loop`` runs UNBATCHED and only the per-lane step is vmapped
     over the replica/config axes — the workload realization (like the
     mobility trajectory) is shared by every replica and config point,
     so the per-TTI offered-bits fill is computed ONCE per TTI.  The
@@ -925,14 +968,7 @@ def build_sm_traffic_advance(prog: LteSmProgram, r_pad: int | None = None,
                     + lo // jnp.int32(2**20),
                 )
 
-            if r_pad is None:
-                step = one
-            else:
-                step = jax.vmap(one, in_axes=(0, 0, None))
-            if n_cfg is None:
-                s2 = step(s, keys, sid)
-            else:
-                s2 = jax.vmap(step, in_axes=(0, None, 0))(s, keys, sid)
+            s2 = _vmap_lanes(one, r_pad, n_cfg)(s, keys, sid)
             return t + 1, s2
 
         t, s = scoped_while_loop(
@@ -1140,10 +1176,11 @@ def _run_lte_sm_mobile(
     """The mobile-geometry form of :func:`run_lte_sm` (same contract,
     same result fields + ``geom_refreshes``/``geom_stride``).
 
-    Structure: the TTI ``while_loop`` runs UNBATCHED (scalar clock +
-    the geometry row dict in the carry) and only the fused TTI kernel
-    is vmapped over the replica / config axes inside the body — the
-    trajectory is shared by every replica and config point, so the
+    Structure as in all three builders (:func:`_vmap_lanes`): the TTI
+    ``while_loop`` runs UNBATCHED (scalar clock + the geometry row dict
+    in the carry) and only the per-lane step is vmapped over the
+    replica / config axes inside the body — the trajectory is shared
+    by every replica and config point, so the
     geometry ``lax.cond`` keeps a SCALAR predicate and the refresh
     really is skipped on non-stride TTIs (a batched predicate would
     degrade to select-both-branches under vmap and the stride would
@@ -1351,12 +1388,11 @@ def _sm_launch(prog: LteSmProgram, key, replicas, mesh, schedulers):
             keys = shard_replica_axis(
                 replica_keys(key, r_pad), mesh, r_pad, 0
             )
-        carry = (jnp.int32(0), init_state())
-        carry = stack_axis(carry, r_pad)
-        carry = stack_axis(carry, n_cfg)
-        carry = shard_replica_axis(
-            carry, mesh, r_pad, 0 if n_cfg is None else 1
-        )
+        # the clock is one scalar for every lane (replicated on a
+        # mesh); only the state is stacked and sharded
+        s0 = stack_axis(stack_axis(init_state(), r_pad), n_cfg)
+        s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
+        carry = (jnp.int32(0), s0)
     return SimpleNamespace(
         consts=consts, fn=fn, carry=carry, keys=keys, sid=sid, sids=sids,
         r_pad=r_pad, n_cfg=n_cfg, obs=obs, compiling=compiling,
@@ -1394,18 +1430,20 @@ def run_lte_sm(
 
     Without ``replicas``: one run, returns per-UE arrays
     ``{rx_bits, new_tbs, retx, drops, ok, cqi, mcs, sinr}``.
-    With ``replicas=R``: vmaps R Monte-Carlo replicas over per-replica
-    keys, leading axis R on the outcome arrays; with ``mesh`` (1-axis
-    "replica") the replica axis is sharded over the mesh devices.  The
-    replica axis is runtime-bucketed (padded to a power of two, results
-    sliced back) so replica sweeps reuse one executable per bucket.
+    With ``replicas=R``: R Monte-Carlo replicas advance in lock step
+    inside ONE TTI loop (scalar clock; the per-TTI step is vmapped over
+    per-replica keys), leading axis R on the outcome arrays; with
+    ``mesh`` (1-axis "replica") the replica axis is sharded over the
+    mesh devices and the loop needs no collective.  The replica axis is
+    runtime-bucketed (padded to a power of two, results sliced back) so
+    replica sweeps reuse one executable per bucket.
 
     ``schedulers=[...]`` (names from :data:`SM_SCHED_IDS`) turns the
     call into a **config-axis sweep**: the scheduler id gains a leading
-    vmapped axis alongside the replica axis, so a C-point scheduler
-    study is ONE device launch of a (C, R, …) program; the return value
-    is a list of per-point result dicts, each exactly what the
-    per-point launch (same key) would have produced.
+    axis, vmapped over the step alongside the replica axis, so a
+    C-point scheduler study is ONE device launch of a (C, R, …)
+    program; the return value is a list of per-point result dicts, each
+    exactly what the per-point launch (same key) would have produced.
 
     ``chunk_ttis=N`` splits the horizon into N-TTI while_loop segments
     with the carry handed (donated) from segment to segment — results
@@ -1464,7 +1502,7 @@ def run_lte_sm(
         r_pad=L.r_pad, n_cfg=n_cfg, obs=obs,
         axis=0 if n_cfg is None else 1, mesh=mesh,
         extra=_sm_cache_key(prog, None, n_cfg, obs, False)
-        + (tuple(L.sids),),
+        + (tuple(L.sids), _SM_CARRY_LAYOUT),
     )
     # scheduler id and horizon are traced, so a 9-scheduler sweep must
     # keep the recorded compile count at ONE — bench reports the metric
@@ -1531,7 +1569,7 @@ def _trace_entries(
         prog, r_pad=_TRACE_R, obs=obs, use_pallas=False
     )
     keys = replica_keys(jax.random.PRNGKey(0), _TRACE_R)
-    carry = stack_axis((jnp.int32(0), init_state()), _TRACE_R)
+    carry = (jnp.int32(0), stack_axis(init_state(), _TRACE_R))
     return [
         TraceEntry("init", init_state, (), kernel=False),
         TraceEntry(
@@ -1692,7 +1730,7 @@ def trace_manifest():
             ),
             # the TpudesObs program (FlowMonitor columns + packet ring)
             # joins the lint surface: its ring write — a scatter here,
-            # the replica vmap batches the ring-slot start index — must
+            # the replica vmap of the step batches the ring — must
             # pass the registered SparseSite contract (JXL008)
             TraceVariant(
                 "obs", lambda: _trace_entries(_trace_prog(), obs=True)
