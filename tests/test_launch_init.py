@@ -1,0 +1,267 @@
+"""The launch carry comes from one jitted init program per runner
+(``runtime.jit_init``, ISSUE 29).
+
+- leaf for leaf, in value, dtype, shape and placement, the init
+  program gives what the eager chain it replaced gave
+  (``replica_keys``; ``init_state()`` -> ``stack_axis`` ->
+  ``shard_replica_axis``): BSS and the three LTE runners, with and
+  without a config axis, on one device and on a 1-axis replica mesh;
+- a run through the init program reproduces, bit for bit, the result
+  arrays that the PARENT of ISSUE 29 (eager carry) wrote into
+  ``golden/launch_init_parent.json``;
+- the program is made once per runner and mesh and counted
+  (``RUNTIME.stats()["init_programs"]``).
+"""
+
+import dataclasses
+import hashlib
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpudes.parallel.runtime import (
+    RUNTIME,
+    bucket_replicas,
+    replica_keys,
+    shard_replica_axis,
+    stack_axis,
+)
+
+KEY = jax.random.PRNGKey(29)
+REPLICAS = 4
+VARIANTS = ("bss", "lte_base", "lte_traffic", "lte_mobile")
+N_CFG = pytest.mark.parametrize("n_cfg", [None, 2], ids=["solo", "cfg2"])
+ON_MESH = pytest.mark.parametrize("on_mesh", [False, True],
+                                  ids=["one", "mesh"])
+_GOLDEN = Path(__file__).parent / "golden" / "launch_init_parent.json"
+
+
+def _mesh(on_mesh):
+    if not on_mesh:
+        return None
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the virtual multi-device mesh")
+    from tpudes.parallel.mesh import replica_mesh
+
+    return replica_mesh(4)
+
+
+def _prog(variant):
+    from tpudes.parallel import lte_sm
+    from tpudes.parallel.programs import toy_bss_program
+
+    if variant == "bss":
+        return toy_bss_program(n_sta=4, sim_end_us=60_000)
+    if variant == "lte_base":
+        return lte_sm._trace_prog()
+    if variant == "lte_traffic":
+        return lte_sm._trace_traffic_prog()
+    from tpudes.ops.mobility import MobilityProgram
+
+    base = lte_sm._trace_prog()
+    return dataclasses.replace(
+        base,
+        mobility=MobilityProgram.constant_velocity(
+            np.full((base.n_ue, 3), 100.0), np.ones((base.n_ue, 3))
+        ),
+        enb_pos=np.array([[0.0, 0.0, 30.0], [500.0, 0.0, 30.0]]),
+        pathloss=("friis", 2.12e9, 1.0, 0.0),
+    )
+
+
+def _init_and_eager(variant, n_cfg, mesh):
+    """``(got, want)``: what the runner's init program returns, and the
+    eager chain of the parent on the same builder's ``init_state``."""
+    from tpudes.parallel import lte_sm, replicated
+
+    prog = _prog(variant)
+    r_pad = bucket_replicas(REPLICAS, mesh)
+    axis = 0 if n_cfg is None else 1
+
+    def shard(tree, ax):
+        return shard_replica_axis(tree, mesh, r_pad, ax)
+
+    if variant == "bss":
+        init, _, _, _ = replicated._compiled_bss_runner(
+            prog, r_pad, mesh, n_cfg=n_cfg
+        )
+        init_state, _, _ = replicated.build_bss_advance(
+            prog, r_pad, n_cfg=n_cfg
+        )
+        return init(mesh), (shard(stack_axis(init_state(), n_cfg), axis),)
+    kw = dict(r_pad=r_pad, n_cfg=n_cfg, use_pallas=False)
+    if variant == "lte_base":
+        _, init_state, _ = lte_sm.build_sm_advance(prog, **kw)
+
+        def init_carry():
+            return (jnp.int32(0), init_state())
+
+        scheds = None if n_cfg is None else ["pf", "rr"]
+        launch = lte_sm._sm_launch(prog, KEY, REPLICAS, mesh, scheds)
+        got = (launch.keys, launch.carry)
+    else:
+        build = (
+            lte_sm.build_sm_traffic_advance if variant == "lte_traffic"
+            else lte_sm.build_sm_mobile_advance
+        )
+        init_carry, _ = build(prog, **kw)
+        got = lte_sm._sm_jit_init(init_carry, r_pad, n_cfg)(mesh, KEY)
+    *shared, s = init_carry()
+    s0 = shard(stack_axis(stack_axis(s, r_pad), n_cfg), axis)
+    return got, (shard(replica_keys(KEY, r_pad), 0), (*shared, s0))
+
+
+@ON_MESH
+@N_CFG
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_init_program_gives_the_eager_carry(variant, n_cfg, on_mesh):
+    mesh = _mesh(on_mesh)
+    got, want = _init_and_eager(variant, n_cfg, mesh)
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    got_leaves = jax.tree_util.tree_leaves_with_path(got)
+    want_leaves = jax.tree_util.tree_leaves(want)
+    sharded = 0
+    for (path, g), w in zip(got_leaves, want_leaves, strict=True):
+        name = jax.tree_util.keystr(path)
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert g.weak_type == w.weak_type, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+        if mesh is None:
+            # uncommitted one-device arrays, as the eager jnp calls
+            # gave: the advance program is lowered for exactly these
+            assert not g.committed and not w.committed, name
+            assert g.sharding.is_equivalent_to(w.sharding, g.ndim), name
+        elif w.committed:
+            # a replica leaf: the same NamedSharding spec
+            assert g.sharding.spec == w.sharding.spec, name
+            assert g.sharding.mesh == mesh, name
+            assert "replica" in g.sharding.spec, name
+            sharded += 1
+        else:
+            # everything else the program replicates over the mesh
+            assert g.sharding.is_fully_replicated, name
+            assert g.sharding.mesh == mesh, name
+    if mesh is not None:
+        assert 0 < sharded <= len(want_leaves)
+
+
+# --- bit identity with the parent's eager carry ------------------------------
+
+
+def _sha(a):
+    a = np.ascontiguousarray(np.asarray(a))
+    h = hashlib.sha256(f"{a.dtype}{a.shape}".encode() + a.tobytes())
+    return [list(a.shape), h.hexdigest()[:16]]
+
+
+def golden_entry(variant, n_cfg, mesh):
+    """One run's result arrays as ``{name: [shape, sha256]}`` per config
+    point.  Uses public entry points only: the digests in the golden
+    file were written by running this function on the parent tree."""
+    from tpudes.parallel.lte_sm import run_lte_sm
+    from tpudes.parallel.replicated import run_replicated_bss
+
+    prog = _prog(variant)
+    RUNTIME.clear()
+    if variant == "bss":
+        ends = None if n_cfg is None else [40_000, 60_000]
+        out = run_replicated_bss(
+            prog, REPLICAS, KEY, mesh=mesh, sim_end_us=ends
+        )
+    else:
+        scheds = None if n_cfg is None else ["pf", "rr"]
+        out = run_lte_sm(
+            prog, KEY, replicas=REPLICAS, mesh=mesh, schedulers=scheds
+        )
+    return [
+        {k: _sha(v) for k, v in sorted(p.items())
+         if isinstance(v, np.ndarray)}
+        for p in (out if isinstance(out, list) else [out])
+    ]
+
+
+def _golden_name(variant, n_cfg, on_mesh):
+    return (f"{variant}.{'solo' if n_cfg is None else 'cfg2'}"
+            f".{'mesh' if on_mesh else 'one'}")
+
+
+@ON_MESH
+@N_CFG
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_run_reproduces_the_parents_result_arrays(variant, n_cfg, on_mesh):
+    want = json.loads(_GOLDEN.read_text())[
+        _golden_name(variant, n_cfg, on_mesh)
+    ]
+    got = golden_entry(variant, n_cfg, _mesh(on_mesh))
+    assert len(got) == (1 if n_cfg is None else n_cfg)
+    assert all(len(point) >= 4 for point in got)
+    assert got == want
+
+
+# --- one program per runner and mesh -----------------------------------------
+
+
+def test_init_program_is_made_once_per_runner_and_mesh():
+    from tpudes.parallel.replicated import run_replicated_bss
+
+    mesh = _mesh(True)
+    prog = _prog("bss")
+    RUNTIME.clear()
+    n0 = RUNTIME.stats()["init_programs"]
+    run_replicated_bss(prog, REPLICAS, KEY)
+    assert RUNTIME.stats()["init_programs"] == n0 + 1
+    run_replicated_bss(prog, REPLICAS, jax.random.PRNGKey(1))
+    assert RUNTIME.stats()["init_programs"] == n0 + 1
+    # the runner (and its advance program) is mesh-independent: the
+    # same entry serves the mesh launch, only the init program is new
+    misses = RUNTIME.stats()["misses"]
+    run_replicated_bss(prog, REPLICAS, KEY, mesh=mesh)
+    run_replicated_bss(prog, REPLICAS, KEY, mesh=mesh)
+    assert RUNTIME.stats()["init_programs"] == n0 + 2
+    assert RUNTIME.stats()["misses"] == misses
+    # no replica axis, nothing to shard: a mesh makes no second program
+    from tpudes.parallel.runtime import jit_init
+
+    init = jit_init("toy", lambda k: (k, jnp.zeros((3,))), None, (0, None))
+    n1 = RUNTIME.stats()["init_programs"]
+    a, _ = init(None, KEY)
+    b, _ = init(mesh, KEY)
+    assert RUNTIME.stats()["init_programs"] == n1 + 1
+    assert a.sharding.is_equivalent_to(b.sharding, 1)
+
+
+def test_init_program_compiles_under_its_own_name():
+    from tpudes.obs.device import CompileTelemetry
+    from tpudes.parallel.lift import run_lifted
+
+    CompileTelemetry.listen()
+    RUNTIME.clear()
+    t0 = max([e[0] for e in CompileTelemetry.xla_events()], default=0.0)
+    run_lifted("bss", _prog("bss"), REPLICAS, KEY)
+    run_lifted("lte_sm", _prog("lte_base"), REPLICAS, KEY)
+    compiled = [
+        e[3] for e in CompileTelemetry.xla_events()
+        if e[0] > t0 and e[1].endswith("backend_compile_duration")
+    ]
+    assert "jit(tpudes_bss_init)" in compiled
+    assert "jit(tpudes_lte_sm_init)" in compiled
+
+
+if __name__ == "__main__":
+    # python tests/test_launch_init.py > tests/golden/launch_init_parent.json
+    # (run from the PARENT tree's root with this file's path)
+    import itertools
+
+    entries = {}
+    for variant, n_cfg, on_mesh in itertools.product(
+        VARIANTS, [None, 2], [False, True]
+    ):
+        entries[_golden_name(variant, n_cfg, on_mesh)] = golden_entry(
+            variant, n_cfg, _mesh(on_mesh)
+        )
+    print(json.dumps(entries, indent=1, sort_keys=True))

@@ -179,6 +179,45 @@ def test_run_lifted_leaves_one_launch_with_children_and_results(kind, block):
     assert runners[1].args["hit"] is True
 
 
+@pytest.mark.parametrize("kind", ["bss", "lte_sm"])
+def test_warm_launches_reuse_the_init_program(kind):
+    """ISSUE 29: the launch carry comes from the runner's cached
+    ``jit_init`` program, so warm launches build nothing, trace
+    nothing and compile nothing on their way to the enqueue."""
+    import time
+
+    from tpudes.parallel.runtime import RUNTIME
+
+    CompileTelemetry.listen()
+    prog, key = _toy(kind), jax.random.PRNGKey(7)
+    first = run_lifted(kind, prog, 8, key, block=False)
+    (cold,) = [s for s in spans.snapshot() if s.name == "launch.operands"]
+    assert isinstance(cold.args["init_cached"], bool)
+    first.result()
+    spans.reset()
+    before, t0 = RUNTIME.stats(), time.perf_counter()
+    futs = [
+        run_lifted(kind, prog, 8, jax.random.PRNGKey(i), block=False)
+        for i in range(3)
+    ]
+    events = CompileTelemetry.xla_events(since=t0)
+    after = RUNTIME.stats()
+    assert after["init_programs"] == before["init_programs"]
+    assert after["launches"][kind] == before["launches"][kind] + 3
+    assert after["misses"] == before["misses"]
+    assert [e for e in events if e[1].endswith("jaxpr_trace_duration")] == []
+    assert [e for e in events
+            if e[1].endswith("backend_compile_duration")] == []
+    ring = spans.snapshot()
+    for fut in futs:
+        (operands,) = [
+            s for s in ring
+            if s.name == "launch.operands" and s.parent == fut.launch_id
+        ]
+        assert operands.args["init_cached"] is True
+        assert fut.result() is not None
+
+
 def test_direct_engine_call_records_children_without_a_launch():
     from tpudes.parallel.replicated import run_replicated_bss
 

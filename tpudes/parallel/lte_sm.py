@@ -1022,9 +1022,6 @@ def _run_lte_sm_traffic(
         chunk_bounds,
         drive_chunks,
         finalize_with_flush,
-        replica_keys,
-        shard_replica_axis,
-        stack_axis,
         unstack_points,
     )
     from tpudes.traffic.device import TRAFFIC_KEY_TAG
@@ -1040,9 +1037,11 @@ def _run_lte_sm_traffic(
             prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
             use_pallas=use_pallas,
         )
-        return init_carry, jit_advance("lte_sm", fn)
+        return _sm_jit_init(init_carry, r_pad, n_cfg), jit_advance(
+            "lte_sm", fn
+        )
 
-    (init_carry, fn), compiling = RUNTIME.runner(
+    (init, fn), compiling = RUNTIME.runner(
         "lte_sm",
         lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas)
         + ("traffic",),
@@ -1052,20 +1051,10 @@ def _run_lte_sm_traffic(
     sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
     sids = [SM_SCHED_IDS[s] for s in sched_names]
     with span("launch.operands"):
-        sid = (
-            jnp.int32(sids[0]) if n_cfg is None
-            else jnp.asarray(sids, jnp.int32)
-        )
-        keys = key if r_pad is None else shard_replica_axis(
-            replica_keys(key, r_pad), mesh, r_pad, 0
-        )
+        sid = _sm_sid_operand(sids, n_cfg)
+        keys, carry = init(mesh, key)
         tr = prog.traffic.operands()
         tr_key = jax.random.fold_in(key, TRAFFIC_KEY_TAG)
-
-        t0, s0 = init_carry()
-        s0 = stack_axis(stack_axis(s0, r_pad), n_cfg)
-        s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
-        carry = (t0, s0)
 
     ckpt = checkpoint_ctx(
         checkpoint, engine="lte_sm", key=key, replicas=replicas,
@@ -1080,7 +1069,7 @@ def _run_lte_sm_traffic(
             chunk_bounds(prog.n_ttis, chunk_ttis or prog.n_ttis),
             carry,
             lambda c, t_end: fn(
-                c, keys, sid, jnp.int32(t_end), tr, tr_key
+                c, keys, sid, np.int32(t_end), tr, tr_key
             ),
             obs,
             checkpoint=ckpt,
@@ -1205,9 +1194,6 @@ def _run_lte_sm_mobile(
         chunk_bounds,
         drive_chunks,
         finalize_with_flush,
-        replica_keys,
-        shard_replica_axis,
-        stack_axis,
         unstack_points,
     )
 
@@ -1225,9 +1211,11 @@ def _run_lte_sm_mobile(
             prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
             use_pallas=use_pallas,
         )
-        return init_carry, jit_advance("lte_sm", fn)
+        return _sm_jit_init(init_carry, r_pad, n_cfg), jit_advance(
+            "lte_sm", fn
+        )
 
-    (init_carry, fn), compiling = RUNTIME.runner(
+    (init, fn), compiling = RUNTIME.runner(
         "lte_sm",
         lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas)
         + ("mobile", dg_on, k_ref),
@@ -1237,13 +1225,8 @@ def _run_lte_sm_mobile(
     sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
     sids = [SM_SCHED_IDS[s] for s in sched_names]
     with span("launch.operands"):
-        sid = (
-            jnp.int32(sids[0]) if n_cfg is None
-            else jnp.asarray(sids, jnp.int32)
-        )
-        keys = key if r_pad is None else shard_replica_axis(
-            replica_keys(key, r_pad), mesh, r_pad, 0
-        )
+        sid = _sm_sid_operand(sids, n_cfg)
+        keys, carry = init(mesh, key)
         mob_ops = prog.mobility.operands()
         pos_table = None
         if k_ref is not None:
@@ -1260,11 +1243,6 @@ def _run_lte_sm_mobile(
                 jnp.float32,
             )
 
-        t0, g0, s0 = init_carry()
-        s0 = stack_axis(stack_axis(s0, r_pad), n_cfg)
-        s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
-        carry = (t0, g0, s0)
-
     from tpudes.parallel.checkpoint import checkpoint_ctx
 
     ckpt = checkpoint_ctx(
@@ -1280,8 +1258,8 @@ def _run_lte_sm_mobile(
             chunk_bounds(prog.n_ttis, chunk_ttis or prog.n_ttis),
             carry,
             lambda c, t_end: fn(
-                c, keys, sid, jnp.int32(t_end), mob_ops,
-                jnp.int32(stride), pos_table,
+                c, keys, sid, np.int32(t_end), mob_ops,
+                np.int32(stride), pos_table,
             ),
             obs,
             checkpoint=ckpt,
@@ -1341,21 +1319,61 @@ def _sm_use_pallas(mesh) -> bool:
     return pallas_enabled() and mesh is None
 
 
+def _sm_sid_operand(sids, n_cfg):
+    """The traced scheduler id(s) as a host value: numpy gives the
+    jitted call the aval ``jnp.int32`` gave it, without an eager
+    transfer first."""
+    return np.int32(sids[0]) if n_cfg is None else np.asarray(sids, np.int32)
+
+
+def _sm_jit_init(init_carry, r_pad, n_cfg):
+    """``lte_sm``'s one way to make a launch's keys and initial carry:
+    the :func:`~tpudes.parallel.runtime.jit_init` program all three
+    runners (plain, traffic, mobile) build beside their advance
+    program and keep in the same ``RUNTIME.runner`` entry.
+
+    ``init_carry()`` is a builder's un-jitted ``(t0, *shared, s)``:
+    the scalar clock, what every lane shares (the mobile runner's
+    geometry rows), and LAST one lane's state dict.  ``init(mesh, key)``
+    returns ``(keys, carry)``: ``keys`` the ``(r_pad, 2)``
+    ``fold_in(key, i)`` rows (``key`` itself without a replica axis),
+    ``carry`` the same tuple with the state stacked on
+    ``(n_cfg,) (r_pad,)``; on a mesh keys and state come out sharded
+    over "replica" and the rest replicated."""
+    from tpudes.parallel.runtime import jit_init, replica_keys, stack_axis
+
+    def parts(key):
+        *shared, s = init_carry()
+        keys = key if r_pad is None else replica_keys(key, r_pad)
+        return keys, tuple(shared), stack_axis(stack_axis(s, r_pad), n_cfg)
+
+    init = jit_init(
+        "lte_sm", parts, r_pad, (0, None, 0 if n_cfg is None else 1)
+    )
+
+    def launch_init(mesh, key):
+        keys, shared, s0 = init(mesh, key)
+        return keys, (*shared, s0)
+
+    return launch_init
+
+
 def _sm_launch(prog: LteSmProgram, key, replicas, mesh, schedulers):
     """The plain runner's launch set-up — cached runner + the exact
     operands it is called with — shared by :func:`run_lte_sm` and
     :func:`compiled_step_lowering` so the inspector reads the very
-    executable a run dispatches."""
+    executable a run dispatches.  Keys and carry come from the entry's
+    :func:`_sm_jit_init` program (one executable, outputs already on
+    the mesh); no launch of this module calls the eager
+    ``replica_keys`` / ``init_state()`` / ``stack_axis`` /
+    ``shard_replica_axis`` sequence any more.  ``build_sm_advance``
+    still returns the un-jitted ``init_state`` for the trace manifest,
+    and ``checkpoint.py`` still shards a restored carry with
+    ``shard_replica_axis``."""
     from types import SimpleNamespace
 
     from tpudes.obs.device import device_metrics_enabled
-    from tpudes.parallel.runtime import (
-        RUNTIME,
-        bucket_replicas,
-        replica_keys,
-        shard_replica_axis,
-        stack_axis,
-    )
+    from tpudes.parallel.runtime import RUNTIME, bucket_replicas
 
     r_pad = bucket_replicas(replicas, mesh)
     n_cfg = None if schedulers is None else len(schedulers)
@@ -1367,9 +1385,14 @@ def _sm_launch(prog: LteSmProgram, key, replicas, mesh, schedulers):
             prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
             use_pallas=use_pallas,
         )
-        return consts, init_state, jit_advance("lte_sm", fn)
+        # the clock is one scalar for every lane (replicated on a
+        # mesh); only the state is stacked and sharded
+        init = _sm_jit_init(
+            lambda: (jnp.int32(0), init_state()), r_pad, n_cfg
+        )
+        return consts, init, jit_advance("lte_sm", fn)
 
-    (consts, init_state, fn), compiling = RUNTIME.runner(
+    (consts, init, fn), compiling = RUNTIME.runner(
         "lte_sm",
         lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas),
         build,
@@ -1378,21 +1401,8 @@ def _sm_launch(prog: LteSmProgram, key, replicas, mesh, schedulers):
     sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
     sids = [SM_SCHED_IDS[s] for s in sched_names]
     with span("launch.operands"):
-        sid = (
-            jnp.int32(sids[0]) if n_cfg is None
-            else jnp.asarray(sids, jnp.int32)
-        )
-        if r_pad is None:
-            keys = key
-        else:
-            keys = shard_replica_axis(
-                replica_keys(key, r_pad), mesh, r_pad, 0
-            )
-        # the clock is one scalar for every lane (replicated on a
-        # mesh); only the state is stacked and sharded
-        s0 = stack_axis(stack_axis(init_state(), r_pad), n_cfg)
-        s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
-        carry = (jnp.int32(0), s0)
+        sid = _sm_sid_operand(sids, n_cfg)
+        keys, carry = init(mesh, key)
     return SimpleNamespace(
         consts=consts, fn=fn, carry=carry, keys=keys, sid=sid, sids=sids,
         r_pad=r_pad, n_cfg=n_cfg, obs=obs, compiling=compiling,
@@ -1410,7 +1420,7 @@ def compiled_step_lowering(prog: LteSmProgram, key, replicas=None,
     this is a compile-cache hit, not a second compile."""
     L = _sm_launch(prog, key, replicas, mesh, schedulers)
     text = L.fn.lower(
-        L.carry, L.keys, L.sid, jnp.int32(prog.n_ttis)
+        L.carry, L.keys, L.sid, np.int32(prog.n_ttis)
     ).compile().as_text()
     return "mosaic" if "tpu_custom_call" in text else "xla"
 
@@ -1511,7 +1521,7 @@ def run_lte_sm(
             "lte_sm",
             chunk_bounds(prog.n_ttis, chunk_ttis or prog.n_ttis),
             L.carry,
-            lambda c, t_end: L.fn(c, L.keys, L.sid, jnp.int32(t_end)),
+            lambda c, t_end: L.fn(c, L.keys, L.sid, np.int32(t_end)),
             obs,
             checkpoint=ckpt,
         )

@@ -1222,11 +1222,25 @@ def _compiled_bss_runner(
     from the input arrays and jax.jit specializes per input sharding
     internally — so mesh is not part of the key.
 
-    Returns ``(init_state, pending, run, compiled_new)`` —
-    ``compiled_new`` tells the caller this call populated the cache (the
-    compile-telemetry trigger), so the cache key is derived in exactly
-    one place."""
-    from tpudes.parallel.runtime import RUNTIME, jit_advance
+    Returns ``(init, pending, run, compiled_new)``.  ``init(mesh)`` is
+    the entry's :func:`~tpudes.parallel.runtime.jit_init` program: it
+    returns ``(s0,)``, the launch's whole initial carry
+    (``init_state()`` stacked over the config axis) from ONE
+    executable, placed on ``mesh`` by the program itself; only it is
+    keyed by mesh, inside the entry.  Nothing here calls the eager
+    ``init_state()`` → ``stack_axis`` → ``shard_replica_axis`` chain
+    any more (a dispatched program per leaf per step): the un-jitted
+    ``init_state`` remains for the trace manifest
+    (:func:`trace_manifest`) and ``shard_replica_axis`` for a restored
+    checkpoint's carry.  ``compiled_new`` tells the caller this call
+    populated the cache (the compile-telemetry trigger), so the cache
+    key is derived in exactly one place."""
+    from tpudes.parallel.runtime import (
+        RUNTIME,
+        jit_advance,
+        jit_init,
+        stack_axis,
+    )
 
     del mesh
 
@@ -1237,16 +1251,19 @@ def _compiled_bss_runner(
             prog, replicas, obs=obs, n_cfg=n_cfg,
             geom_per_step=geom_per_step, sweep=sweep,
         )
-        run = jit_advance("bss", fn)
-        return init_state, pending, run
+        init = jit_init(
+            "bss", lambda: (stack_axis(init_state(), n_cfg),),
+            replicas, (0 if n_cfg is None else 1,),
+        )
+        return init, pending, jit_advance("bss", fn)
 
-    (init_state, pending, run), compiled_new = RUNTIME.runner(
+    (init, pending, run), compiled_new = RUNTIME.runner(
         "bss",
         lambda: (_prog_cache_key(prog), replicas, obs, n_cfg, mobile,
                  geom_per_step, sweep if n_cfg is not None else None),
         build,
     )
-    return init_state, pending, run, compiled_new
+    return init, pending, run, compiled_new
 
 
 def _bss_unpack(host: dict, replicas: int, obs: bool, prog=None) -> dict:
@@ -1405,8 +1422,6 @@ def run_replicated_bss(
         chunk_bounds,
         drive_chunks,
         finalize_with_flush,
-        shard_replica_axis,
-        stack_axis,
         unstack_points,
     )
 
@@ -1444,7 +1459,7 @@ def run_replicated_bss(
     # replica's state is a fixed point of step_fn, so the extra loop
     # iterations the padding may cause cannot corrupt real replicas)
     r_pad = bucket_replicas(replicas, mesh)
-    init_state, pending, run, compiling = _compiled_bss_runner(
+    init, pending, run, compiling = _compiled_bss_runner(
         prog, r_pad, mesh, obs=obs, n_cfg=n_cfg,
         geom_per_step=geom_per_step, sweep=sweep,
     )
@@ -1455,7 +1470,7 @@ def run_replicated_bss(
         geom = (
             None if prog.mobility is None
             else dict(
-                stride=jnp.int32(max(1, int(prog.geom_stride))),
+                stride=np.int32(max(1, int(prog.geom_stride))),
                 **prog.mobility.operands(),
             )
         )
@@ -1474,12 +1489,14 @@ def run_replicated_bss(
             tr = stack_traffic_operands(traffic_sweep)
         else:
             tr = None if prog.traffic is None else prog.traffic.operands()
+        # host scalars go to the jitted call as numpy (the same aval
+        # as jnp.int32, without an eager transfer each)
         sim_end = (
-            jnp.int32(ends[0]) if n_cfg is None or sweep == "traffic"
-            else jnp.asarray(ends, jnp.int32)
+            np.int32(ends[0]) if n_cfg is None or sweep == "traffic"
+            else np.asarray(ends, np.int32)
         )
-        s0 = stack_axis(init_state(), n_cfg)
-        s0 = shard_replica_axis(s0, mesh, r_pad, 0 if n_cfg is None else 1)
+        # the whole carry from ONE cached executable, already sharded
+        (s0,) = init(mesh)
 
     with CompileTelemetry.timed("bss", compiling):
         def launch(carry, bound):
@@ -1487,7 +1504,7 @@ def run_replicated_bss(
             # the step bound; finished replicas are a fixed point of
             # step_fn, so later segments cost one cond evaluation
             state, still_pending, metrics = run(
-                carry[0], key, jnp.int32(bound), sim_end, geom, tr
+                carry[0], key, np.int32(bound), sim_end, geom, tr
             )
             return (state, still_pending), metrics
 

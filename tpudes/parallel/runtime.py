@@ -77,7 +77,9 @@ conventions.  This module is the one runtime they all route through:
   ``launch.enqueue`` in :func:`drive_chunks`, ``result.wait`` /
   ``.fetch`` / ``.unpack`` in :class:`EngineFuture`, whose constructor
   also ends the ``launch`` span ``run_lifted`` opened (each ``run_*``
-  adds ``launch.operands`` around its own carry set-up).
+  adds ``launch.operands`` around its own carry set-up, which for the
+  engines the benchmark runs is one call of a :func:`jit_init`
+  program).
   :func:`scoped_while_loop` gives every engine's outermost loop the
   stable device names ``tpudes.<engine>.step`` / ``.cond``.
 """
@@ -102,6 +104,7 @@ __all__ = [
     "finalize_with_flush",
     "inflight_window",
     "jit_advance",
+    "jit_init",
     "pow2_bucket",
     "replica_keys",
     "scoped_while_loop",
@@ -276,7 +279,11 @@ def unstack_points(n_cfg: int | None, unpack_one, shared=()):
 def stack_axis(tree, n: int | None):
     """Broadcast every leaf of ``tree`` to a new leading axis of size
     ``n`` (None passes through) — how the engines stack the initial
-    carry over the replica and config axes."""
+    carry over the replica and config axes.  One ``broadcast_to`` per
+    leaf: ``bss`` and ``lte_sm`` call it only while their
+    :func:`jit_init` program is traced; ``dumbbell`` and ``as_flows``
+    still call it eagerly on every launch, a dispatched program per
+    leaf (ROADMAP C2 folds their set-ups into the same form)."""
     if n is None:
         return tree
     import jax
@@ -287,22 +294,37 @@ def stack_axis(tree, n: int | None):
     )
 
 
+def _replica_spec(v, r_pad: int, axis: int):
+    """The one rule for which leaves carry the replica axis: dimension
+    ``axis`` exists and equals ``r_pad``.  Returns the leaf's
+    PartitionSpec with that dimension on "replica", else None."""
+    from jax.sharding import PartitionSpec as P
+
+    if getattr(v, "ndim", 0) > axis and v.shape[axis] == r_pad:
+        return P(*([None] * axis), "replica",
+                 *([None] * (v.ndim - axis - 1)))
+    return None
+
+
 def shard_replica_axis(tree, mesh, r_pad: int | None, axis: int):
     """device_put every leaf whose ``axis`` dimension equals ``r_pad``
     with that dimension sharded over the mesh's "replica" axis (other
     leaves pass through).  ``axis`` is 0 for plain runs, 1 when a
-    config axis leads."""
+    config axis leads.  One transfer per leaf: a :func:`jit_init`
+    program places its outputs by the same rule with no transfer, so
+    what still comes here are arrays that exist already — a restored
+    checkpoint's carry (``checkpoint.py``) and the eager set-ups of
+    ``dumbbell``, ``wired`` and ``as_flows`` (ROADMAP C2)."""
     if mesh is None or r_pad is None:
         return tree
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import NamedSharding
 
     def put(v):
-        if getattr(v, "ndim", 0) > axis and v.shape[axis] == r_pad:
-            spec = P(*([None] * axis), "replica",
-                     *([None] * (v.ndim - axis - 1)))
-            return jax.device_put(v, NamedSharding(mesh, spec))
-        return v
+        spec = _replica_spec(v, r_pad, axis)
+        return v if spec is None else jax.device_put(
+            v, NamedSharding(mesh, spec)
+        )
 
     return jax.tree_util.tree_map(put, tree)
 
@@ -335,6 +357,19 @@ def scoped_while_loop(engine: str, cond, body, init):
     return jax.lax.while_loop(scoped("cond", cond), scoped("step", body), init)
 
 
+def _named(fn, name: str):
+    """``fn`` under the program name ``name`` (what ``jax.jit`` calls
+    the executable, see :func:`jit_advance`)."""
+    import functools
+
+    @functools.wraps(fn)
+    def named(*args):
+        return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
 def jit_advance(engine: str, fn):
     """The engines' one way to jit an advance function: the carry
     (argument 0) donated on accelerators, and the program NAMED
@@ -344,16 +379,96 @@ def jit_advance(engine: str, fn):
     operation metadata: without it an executable cached before
     :func:`scoped_while_loop` existed is served again with its old,
     scope-less operation names (seen on the chip, PERF.md PR 25)."""
-    import functools
-
     import jax
 
-    @functools.wraps(fn)
-    def named(*args):
-        return fn(*args)
+    return jax.jit(
+        _named(fn, f"tpudes_{engine}_advance"),
+        donate_argnums=donate_argnums(0),
+    )
 
-    named.__name__ = named.__qualname__ = f"tpudes_{engine}_advance"
-    return jax.jit(named, donate_argnums=donate_argnums(0))
+
+class InitProgram:
+    """What :func:`jit_init` returns: ``init(mesh, *args)`` runs the
+    engine's carry builder as ONE executable and returns its parts.
+    The jitted program is made on the first call for each mesh (None
+    included) and kept here, in the runner's cache entry; the advance
+    program beside it stays mesh-independent."""
+
+    __slots__ = ("_fn", "_r_pad", "_axes", "_by_mesh")
+
+    def __init__(self, fn, r_pad, axes):
+        self._fn = fn
+        self._r_pad = r_pad
+        self._axes = tuple(axes)
+        self._by_mesh: dict = {}
+
+    def _jit(self, mesh, args):
+        import jax
+
+        if mesh is None:
+            return jax.jit(self._fn)
+        import functools
+
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        def place(axis, v):
+            spec = (
+                None if axis is None
+                else _replica_spec(v, self._r_pad, axis)
+            )
+            return NamedSharding(mesh, P() if spec is None else spec)
+
+        parts = jax.eval_shape(self._fn, *args)
+        return jax.jit(
+            self._fn,
+            out_shardings=tuple(
+                jax.tree_util.tree_map(functools.partial(place, axis), part)
+                for axis, part in zip(self._axes, parts, strict=True)
+            ),
+        )
+
+    def __call__(self, mesh, *args):
+        if self._r_pad is None:
+            mesh = None        # no replica axis: nothing to shard
+        prog = self._by_mesh.get(mesh)
+        cached = prog is not None
+        if not cached:
+            prog = self._by_mesh[mesh] = self._jit(mesh, args)
+            RUNTIME.init_programs += 1
+        operands = spans.current()
+        if operands is not None and operands.name == "launch.operands":
+            operands.args["init_cached"] = cached
+        return prog(*args)
+
+
+def jit_init(engine: str, fn, r_pad: int | None, axes) -> InitProgram:
+    """The engines' one way to make a launch's initial carry: ``fn``,
+    the engine's carry builder (``init_state()``, :func:`stack_axis`
+    over the replica and config axes, :func:`replica_keys`), jitted
+    under the program name ``tpudes_<engine>_init`` and built once per
+    runner, inside its ``build()``, so that a warm launch dispatches
+    two executables (init, advance) where the eager form dispatched
+    one per leaf per operation (33 a BSS launch, 50 an LTE launch, 68
+    on a four-chip mesh: 10 to 37 ms of host time with the chip idle,
+    PERF.md PR 25).
+
+    ``fn(*args)`` returns a TUPLE of parts; its only runtime argument
+    is the run key (the BSS builder takes none), shapes are static and
+    already in the runner's cache key.  ``axes`` names, per part, the
+    dimension that carries the replica axis in that part's leaves
+    (None: the part has none).  On a mesh the program places its
+    outputs itself, by :func:`shard_replica_axis`'s rule: a leaf of a
+    part with an axis whose dimension there equals ``r_pad`` is
+    sharded over "replica", every other leaf is replicated, so each
+    chip makes its own shard and no ``device_put`` follows.  Without a
+    mesh the outputs are uncommitted single-device arrays, exactly
+    what the eager ``jnp`` calls gave, so the advance program sees the
+    avals and shardings it was compiled for.
+
+    ``RUNTIME.stats()["init_programs"]`` counts the executables made
+    (one per runner and mesh); the ``launch.operands`` span open
+    around the call gets ``args.init_cached``."""
+    return InitProgram(_named(fn, f"tpudes_{engine}_init"), r_pad, axes)
 
 
 def configure_persistent_cache() -> str | None:
@@ -491,6 +606,7 @@ class EngineRuntime:
         self._runners: OrderedDict[tuple, object] = OrderedDict()
         self.hits = 0
         self.misses = 0
+        self.init_programs = 0
         self._cache_wired = False
         self._inflight: list[EngineFuture] = []
         self.submitted = 0
@@ -620,6 +736,9 @@ class EngineRuntime:
         return {
             "hits": self.hits,
             "misses": self.misses,
+            # jit_init executables made: one per runner and mesh, so a
+            # warm window leaves it alone while `launches` grows
+            "init_programs": self.init_programs,
             "resident": len(self._runners),
             "per_engine": per_engine,
             "submitted": self.submitted,
