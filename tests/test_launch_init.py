@@ -1,14 +1,18 @@
 """The launch carry comes from one jitted init program per runner
-(``runtime.jit_init``, ISSUE 29).
+(``runtime.jit_init``, ISSUE 29), on the one launch path of every
+engine (``runtime.Launch``, ISSUE 30).
 
 - leaf for leaf, in value, dtype, shape and placement, the init
-  program gives what the eager chain it replaced gave
-  (``replica_keys``; ``init_state()`` -> ``stack_axis`` ->
-  ``shard_replica_axis``): BSS and the three LTE runners, with and
-  without a config axis, on one device and on a 1-axis replica mesh;
-- a run through the init program reproduces, bit for bit, the result
-  arrays that the PARENT of ISSUE 29 (eager carry) wrote into
-  ``golden/launch_init_parent.json``;
+  program gives the prepared launch what the eager chain it replaced
+  gave (``replica_keys``, per-replica draws; ``init_state()`` ->
+  ``stack_axis`` -> ``shard_replica_axis``): BSS, the three LTE forms,
+  the dumbbell, AS flows and wired, with and without a config axis, on
+  one device and on a 1-axis replica mesh;
+- a run through the one path reproduces, bit for bit, the result
+  arrays that the PARENT of the issue that moved the engine wrote into
+  ``golden/launch_init_parent.json`` (ISSUE 29: eager carry) and
+  ``golden/launch_path_parent.json`` (ISSUE 30: eager carry, own copy
+  of the run contract);
 - the program is made once per runner and mesh and counted
   (``RUNTIME.stats()["init_programs"]``).
 """
@@ -25,6 +29,7 @@ import pytest
 
 from tpudes.parallel.runtime import (
     RUNTIME,
+    Launch,
     bucket_replicas,
     replica_keys,
     shard_replica_axis,
@@ -33,11 +38,34 @@ from tpudes.parallel.runtime import (
 
 KEY = jax.random.PRNGKey(29)
 REPLICAS = 4
-VARIANTS = ("bss", "lte_base", "lte_traffic", "lte_mobile")
-N_CFG = pytest.mark.parametrize("n_cfg", [None, 2], ids=["solo", "cfg2"])
-ON_MESH = pytest.mark.parametrize("on_mesh", [False, True],
-                                  ids=["one", "mesh"])
-_GOLDEN = Path(__file__).parent / "golden" / "launch_init_parent.json"
+VARIANTS_29 = ("bss", "lte_base", "lte_traffic", "lte_mobile")
+VARIANTS_30 = ("dumbbell", "as_flows", "wired")
+VARIANTS = VARIANTS_29 + VARIANTS_30
+# `wired` has no config axis: its cases are solo only
+CASES = pytest.mark.parametrize(
+    "variant,n_cfg,on_mesh",
+    [
+        pytest.param(
+            v, c, m,
+            id=f"{v}-{'solo' if c is None else 'cfg2'}"
+               f"-{'mesh' if m else 'one'}",
+        )
+        for m in (False, True)
+        for c in (None, 2)
+        for v in VARIANTS
+        if not (v == "wired" and c is not None)
+    ],
+)
+_GOLDEN_DIR = Path(__file__).parent / "golden"
+# written by ``__main__`` below on the PARENT tree of the issue named:
+# 29 put the first four variants on the init program, 30 the rest
+_GOLDEN = {
+    **dict.fromkeys(VARIANTS_29, _GOLDEN_DIR / "launch_init_parent.json"),
+    **dict.fromkeys(VARIANTS_30, _GOLDEN_DIR / "launch_path_parent.json"),
+}
+#: `wired` runs windowed and from a replica offset, so that the
+#: offset's way into the init program is part of what is pinned
+WIRED_KW = dict(window_slots=16, replica_offset=3)
 
 
 def _mesh(on_mesh):
@@ -60,6 +88,18 @@ def _prog(variant):
         return lte_sm._trace_prog()
     if variant == "lte_traffic":
         return lte_sm._trace_traffic_prog()
+    if variant == "dumbbell":
+        from tpudes.parallel.programs import toy_dumbbell_program
+
+        return toy_dumbbell_program(n_flows=3, n_slots=120)
+    if variant == "as_flows":
+        from tpudes.parallel import as_flows
+
+        return as_flows._trace_prog()
+    if variant == "wired":
+        from tpudes.parallel import wired
+
+        return wired._trace_prog()
     from tpudes.ops.mobility import MobilityProgram
 
     base = lte_sm._trace_prog()
@@ -73,54 +113,120 @@ def _prog(variant):
     )
 
 
-def _init_and_eager(variant, n_cfg, mesh):
-    """``(got, want)``: what the runner's init program returns, and the
-    eager chain of the parent on the same builder's ``init_state``."""
-    from tpudes.parallel import lte_sm, replicated
+def _run(variant, n_cfg, mesh):
+    """The variant's launch through its public entry."""
+    from tpudes.parallel.as_flows import run_as_flows
+    from tpudes.parallel.lte_sm import run_lte_sm
+    from tpudes.parallel.replicated import run_replicated_bss
+    from tpudes.parallel.tcp_dumbbell import run_tcp_dumbbell
+    from tpudes.parallel.wired import run_wired
+
+    prog = _prog(variant)
+    if variant == "bss":
+        ends = None if n_cfg is None else [40_000, 60_000]
+        return run_replicated_bss(
+            prog, REPLICAS, KEY, mesh=mesh, sim_end_us=ends
+        )
+    if variant == "dumbbell":
+        points = None if n_cfg is None else [[0, 1, 2], [5, 9, 16]]
+        return run_tcp_dumbbell(
+            prog, KEY, REPLICAS, mesh=mesh, variants=points
+        )
+    if variant == "as_flows":
+        scales = None if n_cfg is None else [0.5, 2.0]
+        return run_as_flows(
+            prog, KEY, REPLICAS, mesh=mesh, rate_scale=scales
+        )
+    if variant == "wired":
+        assert n_cfg is None
+        return run_wired(prog, KEY, REPLICAS, mesh=mesh, **WIRED_KW)
+    scheds = None if n_cfg is None else ["pf", "rr"]
+    return run_lte_sm(
+        prog, KEY, replicas=REPLICAS, mesh=mesh, schedulers=scheds
+    )
+
+
+def _prepared(variant, n_cfg, mesh, monkeypatch):
+    """The launch of ``_run`` stopped after the runtime's prepare step:
+    the :class:`Launch` with the runner, the carry and the operands the
+    run would be driven with."""
+    monkeypatch.setattr(Launch, "drive", lambda self, *a, **kw: self)
+    return _run(variant, n_cfg, mesh)
+
+
+def _init_and_eager(variant, n_cfg, mesh, monkeypatch):
+    """``(got, want)``: what the runner's init program gave the
+    prepared launch, and the eager chain (``init_state()`` ->
+    ``stack_axis`` -> ``shard_replica_axis``, ``replica_keys``) on the
+    same builder's un-jitted ``init_state``."""
+    from tpudes.parallel import as_flows, lte_sm, replicated
+    from tpudes.parallel import tcp_dumbbell, wired
 
     prog = _prog(variant)
     r_pad = bucket_replicas(REPLICAS, mesh)
     axis = 0 if n_cfg is None else 1
+    L = _prepared(variant, n_cfg, mesh, monkeypatch)
 
     def shard(tree, ax):
         return shard_replica_axis(tree, mesh, r_pad, ax)
 
     if variant == "bss":
-        init, _, _, _ = replicated._compiled_bss_runner(
-            prog, r_pad, mesh, n_cfg=n_cfg
-        )
         init_state, _, _ = replicated.build_bss_advance(
             prog, r_pad, n_cfg=n_cfg
         )
-        return init(mesh), (shard(stack_axis(init_state(), n_cfg), axis),)
+        return L.carry, (shard(stack_axis(init_state(), n_cfg), axis), None)
+    if variant == "dumbbell":
+        init_state, _ = tcp_dumbbell.build_dumbbell_advance(
+            prog, r_pad, n_cfg=n_cfg
+        )
+        carry = stack_axis((jnp.int32(0), init_state()), n_cfg)
+        return L.carry, shard(carry, axis)
+    if variant == "as_flows":
+        e2, f = 2 * prog.edges.shape[0], len(prog.src)
+        carry = (
+            jnp.int32(0),
+            jnp.zeros((r_pad, e2 + 1), jnp.float32),
+            jnp.zeros((r_pad, f), jnp.float32),
+            jnp.zeros((r_pad, e2), jnp.float32),
+        )
+        z = as_flows._as_replica_draws(prog, KEY, r_pad)
+        return (L.ops[0], L.carry[0]), (
+            shard(z, 0), shard(stack_axis(carry, n_cfg), axis)
+        )
+    if variant == "wired":
+        init_state, _ = wired.build_wired_advance(prog, r_pad)
+        carry = init_state(KEY, WIRED_KW["replica_offset"])
+        no_ingress = jnp.full(
+            (r_pad, carry["hop"].shape[1]), -1, jnp.int32
+        )
+        return (L.carry, L.ops), (
+            shard(carry, 0), (shard(no_ingress, 0), shard(no_ingress, 0))
+        )
     kw = dict(r_pad=r_pad, n_cfg=n_cfg, use_pallas=False)
     if variant == "lte_base":
         _, init_state, _ = lte_sm.build_sm_advance(prog, **kw)
 
         def init_carry():
             return (jnp.int32(0), init_state())
-
-        scheds = None if n_cfg is None else ["pf", "rr"]
-        launch = lte_sm._sm_launch(prog, KEY, REPLICAS, mesh, scheds)
-        got = (launch.keys, launch.carry)
     else:
         build = (
             lte_sm.build_sm_traffic_advance if variant == "lte_traffic"
             else lte_sm.build_sm_mobile_advance
         )
         init_carry, _ = build(prog, **kw)
-        got = lte_sm._sm_jit_init(init_carry, r_pad, n_cfg)(mesh, KEY)
     *shared, s = init_carry()
     s0 = shard(stack_axis(stack_axis(s, r_pad), n_cfg), axis)
-    return got, (shard(replica_keys(KEY, r_pad), 0), (*shared, s0))
+    return (L.ops[0], L.carry), (
+        shard(replica_keys(KEY, r_pad), 0), (*shared, s0)
+    )
 
 
-@ON_MESH
-@N_CFG
-@pytest.mark.parametrize("variant", VARIANTS)
-def test_init_program_gives_the_eager_carry(variant, n_cfg, on_mesh):
+@CASES
+def test_init_program_gives_the_eager_carry(
+    variant, n_cfg, on_mesh, monkeypatch
+):
     mesh = _mesh(on_mesh)
-    got, want = _init_and_eager(variant, n_cfg, mesh)
+    got, want = _init_and_eager(variant, n_cfg, mesh, monkeypatch)
     assert (jax.tree_util.tree_structure(got)
             == jax.tree_util.tree_structure(want))
     got_leaves = jax.tree_util.tree_leaves_with_path(got)
@@ -163,21 +269,8 @@ def golden_entry(variant, n_cfg, mesh):
     """One run's result arrays as ``{name: [shape, sha256]}`` per config
     point.  Uses public entry points only: the digests in the golden
     file were written by running this function on the parent tree."""
-    from tpudes.parallel.lte_sm import run_lte_sm
-    from tpudes.parallel.replicated import run_replicated_bss
-
-    prog = _prog(variant)
     RUNTIME.clear()
-    if variant == "bss":
-        ends = None if n_cfg is None else [40_000, 60_000]
-        out = run_replicated_bss(
-            prog, REPLICAS, KEY, mesh=mesh, sim_end_us=ends
-        )
-    else:
-        scheds = None if n_cfg is None else ["pf", "rr"]
-        out = run_lte_sm(
-            prog, KEY, replicas=REPLICAS, mesh=mesh, schedulers=scheds
-        )
+    out = _run(variant, n_cfg, mesh)
     return [
         {k: _sha(v) for k, v in sorted(p.items())
          if isinstance(v, np.ndarray)}
@@ -190,16 +283,15 @@ def _golden_name(variant, n_cfg, on_mesh):
             f".{'mesh' if on_mesh else 'one'}")
 
 
-@ON_MESH
-@N_CFG
-@pytest.mark.parametrize("variant", VARIANTS)
+@CASES
 def test_run_reproduces_the_parents_result_arrays(variant, n_cfg, on_mesh):
-    want = json.loads(_GOLDEN.read_text())[
+    want = json.loads(_GOLDEN[variant].read_text())[
         _golden_name(variant, n_cfg, on_mesh)
     ]
     got = golden_entry(variant, n_cfg, _mesh(on_mesh))
     assert len(got) == (1 if n_cfg is None else n_cfg)
-    assert all(len(point) >= 4 for point in got)
+    # `wired` returns three arrays, every other engine at least four
+    assert all(len(point) >= 3 for point in got)
     assert got == want
 
 
@@ -253,14 +345,19 @@ def test_init_program_compiles_under_its_own_name():
 
 
 if __name__ == "__main__":
-    # python tests/test_launch_init.py > tests/golden/launch_init_parent.json
-    # (run from the PARENT tree's root with this file's path)
+    # python tests/test_launch_init.py 30 > tests/golden/launch_path_parent.json
+    # (run from the PARENT tree's root with this file's path; the
+    # argument names the issue whose variants are written)
     import itertools
+    import sys
 
     entries = {}
     for variant, n_cfg, on_mesh in itertools.product(
-        VARIANTS, [None, 2], [False, True]
+        VARIANTS_30 if sys.argv[1:] == ["30"] else VARIANTS_29,
+        [None, 2], [False, True],
     ):
+        if variant == "wired" and n_cfg is not None:
+            continue
         entries[_golden_name(variant, n_cfg, on_mesh)] = golden_entry(
             variant, n_cfg, _mesh(on_mesh)
         )

@@ -24,7 +24,12 @@ import pytest
 from tpudes.obs import spans
 from tpudes.obs.device import CompileTelemetry
 from tpudes.parallel.lift import run_lifted
-from tpudes.parallel.programs import toy_bss_program, toy_lte_program
+from tpudes.parallel.programs import (
+    toy_as_program,
+    toy_bss_program,
+    toy_dumbbell_program,
+    toy_lte_program,
+)
 
 LAUNCH_CHILDREN = ["launch.runner", "launch.operands", "launch.enqueue"]
 RESULT_SPANS = ["result.wait", "result.fetch", "result.unpack"]
@@ -40,6 +45,14 @@ def empty_ring():
 def _toy(kind):
     if kind == "bss":
         return toy_bss_program()
+    if kind == "dumbbell":
+        return toy_dumbbell_program(n_flows=2, n_slots=30)
+    if kind == "as_flows":
+        return toy_as_program(n_nodes=12, n_flows=2, spf_rounds=6)
+    if kind == "wired":
+        from tpudes.parallel.wired import wired_chain
+
+        return wired_chain(n_links=3, n_flows=2, n_slots=40, jitter_slots=2)
     return toy_lte_program(n_enb=2, n_ue=3, n_ttis=40)
 
 
@@ -179,11 +192,12 @@ def test_run_lifted_leaves_one_launch_with_children_and_results(kind, block):
     assert runners[1].args["hit"] is True
 
 
-@pytest.mark.parametrize("kind", ["bss", "lte_sm"])
+@pytest.mark.parametrize("kind", ["bss", "lte_sm", "dumbbell", "as_flows"])
 def test_warm_launches_reuse_the_init_program(kind):
-    """ISSUE 29: the launch carry comes from the runner's cached
-    ``jit_init`` program, so warm launches build nothing, trace
-    nothing and compile nothing on their way to the enqueue."""
+    """ISSUE 29, and ISSUE 30 for every engine: the launch carry comes
+    from the runner's cached ``jit_init`` program, so warm launches
+    build nothing, trace nothing and compile nothing on their way to
+    the enqueue."""
     import time
 
     from tpudes.parallel.runtime import RUNTIME
@@ -218,19 +232,38 @@ def test_warm_launches_reuse_the_init_program(kind):
         assert fut.result() is not None
 
 
-def test_direct_engine_call_records_children_without_a_launch():
+@pytest.mark.parametrize(
+    "kind", ["bss", "lte_sm", "dumbbell", "as_flows", "wired"]
+)
+def test_direct_engine_call_records_children_without_a_launch(kind):
+    from tpudes.parallel.as_flows import run_as_flows
+    from tpudes.parallel.lte_sm import run_lte_sm
     from tpudes.parallel.replicated import run_replicated_bss
+    from tpudes.parallel.tcp_dumbbell import run_tcp_dumbbell
+    from tpudes.parallel.wired import run_wired
 
-    fut = run_replicated_bss(
-        toy_bss_program(), 2, jax.random.PRNGKey(3), block=False
-    )
-    assert fut.launch_id is None
+    prog, key = _toy(kind), jax.random.PRNGKey(3)
+    if kind == "bss":
+        fut = run_replicated_bss(prog, 2, key, block=False)
+    else:
+        run = dict(
+            lte_sm=run_lte_sm, dumbbell=run_tcp_dumbbell,
+            as_flows=run_as_flows, wired=run_wired,
+        )[kind]
+        fut = run(prog, key, 2, block=False)
+    assert fut.engine == kind and fut.launch_id is None
     fut.block()
     fut.result()
     ring = spans.snapshot()
     assert "launch" not in [s.name for s in ring]
     for name in LAUNCH_CHILDREN + RESULT_SPANS:
         assert any(s.name == name and s.parent is None for s in ring), name
+    # one of each launch child, in the order of the one launch path
+    assert [s.name for s in ring if s.name in LAUNCH_CHILDREN] == (
+        LAUNCH_CHILDREN
+    )
+    (operands,) = [s for s in ring if s.name == "launch.operands"]
+    assert isinstance(operands.args["init_cached"], bool)
     # block() and result() each waited once
     assert sum(s.name == "result.wait" for s in ring) == 2
 

@@ -682,115 +682,81 @@ def run_as_flows(
     bit-equal to uninterrupted.  ``block=False`` returns an
     :class:`~tpudes.parallel.runtime.EngineFuture`.
     """
-    from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
-    from tpudes.obs.spans import span
-    from tpudes.parallel.checkpoint import checkpoint_ctx
-    from tpudes.parallel.runtime import (
-        RUNTIME,
-        EngineFuture,
-        bucket_replicas,
-        chunk_bounds,
-        drive_chunks,
-        finalize_with_flush,
-        jit_advance,
-        shard_replica_axis,
-        stack_axis,
-        unstack_points,
-    )
+    from tpudes.parallel.runtime import Launch, chunk_bounds, stack_axis
 
-    r_pad = bucket_replicas(replicas, mesh)
     n_cfg = None if rate_scale is None else len(rate_scale)
-    obs = device_metrics_enabled()
+    L = Launch("as_flows", key, replicas, mesh, n_cfg)
+    r_pad = L.r_pad
 
     def build():
-        return jit_advance(
-            "as_flows",
-            build_as_run(prog, r_pad, n_cfg=n_cfg, obs=obs, mesh=mesh),
+        E2 = 2 * prog.edges.shape[0]
+        F = len(prog.src)
+
+        def init(key):
+            carry = (
+                jnp.int32(0),
+                jnp.zeros((r_pad, E2 + 1), jnp.float32),
+                jnp.zeros((r_pad, F), jnp.float32),
+                jnp.zeros((r_pad, E2), jnp.float32),
+            )
+            return (
+                _as_replica_draws(prog, key, r_pad),
+                stack_axis(carry, n_cfg),
+            )
+
+        return (
+            init, (0, L.axis),
+            build_as_run(prog, r_pad, n_cfg=n_cfg, obs=L.obs, mesh=mesh),
+            None,
         )
+
+    def operands(parts):
+        z, carry = parts
+        scale = (
+            np.float32(1.0) if n_cfg is None
+            else np.asarray([float(v) for v in rate_scale], np.float32)
+        )
+        # workload operands (traced; None = the constant-rate path).
+        # The horizon the fluid multiplier averages over is a traced
+        # operand too — sim_s stays out of the cache key even with
+        # traffic on
+        tr = None if prog.traffic is None else prog.traffic.operands()
+        horizon_us = (
+            None if prog.traffic is None
+            else np.int32(min(int(prog.sim_s * 1e6), 2**30 - 1))
+        )
+        return (carry, None), (z, scale, tr, horizon_us)
 
     # prog.sim_s is deliberately ABSENT (see as_prog_key).  mesh IS
     # present: device_spf shards its tables via the mesh closure,
     # unlike the engines whose sharding flows from inputs
-    run, compiling = RUNTIME.runner(
-        "as_flows",
-        lambda: as_prog_key(prog) + (r_pad, mesh, n_cfg, obs),
-        build,
+    L.prepare(
+        lambda: as_prog_key(prog) + (r_pad, mesh, n_cfg, L.obs),
+        build, operands, init_args=(key,),
     )
 
-    with span("launch.operands"):
-        # per-replica jitter draws keyed by fold_in(key, r): replica
-        # r's z-row is independent of the padded axis size, so
-        # bucketing is exact
-        z = shard_replica_axis(
-            _as_replica_draws(prog, key, r_pad), mesh, r_pad, 0
+    def call(run, c, rounds_end, ops):
+        z, scale, tr, horizon_us = ops
+        carry, out, metrics = run(
+            c[0], z, scale, rounds_end, tr, horizon_us
         )
-        scale = (
-            jnp.float32(1.0) if n_cfg is None
-            else jnp.asarray([float(v) for v in rate_scale], jnp.float32)
-        )
-        E2 = 2 * prog.edges.shape[0]
-        F = len(prog.src)
-        carry = (
-            jnp.int32(0),
-            jnp.zeros((r_pad, E2 + 1), jnp.float32),
-            jnp.zeros((r_pad, F), jnp.float32),
-            jnp.zeros((r_pad, E2), jnp.float32),
-        )
-        carry = stack_axis(carry, n_cfg)
-        carry = shard_replica_axis(
-            carry, mesh, r_pad, 0 if n_cfg is None else 1
-        )
+        return (carry, out), metrics
 
-        # workload operands (traced; None = the constant-rate path).  The
-        # horizon the fluid multiplier averages over is a traced operand
-        # too — sim_s stays out of the cache key even with traffic on
-        tr = None if prog.traffic is None else prog.traffic.operands()
-        horizon_us = (
+    return L.drive(
+        call,
+        chunk_bounds(FP_ROUNDS, chunk_rounds or FP_ROUNDS),
+        lambda c: c[1],
+        lambda host: _as_unpack(host, replicas),
+        shared=("hops", "unreachable"),
+        checkpoint=checkpoint,
+        identity=lambda: as_prog_key(prog) + (
+            None if rate_scale is None
+            else tuple(float(v) for v in rate_scale),
             None if prog.traffic is None
-            else jnp.int32(min(int(prog.sim_s * 1e6), 2**30 - 1))
-        )
-
-    with CompileTelemetry.timed("as_flows", compiling):
-        def launch(c, bound):
-            carry, out, metrics = run(
-                c[0], z, scale, jnp.int32(bound), tr, horizon_us
-            )
-            return (carry, out), metrics
-
-        ckpt = checkpoint_ctx(
-            checkpoint, engine="as_flows", key=key, replicas=replicas,
-            r_pad=r_pad, n_cfg=n_cfg, obs=obs,
-            axis=0 if n_cfg is None else 1, mesh=mesh,
-            extra=as_prog_key(prog)
-            + (None if rate_scale is None
-               else tuple(float(v) for v in rate_scale),
-               None if prog.traffic is None
-               else prog.traffic.param_key() + (float(prog.sim_s),)),
-        )
-        (_, out), flush = drive_chunks(
-            "as_flows",
-            chunk_bounds(FP_ROUNDS, chunk_rounds or FP_ROUNDS),
-            (carry, None),
-            launch,
-            obs,
-            checkpoint=ckpt,
-        )
-        if compiling:
-            jax.block_until_ready(out)
-
-    fut = EngineFuture(
-        "as_flows",
-        out,
-        finalize_with_flush(
-            flush,
-            unstack_points(
-                n_cfg,
-                lambda host: _as_unpack(host, replicas),
-                shared=("hops", "unreachable"),
-            ),
+            else prog.traffic.param_key() + (float(prog.sim_s),),
         ),
+        block=block,
     )
-    return fut.result() if block else fut
 
 
 # --- trace manifest (tpudes.analysis.jaxpr) --------------------------------

@@ -220,6 +220,38 @@ def _discover_as_flows(sim_end_s: float):
 LOWERINGS = [_discover_lte_sm, _discover_dumbbell, _discover_bss, _discover_as_flows]
 
 
+def _run_bss(prog, key, replicas, mesh, **engine_kwargs):
+    from tpudes.parallel.replicated import run_replicated_bss
+
+    return run_replicated_bss(
+        prog, replicas, key, mesh=mesh, **engine_kwargs
+    )
+
+
+def _entry(module: str, name: str):
+    """``run_*(prog, key, replicas, mesh, **kw)`` of an engine module,
+    imported at the call (a lifted run loads only its own engine)."""
+
+    def run(prog, key, replicas, mesh, **engine_kwargs):
+        import importlib
+
+        return getattr(importlib.import_module(module), name)(
+            prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
+        )
+
+    return run
+
+
+#: kind (what a lowering of LOWERINGS returns) -> the engine's entry as
+#: ``run(prog, key, replicas, mesh, **engine_kwargs)``
+ENGINES = {
+    "bss": _run_bss,        # run_replicated_bss takes (prog, replicas, key)
+    "lte_sm": _entry("tpudes.parallel.lte_sm", "run_lte_sm"),
+    "dumbbell": _entry("tpudes.parallel.tcp_dumbbell", "run_tcp_dumbbell"),
+    "as_flows": _entry("tpudes.parallel.as_flows", "run_as_flows"),
+}
+
+
 def lift(sim_end_s: float):
     """Try every registered lowering; returns ``(kind, program, commit)``
     — ``commit()`` is called by the engine after the device run succeeds
@@ -288,30 +320,9 @@ def run_lifted(kind: str, prog, replicas: int, key=None, mesh=None,
                     RuntimeWarning,
                     stacklevel=2,
                 )
-        if kind == "bss":
-            from tpudes.parallel.replicated import run_replicated_bss
-
-            return run_replicated_bss(
-                prog, replicas, key, mesh=mesh, **engine_kwargs
-            )
-        if kind == "lte_sm":
-            from tpudes.parallel.lte_sm import run_lte_sm
-
-            return run_lte_sm(
-                prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
-            )
-        if kind == "dumbbell":
-            from tpudes.parallel.tcp_dumbbell import run_tcp_dumbbell
-
-            return run_tcp_dumbbell(
-                prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
-            )
-        if kind == "as_flows":
-            from tpudes.parallel.as_flows import run_as_flows
-
-            return run_as_flows(
-                prog, key, replicas=replicas, mesh=mesh, **engine_kwargs
-            )
-        raise ValueError(f"unknown lifted program kind {kind!r}")
+        run = ENGINES.get(kind)
+        if run is None:
+            raise ValueError(f"unknown lifted program kind {kind!r}")
+        return run(prog, key, replicas, mesh, **engine_kwargs)
     finally:
         launch.close()

@@ -53,7 +53,9 @@ identical lowered scenario:
 
 from __future__ import annotations
 
+from collections.abc import Callable
 from dataclasses import dataclass
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -61,7 +63,6 @@ import numpy as np
 
 from tpudes.fuzz.envelope import FuzzEnvelope
 from tpudes.models.lte.scheduler import SCHEDULERS
-from tpudes.obs.spans import span
 from tpudes.parallel.kernels_pallas import (
     SM_PRECISIONS,
     SM_SCHED_IDS,
@@ -70,7 +71,7 @@ from tpudes.parallel.kernels_pallas import (
     pallas_enabled,
     sm_init_state,
 )
-from tpudes.parallel.runtime import jit_advance, scoped_while_loop
+from tpudes.parallel.runtime import scoped_while_loop
 
 
 class UnliftableLteScenarioError(ValueError):
@@ -832,9 +833,18 @@ def build_sm_mobile_advance(prog: LteSmProgram, r_pad: int | None = None,
                             use_pallas: bool = False):
     """``(init_carry, fn)`` with
     ``fn(carry, keys, sid, t_end, mob_ops, stride_, pos_table)`` the
-    UNJITTED mobile-geometry advance exactly as
-    :func:`_run_lte_sm_mobile` jits it (see that docstring for the
-    unbatched-loop / scalar-geometry-predicate structure)."""
+    UNJITTED mobile-geometry advance exactly as :func:`run_lte_sm`
+    jits it for a program with ``prog.mobility``.
+
+    Structure as in all three builders (:func:`_vmap_lanes`): the TTI
+    ``while_loop`` runs UNBATCHED (scalar clock + the geometry row dict
+    in the carry) and only the per-lane step is vmapped over the
+    replica / config axes inside the body — the trajectory is shared
+    by every replica and config point, so the
+    geometry ``lax.cond`` keeps a SCALAR predicate and the refresh
+    really is skipped on non-stride TTIs (a batched predicate would
+    degrade to select-both-branches under vmap and the stride would
+    save nothing)."""
     consts_np = build_sm_consts(prog)
     fused = build_sm_step_fn(
         consts_np, use_pallas, dynamic=SM_DYNAMIC_ROWS
@@ -894,8 +904,8 @@ def build_sm_traffic_advance(prog: LteSmProgram, r_pad: int | None = None,
                              n_cfg: int | None = None, obs: bool = False,
                              use_pallas: bool = False):
     """``(init_carry, fn)`` with ``fn(carry, keys, sid, t_end, tr)``
-    the UNJITTED finite-backlog advance exactly as
-    :func:`_run_lte_sm_traffic` jits it.
+    the UNJITTED finite-backlog advance exactly as :func:`run_lte_sm`
+    jits it for a program with ``prog.traffic``.
 
     Structure as in all three builders (:func:`_vmap_lanes`): the TTI
     ``while_loop`` runs UNBATCHED and only the per-lane step is vmapped
@@ -994,319 +1004,6 @@ def build_sm_traffic_advance(prog: LteSmProgram, r_pad: int | None = None,
     return init_carry, advance
 
 
-def _run_lte_sm_traffic(
-    prog: LteSmProgram,
-    key,
-    replicas: int | None = None,
-    mesh=None,
-    *,
-    schedulers=None,
-    chunk_ttis: int | None = None,
-    checkpoint=None,
-    block: bool = True,
-):
-    """The finite-backlog form of :func:`run_lte_sm` (same contract,
-    same result fields + per-UE ``backlog_bits``/``offered_bits``).
-    One compiled executable serves the whole workload family AND all
-    nine schedulers at every horizon — model id, traffic params,
-    scheduler id and TTI bound are all traced operands."""
-    import jax.numpy as jnp
-
-    from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
-    from tpudes.obs.traffic import TrafficTelemetry
-    from tpudes.parallel.checkpoint import checkpoint_ctx
-    from tpudes.parallel.runtime import (
-        RUNTIME,
-        EngineFuture,
-        bucket_replicas,
-        chunk_bounds,
-        drive_chunks,
-        finalize_with_flush,
-        unstack_points,
-    )
-    from tpudes.traffic.device import TRAFFIC_KEY_TAG
-    from tpudes.traffic.host import offered_bits_mean
-
-    r_pad = bucket_replicas(replicas, mesh)
-    n_cfg = None if schedulers is None else len(schedulers)
-    obs = device_metrics_enabled()
-    use_pallas = _sm_use_pallas(mesh)
-
-    def build():
-        init_carry, fn = build_sm_traffic_advance(
-            prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
-            use_pallas=use_pallas,
-        )
-        return _sm_jit_init(init_carry, r_pad, n_cfg), jit_advance(
-            "lte_sm", fn
-        )
-
-    (init, fn), compiling = RUNTIME.runner(
-        "lte_sm",
-        lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas)
-        + ("traffic",),
-        build,
-    )
-
-    sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
-    sids = [SM_SCHED_IDS[s] for s in sched_names]
-    with span("launch.operands"):
-        sid = _sm_sid_operand(sids, n_cfg)
-        keys, carry = init(mesh, key)
-        tr = prog.traffic.operands()
-        tr_key = jax.random.fold_in(key, TRAFFIC_KEY_TAG)
-
-    ckpt = checkpoint_ctx(
-        checkpoint, engine="lte_sm", key=key, replicas=replicas,
-        r_pad=r_pad, n_cfg=n_cfg, obs=obs,
-        axis=0 if n_cfg is None else 1, mesh=mesh,
-        extra=_sm_cache_key(prog, None, n_cfg, obs, False)
-        + ("traffic", prog.traffic.param_key(), tuple(sids)),
-    )
-    with CompileTelemetry.timed("lte_sm", compiling):
-        carry, flush = drive_chunks(
-            "lte_sm",
-            chunk_bounds(prog.n_ttis, chunk_ttis or prog.n_ttis),
-            carry,
-            lambda c, t_end: fn(
-                c, keys, sid, np.int32(t_end), tr, tr_key
-            ),
-            obs,
-            checkpoint=ckpt,
-        )
-        if compiling:
-            jax.block_until_ready(carry)
-
-    _, s_fin = carry
-    fetch = {k: s_fin[k] for k in _SM_FETCH}
-    fetch["_tr_backlog"] = s_fin["_tr_backlog"]
-    fetch["_tr_drained_lo"] = s_fin["_tr_drained_lo"]
-    fetch["_tr_drained_hi"] = s_fin["_tr_drained_hi"]
-    consts_np_h = build_sm_consts(prog)
-    consts_host = {
-        "cqi": np.asarray(consts_np_h["cqi"][0]),
-        "mcs": np.asarray(consts_np_h["mcs"][0]),
-        "sinr": np.asarray(consts_np_h["sinr"][0]),
-    }
-    want = replicas if r_pad is not None else None
-    # the workload's mean offered bits per UE over the horizon — the
-    # host mirror of the device fill (size quantization differs per
-    # TTI draw; this is its expectation), for telemetry + results
-    offered = offered_bits_mean(prog.traffic, prog.n_ttis * 1000)
-
-    def unpack_one(host):
-        host = dict(host)
-
-        def row(v):
-            a = np.asarray(v)
-            a = a.reshape(a.shape[:-2] + a.shape[-1:])
-            return a[:want] if want is not None and a.shape[0] != want \
-                else a
-
-        backlog = row(host.pop("_tr_backlog"))
-        drained = (
-            row(host.pop("_tr_drained_hi")).astype(np.int64) << 20
-        ) + row(host.pop("_tr_drained_lo")).astype(np.int64)
-        out = _sm_unpack(host, consts_host, want)
-        out["backlog_bits"] = backlog
-        out["goodput_bits"] = drained
-        out["offered_bits"] = offered
-        return out
-
-    unstack = unstack_points(n_cfg, unpack_one)
-
-    # burst duty (mean ON share) only means anything for onoff programs
-    duty = (
-        float(
-            np.clip(
-                prog.traffic.rate_pps.sum()
-                / max(float(prog.traffic.peak_pps.sum()), 1e-9),
-                0.0, 1.0,
-            )
-        )
-        if prog.traffic.model == "onoff"
-        else None
-    )
-
-    def finalize(host):
-        out = unstack(host)
-        pts = out if isinstance(out, list) else [out]
-        drained = float(
-            sum(
-                np.asarray(p["goodput_bits"], np.float64).sum()
-                for p in pts
-            )
-        )
-        lanes = len(pts) * (want or 1)
-        TrafficTelemetry.record(
-            "lte_sm", prog.traffic.model,
-            offered=float(offered.sum()) * lanes,
-            delivered=drained, duty=duty,
-        )
-        return out
-
-    fut = EngineFuture(
-        "lte_sm", fetch, finalize_with_flush(flush, finalize),
-    )
-    return fut.result() if block else fut
-
-
-def _run_lte_sm_mobile(
-    prog: LteSmProgram,
-    key,
-    replicas: int | None = None,
-    mesh=None,
-    *,
-    schedulers=None,
-    chunk_ttis: int | None = None,
-    checkpoint=None,
-    block: bool = True,
-):
-    """The mobile-geometry form of :func:`run_lte_sm` (same contract,
-    same result fields + ``geom_refreshes``/``geom_stride``).
-
-    Structure as in all three builders (:func:`_vmap_lanes`): the TTI
-    ``while_loop`` runs UNBATCHED (scalar clock + the geometry row dict
-    in the carry) and only the per-lane step is vmapped over the
-    replica / config axes inside the body — the trajectory is shared
-    by every replica and config point, so the
-    geometry ``lax.cond`` keeps a SCALAR predicate and the refresh
-    really is skipped on non-stride TTIs (a batched predicate would
-    degrade to select-both-branches under vmap and the stride would
-    save nothing).
-
-    ``TPUDES_DEVICE_GEOM=0`` takes the per-window fallback: refresh
-    POSITIONS are precomputed on the host (one tiny device call per
-    refresh time through the same closed-form kernel) and shipped as a
-    ``(K_ref, U, 3)`` operand the loop gathers — the per-window
-    fresh-operands shape of the host controller path — while the rows
-    math stays the identical in-step code, so the two modes are pinned
-    bit-equal."""
-    import jax.numpy as jnp
-
-    from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
-    from tpudes.obs.geometry import GeomTelemetry
-    from tpudes.ops.mobility import device_geom_enabled
-    from tpudes.parallel.runtime import (
-        RUNTIME,
-        EngineFuture,
-        bucket_replicas,
-        chunk_bounds,
-        drive_chunks,
-        finalize_with_flush,
-        unstack_points,
-    )
-
-    r_pad = bucket_replicas(replicas, mesh)
-    n_cfg = None if schedulers is None else len(schedulers)
-    obs = device_metrics_enabled()
-    use_pallas = _sm_use_pallas(mesh)
-    stride = max(1, int(prog.geom_stride))
-    dg_on = device_geom_enabled()
-    # fallback mode: the refresh-time grid is a SHAPE (K_ref rows)
-    k_ref = None if dg_on else -(-prog.n_ttis // stride)
-
-    def build():
-        init_carry, fn = build_sm_mobile_advance(
-            prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
-            use_pallas=use_pallas,
-        )
-        return _sm_jit_init(init_carry, r_pad, n_cfg), jit_advance(
-            "lte_sm", fn
-        )
-
-    (init, fn), compiling = RUNTIME.runner(
-        "lte_sm",
-        lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas)
-        + ("mobile", dg_on, k_ref),
-        build,
-    )
-
-    sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
-    sids = [SM_SCHED_IDS[s] for s in sched_names]
-    with span("launch.operands"):
-        sid = _sm_sid_operand(sids, n_cfg)
-        keys, carry = init(mesh, key)
-        mob_ops = prog.mobility.operands()
-        pos_table = None
-        if k_ref is not None:
-            # host-materialized refresh schedule (the per-window fresh
-            # operands of the legacy path) through the SAME position
-            # kernel
-            from tpudes.ops.mobility import trajectory_positions
-
-            pos_table = jnp.asarray(
-                trajectory_positions(
-                    prog.mobility,
-                    [t * 1000 for t in range(0, prog.n_ttis, stride)],
-                ),
-                jnp.float32,
-            )
-
-    from tpudes.parallel.checkpoint import checkpoint_ctx
-
-    ckpt = checkpoint_ctx(
-        checkpoint, engine="lte_sm", key=key, replicas=replicas,
-        r_pad=r_pad, n_cfg=n_cfg, obs=obs,
-        axis=0 if n_cfg is None else 1, mesh=mesh,
-        extra=_sm_cache_key(prog, None, n_cfg, obs, False)
-        + ("mobile", dg_on, k_ref, stride, tuple(sids)),
-    )
-    with CompileTelemetry.timed("lte_sm", compiling):
-        carry, flush = drive_chunks(
-            "lte_sm",
-            chunk_bounds(prog.n_ttis, chunk_ttis or prog.n_ttis),
-            carry,
-            lambda c, t_end: fn(
-                c, keys, sid, np.int32(t_end), mob_ops,
-                np.int32(stride), pos_table,
-            ),
-            obs,
-            checkpoint=ckpt,
-        )
-        if compiling:
-            jax.block_until_ready(carry)
-
-    _, g_fin, s_fin = carry
-    fetch = {k: s_fin[k] for k in _SM_FETCH}
-    fetch["_geom_sinr"] = g_fin["sinr"]
-    fetch["_geom_cqi"] = g_fin["cqi"]
-    fetch["_geom_mcs"] = g_fin["mcs"]
-    fetch["_geom_refreshes"] = g_fin["refreshes"]
-    want = replicas if r_pad is not None else None
-    shared = ("_geom_sinr", "_geom_cqi", "_geom_mcs", "_geom_refreshes")
-
-    def unpack_one(host):
-        host = dict(host)
-        consts_np = {
-            "sinr": np.asarray(host.pop("_geom_sinr"))[0],
-            "cqi": np.asarray(host.pop("_geom_cqi"))[0],
-            "mcs": np.asarray(host.pop("_geom_mcs"))[0],
-        }
-        refreshes = int(host.pop("_geom_refreshes"))
-        out = _sm_unpack(host, consts_np, want)
-        out["geom_refreshes"] = refreshes
-        out["geom_stride"] = stride
-        return out
-
-    unstack = unstack_points(n_cfg, unpack_one, shared=shared)
-
-    def finalize(host):
-        # telemetry once per LAUNCH: the geometry loop is shared by
-        # every config point (the rows ride `shared`), so recording
-        # inside the per-point unpack would inflate the counters
-        # n_cfg-fold
-        GeomTelemetry.record_device(
-            "lte_sm", int(host["_geom_refreshes"]), prog.n_ttis
-        )
-        return unstack(host)
-
-    fut = EngineFuture(
-        "lte_sm", fetch, finalize_with_flush(flush, finalize),
-    )
-    return fut.result() if block else fut
-
-
 def _sm_use_pallas(mesh) -> bool:
     """Which TTI-step lowering a launch takes.  Mosaic kernels cannot
     be partitioned by GSPMD ("Mosaic kernels cannot be automatically
@@ -1319,94 +1016,283 @@ def _sm_use_pallas(mesh) -> bool:
     return pallas_enabled() and mesh is None
 
 
-def _sm_sid_operand(sids, n_cfg):
-    """The traced scheduler id(s) as a host value: numpy gives the
-    jitted call the aval ``jnp.int32`` gave it, without an eager
-    transfer first."""
-    return np.int32(sids[0]) if n_cfg is None else np.asarray(sids, np.int32)
+class _SmVariant(NamedTuple):
+    """What differs between the three forms of one LTE launch (plain,
+    finite-backlog, mobile geometry); :func:`_sm_prepare` and
+    :func:`run_lte_sm` hold what they share.  The state dict is the
+    LAST part of every form's carry."""
 
-
-def _sm_jit_init(init_carry, r_pad, n_cfg):
-    """``lte_sm``'s one way to make a launch's keys and initial carry:
-    the :func:`~tpudes.parallel.runtime.jit_init` program all three
-    runners (plain, traffic, mobile) build beside their advance
-    program and keep in the same ``RUNTIME.runner`` entry.
-
-    ``init_carry()`` is a builder's un-jitted ``(t0, *shared, s)``:
-    the scalar clock, what every lane shares (the mobile runner's
-    geometry rows), and LAST one lane's state dict.  ``init(mesh, key)``
-    returns ``(keys, carry)``: ``keys`` the ``(r_pad, 2)``
-    ``fold_in(key, i)`` rows (``key`` itself without a replica axis),
-    ``carry`` the same tuple with the state stacked on
-    ``(n_cfg,) (r_pad,)``; on a mesh keys and state come out sharded
-    over "replica" and the rest replicated."""
-    from tpudes.parallel.runtime import jit_init, replica_keys, stack_axis
-
-    def parts(key):
-        *shared, s = init_carry()
-        keys = key if r_pad is None else replica_keys(key, r_pad)
-        return keys, tuple(shared), stack_axis(stack_axis(s, r_pad), n_cfg)
-
-    init = jit_init(
-        "lte_sm", parts, r_pad, (0, None, 0 if n_cfg is None else 1)
+    #: joins the runner's cache key
+    tag: tuple
+    #: ``(r_pad=, n_cfg=, obs=, use_pallas=)`` -> ``(init_carry,
+    #: advance, aux)``: the builder's un-jitted ``(t0, *shared, s)``
+    #: and advance, and the host constants the unpack wants
+    build: Callable
+    #: the advance program's arguments after ``t_end``, given what
+    #: ``init_extra(key)`` made inside the init program
+    operands: Callable = lambda extra: ()
+    init_extra: Callable = lambda key: ()
+    #: ``(carry, obs)`` -> the leaves that go to the host
+    fetch: Callable = lambda carry, obs: {
+        k: carry[-1][k]
+        for k in _SM_FETCH + (_sm_fetch_obs() if obs else ())
+    }
+    #: fetched names without a config axis
+    shared: tuple = ()
+    #: ``(aux, want)`` -> one config point's unpack
+    unpack: Callable = lambda aux, want: (
+        lambda host: _sm_unpack(host, aux, want)
     )
+    #: once-per-launch telemetry, ``(host, points)``
+    once: Callable | None = None
+    #: ``(sids)`` -> what a checkpoint's fingerprint holds beside
+    #: :func:`_sm_cache_key`
+    identity: Callable = lambda sids: (tuple(sids), _SM_CARRY_LAYOUT)
 
-    def launch_init(mesh, key):
-        keys, shared, s0 = init(mesh, key)
-        return keys, (*shared, s0)
 
-    return launch_init
+def _sm_host_consts(consts: dict) -> dict:
+    return {k: np.asarray(consts[k]) for k in ("cqi", "mcs", "sinr")}
 
 
-def _sm_launch(prog: LteSmProgram, key, replicas, mesh, schedulers):
-    """The plain runner's launch set-up — cached runner + the exact
-    operands it is called with — shared by :func:`run_lte_sm` and
-    :func:`compiled_step_lowering` so the inspector reads the very
-    executable a run dispatches.  Keys and carry come from the entry's
-    :func:`_sm_jit_init` program (one executable, outputs already on
-    the mesh); no launch of this module calls the eager
-    ``replica_keys`` / ``init_state()`` / ``stack_axis`` /
-    ``shard_replica_axis`` sequence any more.  ``build_sm_advance``
-    still returns the un-jitted ``init_state`` for the trace manifest,
-    and ``checkpoint.py`` still shards a restored carry with
-    ``shard_replica_axis``."""
-    from types import SimpleNamespace
-
-    from tpudes.obs.device import device_metrics_enabled
-    from tpudes.parallel.runtime import RUNTIME, bucket_replicas
-
-    r_pad = bucket_replicas(replicas, mesh)
-    n_cfg = None if schedulers is None else len(schedulers)
-    obs = device_metrics_enabled()
-    use_pallas = _sm_use_pallas(mesh)
-
-    def build():
-        consts, init_state, fn = build_sm_advance(
-            prog, r_pad=r_pad, n_cfg=n_cfg, obs=obs,
-            use_pallas=use_pallas,
-        )
+def _sm_plain(prog: LteSmProgram) -> _SmVariant:
+    def build(**kw):
+        consts, init_state, fn = build_sm_advance(prog, **kw)
         # the clock is one scalar for every lane (replicated on a
         # mesh); only the state is stacked and sharded
-        init = _sm_jit_init(
-            lambda: (jnp.int32(0), init_state()), r_pad, n_cfg
+        return (
+            lambda: (jnp.int32(0), init_state()), fn,
+            _sm_host_consts(consts),
         )
-        return consts, init, jit_advance("lte_sm", fn)
 
-    (consts, init, fn), compiling = RUNTIME.runner(
-        "lte_sm",
-        lambda: _sm_cache_key(prog, r_pad, n_cfg, obs, use_pallas),
-        build,
+    return _SmVariant((), build)
+
+
+def _sm_traffic(prog: LteSmProgram) -> _SmVariant:
+    """The finite-backlog form: results gain per-UE ``backlog_bits`` /
+    ``goodput_bits`` / ``offered_bits``.  One executable serves the
+    whole workload family AND all nine schedulers at every horizon —
+    model id, traffic params, scheduler id and TTI bound are all
+    traced operands."""
+    from tpudes.obs.traffic import TrafficTelemetry
+    from tpudes.traffic.device import TRAFFIC_KEY_TAG
+    from tpudes.traffic.host import offered_bits_mean
+
+    traffic = prog.traffic
+    # the workload's mean offered bits per UE over the horizon — the
+    # host mirror of the device fill (size quantization differs per
+    # TTI draw; this is its expectation), for telemetry + results
+    offered = offered_bits_mean(traffic, prog.n_ttis * 1000)
+    tr_leaves = ("_tr_backlog", "_tr_drained_lo", "_tr_drained_hi")
+
+    def build(**kw):
+        init_carry, fn = build_sm_traffic_advance(prog, **kw)
+        consts = build_sm_consts(prog)
+        return init_carry, fn, _sm_host_consts(
+            {k: consts[k][0] for k in ("cqi", "mcs", "sinr")}
+        )
+
+    def unpack(aux, want):
+        def row(v):
+            a = np.asarray(v)
+            a = a.reshape(a.shape[:-2] + a.shape[-1:])
+            return a[:want] if want is not None and a.shape[0] != want \
+                else a
+
+        def unpack_one(host):
+            host = dict(host)
+            backlog = row(host.pop("_tr_backlog"))
+            drained = (
+                row(host.pop("_tr_drained_hi")).astype(np.int64) << 20
+            ) + row(host.pop("_tr_drained_lo")).astype(np.int64)
+            out = _sm_unpack(host, aux, want)
+            out["backlog_bits"] = backlog
+            out["goodput_bits"] = drained
+            out["offered_bits"] = offered
+            return out
+
+        return unpack_one
+
+    def record_traffic(host, points):
+        # burst duty (mean ON share) only means anything for onoff
+        duty = (
+            float(
+                np.clip(
+                    traffic.rate_pps.sum()
+                    / max(float(traffic.peak_pps.sum()), 1e-9),
+                    0.0, 1.0,
+                )
+            )
+            if traffic.model == "onoff"
+            else None
+        )
+        goodput = [np.asarray(p["goodput_bits"], np.float64) for p in points]
+        lanes = sum(g.size for g in goodput) // prog.n_ue
+        TrafficTelemetry.record(
+            "lte_sm", traffic.model,
+            offered=float(offered.sum()) * lanes,
+            delivered=float(sum(g.sum() for g in goodput)), duty=duty,
+        )
+
+    return _SmVariant(
+        ("traffic",), build,
+        operands=lambda extra: (traffic.operands(), *extra),
+        init_extra=lambda key: (jax.random.fold_in(key, TRAFFIC_KEY_TAG),),
+        fetch=lambda carry, obs: {
+            k: carry[-1][k] for k in _SM_FETCH + tr_leaves
+        },
+        unpack=unpack,
+        once=record_traffic,
+        identity=lambda sids: (
+            "traffic", traffic.param_key(), tuple(sids)
+        ),
     )
 
-    sched_names = [prog.scheduler] if schedulers is None else list(schedulers)
-    sids = [SM_SCHED_IDS[s] for s in sched_names]
-    with span("launch.operands"):
-        sid = _sm_sid_operand(sids, n_cfg)
-        keys, carry = init(mesh, key)
-    return SimpleNamespace(
-        consts=consts, fn=fn, carry=carry, keys=keys, sid=sid, sids=sids,
-        r_pad=r_pad, n_cfg=n_cfg, obs=obs, compiling=compiling,
+
+def _sm_mobile(prog: LteSmProgram) -> _SmVariant:
+    """The mobile-geometry form (:func:`build_sm_mobile_advance`):
+    results gain ``geom_refreshes`` / ``geom_stride``.
+
+    ``TPUDES_DEVICE_GEOM=0`` takes the per-window fallback: refresh
+    POSITIONS are precomputed on the host (one tiny device call per
+    refresh time through the same closed-form kernel) and shipped as a
+    ``(K_ref, U, 3)`` operand the loop gathers — the per-window
+    fresh-operands shape of the host controller path — while the rows
+    math stays the identical in-step code, so the two modes are pinned
+    bit-equal."""
+    from tpudes.obs.geometry import GeomTelemetry
+    from tpudes.ops.mobility import device_geom_enabled
+
+    stride = max(1, int(prog.geom_stride))
+    dg_on = device_geom_enabled()
+    # fallback mode: the refresh-time grid is a SHAPE (K_ref rows)
+    k_ref = None if dg_on else -(-prog.n_ttis // stride)
+    shared = ("_geom_sinr", "_geom_cqi", "_geom_mcs", "_geom_refreshes")
+
+    def operands(extra):
+        pos_table = None
+        if k_ref is not None:
+            from tpudes.ops.mobility import trajectory_positions
+
+            pos_table = jnp.asarray(
+                trajectory_positions(
+                    prog.mobility,
+                    [t * 1000 for t in range(0, prog.n_ttis, stride)],
+                ),
+                jnp.float32,
+            )
+        return prog.mobility.operands(), np.int32(stride), pos_table
+
+    def fetch(carry, obs):
+        _, g_fin, s_fin = carry
+        return dict(
+            {k: s_fin[k] for k in _SM_FETCH},
+            **{f"_geom_{k}": g_fin[k]
+               for k in ("sinr", "cqi", "mcs", "refreshes")},
+        )
+
+    def unpack(aux, want):
+        def unpack_one(host):
+            host = dict(host)
+            consts_np = {
+                k: np.asarray(host.pop(f"_geom_{k}"))[0]
+                for k in ("sinr", "cqi", "mcs")
+            }
+            refreshes = int(host.pop("_geom_refreshes"))
+            out = _sm_unpack(host, consts_np, want)
+            out["geom_refreshes"] = refreshes
+            out["geom_stride"] = stride
+            return out
+
+        return unpack_one
+
+    return _SmVariant(
+        ("mobile", dg_on, k_ref),
+        lambda **kw: (*build_sm_mobile_advance(prog, **kw), None),
+        operands=operands,
+        fetch=fetch,
+        shared=shared,
+        unpack=unpack,
+        # the geometry loop is shared by every config point (its rows
+        # ride `shared`)
+        once=lambda host, points: GeomTelemetry.record_device(
+            "lte_sm", int(host["_geom_refreshes"]), prog.n_ttis
+        ),
+        identity=lambda sids: (
+            "mobile", dg_on, k_ref, stride, tuple(sids)
+        ),
     )
+
+
+def _sm_prepare(prog: LteSmProgram, key, replicas, mesh, schedulers):
+    """The prepared :class:`~tpudes.parallel.runtime.Launch` of
+    ``run_lte_sm(prog, key, replicas, mesh, schedulers=...)``, its
+    variant and the scheduler ids: the cached runner plus the exact
+    carry and operands it is called with, shared by :func:`run_lte_sm`
+    and :func:`compiled_step_lowering` so the inspector reads the very
+    executable a run dispatches."""
+    from tpudes.parallel.runtime import Launch, replica_keys, stack_axis
+
+    if prog.traffic is not None:
+        if prog.mobility is not None:
+            raise UnliftableLteScenarioError(
+                "traffic + mobility cannot yet ride one LTE program; "
+                "run one axis on device and the other on the host "
+                "controller"
+            )
+        v = _sm_traffic(prog)
+    else:
+        v = _sm_plain(prog) if prog.mobility is None else _sm_mobile(prog)
+    n_cfg = None if schedulers is None else len(schedulers)
+    use_pallas = _sm_use_pallas(mesh)
+    L = Launch("lte_sm", key, replicas, mesh, n_cfg)
+    r_pad = L.r_pad
+
+    def build():
+        init_carry, fn, aux = v.build(
+            r_pad=r_pad, n_cfg=n_cfg, obs=L.obs, use_pallas=use_pallas
+        )
+
+        def parts(key):
+            # ``keys`` the (r_pad, 2) ``fold_in(key, i)`` rows (``key``
+            # itself without a replica axis); what every lane shares
+            # (the clock, the mobile form's geometry rows) stays
+            # unstacked, replicated on a mesh; the state is stacked on
+            # ``(n_cfg,) (r_pad,)``
+            *shared, s = init_carry()
+            keys = key if r_pad is None else replica_keys(key, r_pad)
+            return (
+                keys, tuple(shared),
+                stack_axis(stack_axis(s, r_pad), n_cfg),
+                v.init_extra(key),
+            )
+
+        return parts, (0, None, L.axis, None), fn, aux
+
+    sids = [
+        SM_SCHED_IDS[s]
+        for s in ([prog.scheduler] if schedulers is None else schedulers)
+    ]
+
+    def operands(parts):
+        keys, shared, s0, extra = parts
+        # the traced scheduler id(s)
+        sid = (
+            np.int32(sids[0]) if n_cfg is None
+            else np.asarray(sids, np.int32)
+        )
+        return (*shared, s0), (keys, sid, v.operands(extra))
+
+    L.prepare(
+        lambda: _sm_cache_key(prog, r_pad, n_cfg, L.obs, use_pallas)
+        + v.tag,
+        build, operands, init_args=(key,),
+    )
+    return L, v, sids
+
+
+def _sm_call(fn, carry, t_end, ops):
+    keys, sid, extra = ops
+    return fn(carry, keys, sid, t_end, *extra)
 
 
 def compiled_step_lowering(prog: LteSmProgram, key, replicas=None,
@@ -1418,9 +1304,9 @@ def compiled_step_lowering(prog: LteSmProgram, key, replicas=None,
     (the Pallas kernel, compiled by Mosaic), ``"xla"`` otherwise.
     Lowers the cached runner with a run's own operands, so after a run
     this is a compile-cache hit, not a second compile."""
-    L = _sm_launch(prog, key, replicas, mesh, schedulers)
-    text = L.fn.lower(
-        L.carry, L.keys, L.sid, np.int32(prog.n_ttis)
+    L, _, _ = _sm_prepare(prog, key, replicas, mesh, schedulers)
+    text = _sm_call(
+        L.fn.lower, L.carry, np.int32(prog.n_ttis), L.ops
     ).compile().as_text()
     return "mosaic" if "tpu_custom_call" in text else "xla"
 
@@ -1454,6 +1340,8 @@ def run_lte_sm(
     C-point scheduler study is ONE device launch of a (C, R, …)
     program; the return value is a list of per-point result dicts, each
     exactly what the per-point launch (same key) would have produced.
+    Scheduler id and horizon are traced, so a 9-scheduler sweep keeps
+    the recorded compile count at ONE.
 
     ``chunk_ttis=N`` splits the horizon into N-TTI while_loop segments
     with the carry handed (donated) from segment to segment — results
@@ -1466,87 +1354,29 @@ def run_lte_sm(
     (the launch is dispatched; D2H + unpack happen at ``result()``) —
     the :meth:`RUNTIME.submit` payload.
 
-    A program with ``prog.mobility`` routes to the mobile-geometry
-    runner (same contract; results gain ``geom_refreshes``/
-    ``geom_stride``) — see :func:`_run_lte_sm_mobile`.  A program with
-    ``prog.traffic`` routes to the finite-backlog runner (results gain
-    ``backlog_bits``/``offered_bits``) — see
-    :func:`_run_lte_sm_traffic`; combining both axes on one LTE
-    program is rejected loudly (run one axis on device and the other
-    through the host controller) — the ROADMAP remainder.
+    A program with ``prog.mobility`` runs the mobile-geometry form
+    (same contract; results gain ``geom_refreshes``/``geom_stride``) —
+    see :func:`_sm_mobile`.  A program with ``prog.traffic`` runs the
+    finite-backlog form (results gain ``backlog_bits``/
+    ``offered_bits``) — see :func:`_sm_traffic`; combining both axes on
+    one LTE program is rejected loudly (run one axis on device and the
+    other through the host controller) — the ROADMAP remainder.
     """
-    if prog.traffic is not None:
-        if prog.mobility is not None:
-            raise UnliftableLteScenarioError(
-                "traffic + mobility cannot yet ride one LTE program; "
-                "run one axis on device and the other on the host "
-                "controller"
-            )
-        return _run_lte_sm_traffic(
-            prog, key, replicas=replicas, mesh=mesh,
-            schedulers=schedulers, chunk_ttis=chunk_ttis,
-            checkpoint=checkpoint, block=block,
-        )
-    if prog.mobility is not None:
-        return _run_lte_sm_mobile(
-            prog, key, replicas=replicas, mesh=mesh,
-            schedulers=schedulers, chunk_ttis=chunk_ttis,
-            checkpoint=checkpoint, block=block,
-        )
-    from tpudes.obs.device import CompileTelemetry
-    from tpudes.parallel.runtime import (
-        EngineFuture,
-        chunk_bounds,
-        drive_chunks,
-        finalize_with_flush,
-        unstack_points,
+    from tpudes.parallel.runtime import chunk_bounds
+
+    L, v, sids = _sm_prepare(prog, key, replicas, mesh, schedulers)
+    return L.drive(
+        _sm_call,
+        chunk_bounds(prog.n_ttis, chunk_ttis or prog.n_ttis),
+        lambda carry: v.fetch(carry, L.obs),
+        v.unpack(L.aux, replicas),
+        shared=v.shared,
+        once=v.once,
+        checkpoint=checkpoint,
+        identity=lambda: _sm_cache_key(prog, None, L.n_cfg, L.obs, False)
+        + v.identity(sids),
+        block=block,
     )
-
-    L = _sm_launch(prog, key, replicas, mesh, schedulers)
-    n_cfg, obs = L.n_cfg, L.obs
-
-    from tpudes.parallel.checkpoint import checkpoint_ctx
-
-    ckpt = checkpoint_ctx(
-        checkpoint, engine="lte_sm", key=key, replicas=replicas,
-        r_pad=L.r_pad, n_cfg=n_cfg, obs=obs,
-        axis=0 if n_cfg is None else 1, mesh=mesh,
-        extra=_sm_cache_key(prog, None, n_cfg, obs, False)
-        + (tuple(L.sids), _SM_CARRY_LAYOUT),
-    )
-    # scheduler id and horizon are traced, so a 9-scheduler sweep must
-    # keep the recorded compile count at ONE — bench reports the metric
-    with CompileTelemetry.timed("lte_sm", L.compiling):
-        carry, flush = drive_chunks(
-            "lte_sm",
-            chunk_bounds(prog.n_ttis, chunk_ttis or prog.n_ttis),
-            L.carry,
-            lambda c, t_end: L.fn(c, L.keys, L.sid, np.int32(t_end)),
-            obs,
-            checkpoint=ckpt,
-        )
-        if L.compiling:
-            jax.block_until_ready(carry)
-
-    fetch_keys = _SM_FETCH + (_sm_fetch_obs() if obs else ())
-    fetch = {k: carry[1][k] for k in fetch_keys}
-    consts_np = {
-        "cqi": np.asarray(L.consts["cqi"]),
-        "mcs": np.asarray(L.consts["mcs"]),
-        "sinr": np.asarray(L.consts["sinr"]),
-    }
-    want = replicas if L.r_pad is not None else None
-    fut = EngineFuture(
-        "lte_sm",
-        fetch,
-        finalize_with_flush(
-            flush,
-            unstack_points(
-                n_cfg, lambda host: _sm_unpack(host, consts_np, want)
-            ),
-        ),
-    )
-    return fut.result() if block else fut
 
 
 # --- trace manifest (tpudes.analysis.jaxpr) --------------------------------
@@ -1642,7 +1472,7 @@ def _trace_traffic_prog():
 
 
 def _trace_entries_traffic(prog: LteSmProgram):
-    """The finite-backlog advance exactly as ``_run_lte_sm_traffic``
+    """The finite-backlog advance exactly as ``run_lte_sm``
     jits it (plain-XLA lowering), with concrete tiny operands — the
     new jitted program joins the JXL lint surface like the base one."""
     from tpudes.analysis.jaxpr.spec import TraceEntry

@@ -1141,8 +1141,8 @@ def build_bss_advance(prog: "BssProgram", replicas: int, obs: bool = False,
                       sweep: str = "horizon"):
     """``(init_state, pending, fn)`` with
     ``fn(s, k, max_steps, sim_end, geom, tr)`` the UNJITTED (but
-    config-vmapped) advance exactly as :func:`_compiled_bss_runner`
-    jits it — factored out so the trace manifest
+    config-vmapped) advance exactly as :func:`run_replicated_bss`'s
+    launch jits it — factored out so the trace manifest
     (:func:`trace_manifest`) abstractly traces the same program the
     runner cache compiles.  With ``n_cfg``, ``sweep`` picks the
     config-axis operand: ``"horizon"`` vmaps (state, sim_end) — the
@@ -1204,66 +1204,6 @@ def build_bss_advance(prog: "BssProgram", replicas: int, obs: bool = False,
             ),
         )
     return init_state, pending, fn
-
-
-def _compiled_bss_runner(
-    prog, replicas, mesh, obs=False, n_cfg=None,
-    geom_per_step=False, sweep: str = "horizon",
-):
-    """Jitted runner via the shared :data:`~tpudes.parallel.runtime.RUNTIME`
-    cache, keyed on (program, padded replicas) so a warm-up call
-    actually warms subsequent timed calls (ADVICE r2 medium: a fresh
-    jax.jit wrapper per call re-traces every time).  ``max_steps`` AND
-    ``sim_end`` are traced operands — a horizon sweep reuses ONE
-    executable — and the state carry is donated on accelerators.  With
-    ``n_cfg`` the runner is additionally vmapped over a leading
-    config axis of (state, sim_end) — a C-point horizon sweep is one
-    launch.  The runner itself is mesh-independent — sharding flows
-    from the input arrays and jax.jit specializes per input sharding
-    internally — so mesh is not part of the key.
-
-    Returns ``(init, pending, run, compiled_new)``.  ``init(mesh)`` is
-    the entry's :func:`~tpudes.parallel.runtime.jit_init` program: it
-    returns ``(s0,)``, the launch's whole initial carry
-    (``init_state()`` stacked over the config axis) from ONE
-    executable, placed on ``mesh`` by the program itself; only it is
-    keyed by mesh, inside the entry.  Nothing here calls the eager
-    ``init_state()`` → ``stack_axis`` → ``shard_replica_axis`` chain
-    any more (a dispatched program per leaf per step): the un-jitted
-    ``init_state`` remains for the trace manifest
-    (:func:`trace_manifest`) and ``shard_replica_axis`` for a restored
-    checkpoint's carry.  ``compiled_new`` tells the caller this call
-    populated the cache (the compile-telemetry trigger), so the cache
-    key is derived in exactly one place."""
-    from tpudes.parallel.runtime import (
-        RUNTIME,
-        jit_advance,
-        jit_init,
-        stack_axis,
-    )
-
-    del mesh
-
-    mobile = prog.mobility is not None
-
-    def build():
-        init_state, pending, fn = build_bss_advance(
-            prog, replicas, obs=obs, n_cfg=n_cfg,
-            geom_per_step=geom_per_step, sweep=sweep,
-        )
-        init = jit_init(
-            "bss", lambda: (stack_axis(init_state(), n_cfg),),
-            replicas, (0 if n_cfg is None else 1,),
-        )
-        return init, pending, jit_advance("bss", fn)
-
-    (init, pending, run), compiled_new = RUNTIME.runner(
-        "bss",
-        lambda: (_prog_cache_key(prog), replicas, obs, n_cfg, mobile,
-                 geom_per_step, sweep if n_cfg is not None else None),
-        build,
-    )
-    return init, pending, run, compiled_new
 
 
 def _bss_unpack(host: dict, replicas: int, obs: bool, prog=None) -> dict:
@@ -1413,17 +1353,7 @@ def run_replicated_bss(
     """
     import dataclasses
 
-    from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
-    from tpudes.obs.spans import span
-    from tpudes.parallel.checkpoint import checkpoint_ctx
-    from tpudes.parallel.runtime import (
-        EngineFuture,
-        bucket_replicas,
-        chunk_bounds,
-        drive_chunks,
-        finalize_with_flush,
-        unstack_points,
-    )
+    from tpudes.parallel.runtime import Launch, chunk_bounds, stack_axis
 
     if sim_end_us is not None and traffic_sweep is not None:
         raise ValueError(
@@ -1451,24 +1381,28 @@ def run_replicated_bss(
             for v in ends
             for p in sweep_progs
         )
-    obs = device_metrics_enabled()
-    # replica bucketing: pad R to the power-of-two bucket so a replica
-    # sweep reuses one compiled program per bucket; padded replicas are
-    # real independent simulations whose results are sliced off below
-    # (per-replica keying in step_fn makes this exact, and a finished
-    # replica's state is a fixed point of step_fn, so the extra loop
-    # iterations the padding may cause cannot corrupt real replicas)
-    r_pad = bucket_replicas(replicas, mesh)
-    init, pending, run, compiling = _compiled_bss_runner(
-        prog, r_pad, mesh, obs=obs, n_cfg=n_cfg,
-        geom_per_step=geom_per_step, sweep=sweep,
-    )
+    mobile = prog.mobility is not None
+    # per-replica keying in step_fn makes the replica bucket exact, and
+    # a finished replica's state is a fixed point of step_fn, so the
+    # extra loop iterations the padding may cause cannot corrupt real
+    # replicas
+    L = Launch("bss", key, replicas, mesh, n_cfg)
 
-    with span("launch.operands"):
+    def build():
+        init_state, _, fn = build_bss_advance(
+            prog, L.r_pad, obs=L.obs, n_cfg=n_cfg,
+            geom_per_step=geom_per_step, sweep=sweep,
+        )
+        return (
+            lambda: (stack_axis(init_state(), n_cfg),),
+            (L.axis,), fn, None,
+        )
+
+    def operands(parts):
         # mobility/traffic params ride as TRACED operands (None for the
-        # legacy paths); the cache key above carries only shapes
+        # legacy paths); the cache key carries only shapes
         geom = (
-            None if prog.mobility is None
+            None if not mobile
             else dict(
                 stride=np.int32(max(1, int(prog.geom_stride))),
                 **prog.mobility.operands(),
@@ -1489,84 +1423,65 @@ def run_replicated_bss(
             tr = stack_traffic_operands(traffic_sweep)
         else:
             tr = None if prog.traffic is None else prog.traffic.operands()
-        # host scalars go to the jitted call as numpy (the same aval
-        # as jnp.int32, without an eager transfer each)
         sim_end = (
             np.int32(ends[0]) if n_cfg is None or sweep == "traffic"
             else np.asarray(ends, np.int32)
         )
-        # the whole carry from ONE cached executable, already sharded
-        (s0,) = init(mesh)
+        return (parts[0], None), (sim_end, geom, tr)
 
-    with CompileTelemetry.timed("bss", compiling):
-        def launch(carry, bound):
-            # chunking reuses the SAME executable: each segment raises
-            # the step bound; finished replicas are a fixed point of
-            # step_fn, so later segments cost one cond evaluation
-            state, still_pending, metrics = run(
-                carry[0], key, np.int32(bound), sim_end, geom, tr
-            )
-            return (state, still_pending), metrics
-
-        ckpt = checkpoint_ctx(
-            checkpoint, engine="bss", key=key, replicas=replicas,
-            r_pad=r_pad, n_cfg=n_cfg, obs=obs,
-            axis=0 if n_cfg is None else 1, mesh=mesh,
-            extra=_prog_cache_key(prog) + (
-                tuple(ends), geom_per_step,
-                # traffic identity by VALUE (shape key alone would let
-                # a resumed run silently swap workloads mid-study)
-                None if prog.traffic is None
-                else prog.traffic.param_key(),
-                None if traffic_sweep is None
-                else tuple(tp.param_key() for tp in traffic_sweep),
-            ),
-        )
-        (out, still_pending), flush = drive_chunks(
-            "bss",
-            chunk_bounds(max_steps, chunk_steps or max_steps),
-            (s0, None),
-            launch,
-            obs,
-            checkpoint=ckpt,
-        )
-        # one batched device→host transfer for every result (steps/
-        # all_done ride along instead of costing their own round trips)
-        fetch = dict(
-            srv_rx=out["srv_rx"],
-            cli_rx=out["cli_rx"],
-            tx_data=out["tx_data"],
-            drops=out["drops"],
-            step=out["step"],
-            pending=still_pending,
-        )
-        if obs:
-            from tpudes.obs.flowmon import FM_KEYS
-
-            fetch["retx"] = out["retx"]
-            for k in FM_KEYS:
-                fetch[k] = out[k]
-        if compiling:
-            jax.block_until_ready(fetch)
-
-    unstack = unstack_points(
-        n_cfg, lambda host: _bss_unpack(host, replicas, obs, prog)
+    # max_steps AND sim_end are traced operands (a horizon sweep reuses
+    # ONE executable); the runner is mesh-independent, sharding flows
+    # from the init program's outputs
+    L.prepare(
+        lambda: (_prog_cache_key(prog), L.r_pad, L.obs, n_cfg, mobile,
+                 geom_per_step, sweep if n_cfg is not None else None),
+        build, operands,
     )
 
-    def finalize(host):
-        if prog.mobility is not None:
-            # once per LAUNCH (a sweep's vmapped while_loop advances
-            # every point's step counter in lockstep, so the lanes
-            # agree on the shared loop's step count)
-            from tpudes.obs.geometry import GeomTelemetry
+    def call(run, carry, max_steps, ops):
+        # finished replicas are a fixed point of step_fn, so later
+        # segments cost them one cond evaluation
+        state, still_pending, metrics = run(carry[0], key, max_steps, *ops)
+        return (state, still_pending), metrics
 
-            stride = max(1, int(prog.geom_stride))
-            steps = int(np.max(host["step"]))
-            GeomTelemetry.record_device("bss", -(-steps // stride), steps)
-        return unstack(host)
+    def fetch(carry):
+        # steps/all_done ride along instead of costing their own round
+        # trips
+        out, still_pending = carry
+        names = ("srv_rx", "cli_rx", "tx_data", "drops", "step")
+        if L.obs:
+            from tpudes.obs.flowmon import FM_KEYS
 
-    fut = EngineFuture("bss", fetch, finalize_with_flush(flush, finalize))
-    return fut.result() if block else fut
+            names += ("retx",) + FM_KEYS
+        return dict({k: out[k] for k in names}, pending=still_pending)
+
+    def record_geometry(host, points):
+        # a sweep's vmapped while_loop advances every point's step
+        # counter in lockstep, so the lanes agree on the shared loop's
+        # step count
+        from tpudes.obs.geometry import GeomTelemetry
+
+        stride = max(1, int(prog.geom_stride))
+        steps = int(np.max(host["step"]))
+        GeomTelemetry.record_device("bss", -(-steps // stride), steps)
+
+    return L.drive(
+        call,
+        chunk_bounds(max_steps, chunk_steps or max_steps),
+        fetch,
+        lambda host: _bss_unpack(host, replicas, L.obs, prog),
+        once=record_geometry if mobile else None,
+        checkpoint=checkpoint,
+        identity=lambda: _prog_cache_key(prog) + (
+            tuple(ends), geom_per_step,
+            # traffic identity by VALUE (shape key alone would let a
+            # resumed run silently swap workloads mid-study)
+            None if prog.traffic is None else prog.traffic.param_key(),
+            None if traffic_sweep is None
+            else tuple(tp.param_key() for tp in traffic_sweep),
+        ),
+        block=block,
+    )
 
 
 # --- trace manifest (tpudes.analysis.jaxpr) --------------------------------

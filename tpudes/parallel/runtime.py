@@ -1,18 +1,25 @@
-"""Shared engine runtime: runner cache, shape bucketing, donation,
-and persistent-compile-cache wiring for the device engines.
+"""Shared engine runtime: the one launch path, the runner cache, shape
+bucketing, donation and the persistent compile cache of the device
+engines (replicated BSS, LTE SM, TCP dumbbell, AS flows, wired).
 
-Every device engine (replicated BSS, LTE SM, TCP dumbbell, AS flows)
-used to carry its own module-level runner dict with ad-hoc eviction,
-its own idea of what belongs in the cache key, and its own launch
-conventions.  This module is the one runtime they all route through:
+- :class:`Launch` — what a launch IS, written once.  An engine's
+  ``run_*`` describes what only it knows (its cache key, its builder,
+  its operand tables, how its advance program takes them, what it
+  fetches and how it unpacks); the launch does the rest in one order
+  for all of them: bucket the replica axis, find or build the runner,
+  make the carry with the entry's init program, fingerprint a
+  checkpoint, time a compile, dispatch one advance program per
+  segment, block when that call compiled, chain the metrics flush in
+  front of the unpack, return the result or its future.  A warm launch
+  of any engine dispatches two programs: ``tpudes_<engine>_init`` and
+  ``tpudes_<engine>_advance``.
 
 - :class:`EngineRuntime` / :data:`RUNTIME` — one process-wide runner
   registry with **true LRU eviction** (a cache hit moves the entry to
-  the back of the eviction order; the old per-engine dicts popped the
-  *insertion*-oldest entry, so a hot runner could be evicted while a
-  stale one survived).  Misses call the engine's ``build`` thunk and
-  report ``compiled_new`` so :class:`~tpudes.obs.device.CompileTelemetry`
-  is triggered from exactly one place per engine.
+  the back of the eviction order).  Misses call the launch's ``build``
+  and report ``compiled_new``, so
+  :class:`~tpudes.obs.device.CompileTelemetry` is triggered from
+  exactly one place.
 
 - **Shape bucketing** (:func:`bucket_replicas`): the replica axis is
   padded up to the next power of two (and to a multiple of the mesh
@@ -33,11 +40,11 @@ conventions.  This module is the one runtime they all route through:
   growing R would silently reshuffle every replica's draws.)
   ``TPUDES_BUCKETING=0`` disables padding for A/B debugging.
 
-- :func:`donate_argnums` — the state carry crossing the jit boundary is
-  donated on accelerators (the (R, …) carry is rebuilt fresh per call,
-  so XLA may alias it into the loop buffers instead of copying);
-  XLA:CPU does not implement donation and warns per call, so the CPU
-  backend gets an empty donate list.
+- **Donation**: the state carry crossing the jit boundary is donated on
+  accelerators (it is made fresh per launch, so XLA may alias it into
+  the loop buffers instead of copying); XLA:CPU does not implement
+  donation and warns per call, so :func:`donate_argnums` gives the CPU
+  backend an empty list.
 
 - :func:`configure_persistent_cache` — arms jax's persistent
   compilation cache, so a *second process* running the same engines
@@ -63,25 +70,22 @@ conventions.  This module is the one runtime they all route through:
   assumed.
 
 - **Chunked horizons** (:func:`chunk_bounds`): a long horizon splits
-  into fixed-size ``while_loop`` segments; the engines hand the carry
+  into fixed-size ``while_loop`` segments; the launch hands the carry
   from segment to segment (donated, so the state never copies) and
-  return a small per-chunk metrics tree that streams to
-  :class:`tpudes.obs.device.ChunkStream` while the *next* chunk runs.
+  each segment returns a small metrics tree that streams to
+  :class:`tpudes.obs.device.ChunkStream` while the *next* one runs.
   Results are bit-identical to a single-shot run because every step's
   randomness is ``fold_in(key, t)`` — pure in t, indifferent to where
   the segment boundaries fall.
 
-- **Launch-path spans and device names**: the shared halves of the
-  launch are timed here, always on (:mod:`tpudes.obs.spans`):
-  ``launch.runner`` in :meth:`EngineRuntime.runner`,
-  ``launch.enqueue`` in :func:`drive_chunks`, ``result.wait`` /
-  ``.fetch`` / ``.unpack`` in :class:`EngineFuture`, whose constructor
-  also ends the ``launch`` span ``run_lifted`` opened (each ``run_*``
-  adds ``launch.operands`` around its own carry set-up, which for the
-  engines the benchmark runs is one call of a :func:`jit_init`
-  program).
-  :func:`scoped_while_loop` gives every engine's outermost loop the
-  stable device names ``tpudes.<engine>.step`` / ``.cond``.
+- **Launch-path spans and device names**, always on
+  (:mod:`tpudes.obs.spans`): ``launch.runner`` in
+  :meth:`EngineRuntime.runner`, ``launch.operands`` in
+  :meth:`Launch.prepare`, ``launch.enqueue`` in :func:`drive_chunks`,
+  ``result.wait`` / ``.fetch`` / ``.unpack`` in :class:`EngineFuture`,
+  whose constructor also ends the ``launch`` span ``run_lifted``
+  opened.  :func:`scoped_while_loop` gives every engine's outermost
+  loop the stable device names ``tpudes.<engine>.step`` / ``.cond``.
 """
 
 from __future__ import annotations
@@ -95,22 +99,16 @@ __all__ = [
     "RUNTIME",
     "EngineFuture",
     "EngineRuntime",
+    "Launch",
     "bucket_replicas",
-    "bucketing_enabled",
     "chunk_bounds",
     "configure_persistent_cache",
     "donate_argnums",
-    "drive_chunks",
-    "finalize_with_flush",
-    "inflight_window",
-    "jit_advance",
-    "jit_init",
     "pow2_bucket",
     "replica_keys",
     "scoped_while_loop",
     "shard_replica_axis",
     "stack_axis",
-    "unstack_points",
 ]
 
 
@@ -184,15 +182,15 @@ def chunk_bounds(total: int, chunk: int) -> list[int]:
 
 def drive_chunks(engine: str, bounds, carry, launch, obs: bool,
                  checkpoint=None):
-    """The one chunk-dispatch protocol every engine runs: one device
+    """The chunk-dispatch protocol of :meth:`Launch.drive`: one device
     launch per bound; when observability is up AND the run is actually
     chunked (>1 bound — a single-shot run has no chunk stream), chunk
     k's metrics are fetched only after chunk k+1 is dispatched, so the
     D2H overlaps the next segment's compute.  Returns ``(carry,
     flush)``: the final carry plus a deferred thunk (or None) that
-    records the LAST chunk's metrics — the engines run it inside their
-    EngineFuture finalize, so a ``block=False`` caller's dispatch never
-    blocks on a metrics fetch.
+    records the LAST chunk's metrics — it runs inside the
+    EngineFuture's finalize, so a ``block=False`` caller's dispatch
+    never blocks on a metrics fetch.
 
     ``launch(carry, bound) -> (carry', metrics)`` — INVARIANT: every
     leaf of ``metrics`` must be a FRESH device value (a reduction or
@@ -244,46 +242,12 @@ def drive_chunks(engine: str, bounds, carry, launch, obs: bool,
     return carry, flush
 
 
-def finalize_with_flush(flush, finalize):
-    """Chain the deferred last-chunk metrics flush in front of an
-    EngineFuture finalize (identity when there is nothing to flush)."""
-    if flush is None:
-        return finalize
-
-    def wrapped(host):
-        flush()
-        return finalize(host)
-
-    return wrapped
-
-
-def unstack_points(n_cfg: int | None, unpack_one, shared=()):
-    """Build the EngineFuture ``finalize``: without a config axis the
-    fetched host tree unpacks directly; with one, each point's slice of
-    the leading axis unpacks separately (``shared`` names keys with no
-    config axis — per-flow statics identical across points)."""
-
-    def finalize(host):
-        if n_cfg is None:
-            return unpack_one(host)
-        return [
-            unpack_one(
-                {k: (v if k in shared else v[i]) for k, v in host.items()}
-            )
-            for i in range(n_cfg)
-        ]
-
-    return finalize
-
-
 def stack_axis(tree, n: int | None):
     """Broadcast every leaf of ``tree`` to a new leading axis of size
     ``n`` (None passes through) — how the engines stack the initial
     carry over the replica and config axes.  One ``broadcast_to`` per
-    leaf: ``bss`` and ``lte_sm`` call it only while their
-    :func:`jit_init` program is traced; ``dumbbell`` and ``as_flows``
-    still call it eagerly on every launch, a dispatched program per
-    leaf (ROADMAP C2 folds their set-ups into the same form)."""
+    leaf, so it belongs inside a carry builder that :func:`jit_init`
+    traces, where the whole carry is one program."""
     if n is None:
         return tree
     import jax
@@ -312,9 +276,8 @@ def shard_replica_axis(tree, mesh, r_pad: int | None, axis: int):
     leaves pass through).  ``axis`` is 0 for plain runs, 1 when a
     config axis leads.  One transfer per leaf: a :func:`jit_init`
     program places its outputs by the same rule with no transfer, so
-    what still comes here are arrays that exist already — a restored
-    checkpoint's carry (``checkpoint.py``) and the eager set-ups of
-    ``dumbbell``, ``wired`` and ``as_flows`` (ROADMAP C2)."""
+    this is for arrays that exist already — a restored checkpoint's
+    carry (``checkpoint.py``)."""
     if mesh is None or r_pad is None:
         return tree
     import jax
@@ -371,14 +334,14 @@ def _named(fn, name: str):
 
 
 def jit_advance(engine: str, fn):
-    """The engines' one way to jit an advance function: the carry
-    (argument 0) donated on accelerators, and the program NAMED
-    ``tpudes_<engine>_advance``.  The name is what a profile's ``XLA
-    Modules`` line shows (every engine's used to read ``jit_advance``),
-    and it is part of jax's persistent-cache key, which ignores
-    operation metadata: without it an executable cached before
-    :func:`scoped_while_loop` existed is served again with its old,
-    scope-less operation names (seen on the chip, PERF.md PR 25)."""
+    """How :meth:`Launch.prepare` jits an engine's advance function:
+    the carry (argument 0) donated on accelerators, and the program
+    NAMED ``tpudes_<engine>_advance``.  The name is what a profile's
+    ``XLA Modules`` line shows, and it is part of jax's
+    persistent-cache key, which ignores operation metadata: two
+    programs that differ only in their :func:`scoped_while_loop` names
+    would otherwise share one cached executable (seen on the chip,
+    PERF.md PR 25)."""
     import jax
 
     return jax.jit(
@@ -442,27 +405,28 @@ class InitProgram:
 
 
 def jit_init(engine: str, fn, r_pad: int | None, axes) -> InitProgram:
-    """The engines' one way to make a launch's initial carry: ``fn``,
-    the engine's carry builder (``init_state()``, :func:`stack_axis`
-    over the replica and config axes, :func:`replica_keys`), jitted
-    under the program name ``tpudes_<engine>_init`` and built once per
-    runner, inside its ``build()``, so that a warm launch dispatches
-    two executables (init, advance) where the eager form dispatched
-    one per leaf per operation (33 a BSS launch, 50 an LTE launch, 68
-    on a four-chip mesh: 10 to 37 ms of host time with the chip idle,
-    PERF.md PR 25).
+    """How :meth:`Launch.prepare` makes a launch's initial carry:
+    ``fn``, the engine's carry builder (``init_state()``,
+    :func:`stack_axis` over the replica and config axes,
+    :func:`replica_keys`, per-replica draws), jitted under the program
+    name ``tpudes_<engine>_init`` and kept in the runner's cache entry
+    beside the advance program, so that a warm launch dispatches two
+    executables.  (Built eagerly the same carry is a dispatched
+    program per leaf per operation: 33 a BSS launch, 50 an LTE launch,
+    68 on a four-chip mesh, 10 to 37 ms of host time with the chip
+    idle, PERF.md PR 25.)
 
-    ``fn(*args)`` returns a TUPLE of parts; its only runtime argument
-    is the run key (the BSS builder takes none), shapes are static and
-    already in the runner's cache key.  ``axes`` names, per part, the
-    dimension that carries the replica axis in that part's leaves
+    ``fn(*args)`` returns a TUPLE of parts; its runtime arguments are
+    traced (the run key, ``wired``'s replica offset), shapes are static
+    and already in the runner's cache key.  ``axes`` names, per part,
+    the dimension that carries the replica axis in that part's leaves
     (None: the part has none).  On a mesh the program places its
     outputs itself, by :func:`shard_replica_axis`'s rule: a leaf of a
     part with an axis whose dimension there equals ``r_pad`` is
     sharded over "replica", every other leaf is replicated, so each
     chip makes its own shard and no ``device_put`` follows.  Without a
     mesh the outputs are uncommitted single-device arrays, exactly
-    what the eager ``jnp`` calls gave, so the advance program sees the
+    what eager ``jnp`` calls give, so the advance program sees the
     avals and shardings it was compiled for.
 
     ``RUNTIME.stats()["init_programs"]`` counts the executables made
@@ -591,6 +555,149 @@ class EngineFuture:
             self._device_out = None  # release the device buffers
             self._done = True
         return self._result
+
+
+class Launch:
+    """One launch of a device engine: the ONE place that knows what a
+    launch is.  The engine's ``run_*`` says what only it knows, in two
+    steps; everything else (the order of the steps, what is timed,
+    what is counted, when the call blocks, how the carry is made and
+    placed) happens here, once, for every engine.
+
+    ``Launch(engine, key, replicas, mesh, n_cfg)`` buckets the replica
+    axis (``r_pad``) and reads the observability switch (``obs``);
+    ``n_cfg`` is the size of the leading config axis of a sweep (None:
+    no such axis), ``axis`` the dimension the replica axis then has.
+
+    :meth:`prepare` finds or builds the runner (``launch.runner``) and
+    makes this call's carry and operands (``launch.operands``); it
+    dispatches the init program and nothing else, so an inspector
+    (``lte_sm.compiled_step_lowering``) stops after it and lowers
+    ``fn`` with the run's own ``carry`` and ``ops``.  :meth:`drive`
+    dispatches the advance program once per bound
+    (``launch.enqueue``) and returns the result or, with
+    ``block=False``, its :class:`EngineFuture`."""
+
+    __slots__ = ("engine", "key", "replicas", "mesh", "n_cfg", "axis",
+                 "r_pad", "obs", "fn", "aux", "compiling", "carry", "ops")
+
+    def __init__(self, engine: str, key, replicas, mesh, n_cfg):
+        from tpudes.obs.device import device_metrics_enabled
+
+        self.engine = engine
+        self.key = key
+        self.replicas = replicas
+        self.mesh = mesh
+        self.n_cfg = n_cfg
+        # where the replica axis sits in a leaf that carries the
+        # config axis too (which leads)
+        self.axis = 0 if n_cfg is None else 1
+        # padded replicas are real independent simulations whose rows
+        # the engine's unpack slices off again
+        self.r_pad = bucket_replicas(replicas, mesh)
+        self.obs = device_metrics_enabled()
+
+    def prepare(self, static_key, build, operands, init_args=()):
+        """``static_key()`` is the runner's cache key: the engine's
+        cache-key helper plus what else shapes the executable
+        (``r_pad``, ``obs``, ``n_cfg``, ...); it runs once, inside
+        ``launch.runner``.  ``build()`` runs at a miss and returns
+        ``(init_fn, axes, advance, aux)``, both functions UN-jitted:
+        they become the entry's :func:`jit_init` and
+        :func:`jit_advance` programs here (``axes`` as in
+        :func:`jit_init`); ``aux`` is whatever host value the engine
+        wants kept with them.  ``operands(parts)`` gets what
+        ``init_fn(*init_args)`` returned, from the init program and
+        already placed on the mesh, and returns ``(carry, ops)``: the
+        first carry and the operand tables of this call, as numpy
+        (the aval ``jnp`` would give, without an eager transfer)."""
+        engine, r_pad = self.engine, self.r_pad
+
+        def build_entry():
+            init_fn, axes, advance, aux = build()
+            return (jit_init(engine, init_fn, r_pad, axes),
+                    jit_advance(engine, advance), aux)
+
+        (init, self.fn, self.aux), self.compiling = RUNTIME.runner(
+            engine, static_key, build_entry
+        )
+        with spans.span("launch.operands"):
+            self.carry, self.ops = operands(init(self.mesh, *init_args))
+        return self
+
+    def drive(self, call, bounds, fetch, unpack_one, *, shared=(),
+              once=None, checkpoint=None, identity=None, block=True):
+        """``call(fn, carry, bound, ops) -> (carry', metrics)`` is how
+        this engine's advance program takes its arguments (``bound``
+        arrives as ``np.int32``; ``metrics`` under :func:`drive_chunks`'s
+        invariant); ``bounds`` the segment ends
+        (:func:`chunk_bounds`).  ``fetch(carry)`` picks the leaves of
+        the FINAL carry that go to the host, as one dict and so one
+        batched transfer; ``unpack_one(host)`` assembles one config
+        point's result from it (with a config axis each point gets its
+        slice of every fetched array but those named in ``shared``,
+        per-flow statics identical across points).  ``once(host,
+        points)`` runs once per launch after the unpack, ``points`` the
+        list of per-point results: a sweep shares one loop, so
+        per-launch telemetry recorded per point would count it
+        ``n_cfg``-fold.  ``identity()`` is what a ``checkpoint`` adds
+        to the run's fingerprint: every value that, if changed, would
+        make a saved carry mean another study."""
+        import jax
+        import numpy as np
+
+        from tpudes.obs.device import CompileTelemetry
+
+        engine, fn, ops = self.engine, self.fn, self.ops
+        ckpt = None
+        if checkpoint is not None:
+            from tpudes.parallel.checkpoint import checkpoint_ctx
+
+            ckpt = checkpoint_ctx(
+                checkpoint, engine=engine, key=self.key,
+                replicas=self.replicas, r_pad=self.r_pad,
+                n_cfg=self.n_cfg, obs=self.obs, axis=self.axis,
+                mesh=self.mesh,
+                extra=identity(),
+            )
+        # the first segment takes (on accelerators: donates) the carry,
+        # so the launch, which the unpack closures keep alive as long
+        # as the future, lets go of it
+        carry, self.carry = self.carry, None
+        with CompileTelemetry.timed(engine, self.compiling):
+            # chunking reuses the SAME executable: each segment only
+            # raises the traced bound
+            carry, flush = drive_chunks(
+                engine, bounds, carry,
+                lambda c, bound: call(fn, c, np.int32(bound), ops),
+                self.obs, checkpoint=ckpt,
+            )
+            if self.compiling:
+                # the recorded compile time must include the compile
+                jax.block_until_ready(carry)
+        n_cfg = self.n_cfg
+
+        def finalize(host):
+            if flush is not None:
+                flush()        # the last segment's deferred metrics
+            if n_cfg is None:
+                out = unpack_one(host)
+                points = [out]
+            else:
+                # each point's slice of the leading config axis
+                out = points = [
+                    unpack_one({
+                        k: (v if k in shared else v[i])
+                        for k, v in host.items()
+                    })
+                    for i in range(n_cfg)
+                ]
+            if once is not None:
+                once(host, points)
+            return out
+
+        fut = EngineFuture(engine, fetch(carry), finalize)
+        return fut.result() if block else fut
 
 
 class EngineRuntime:

@@ -1265,23 +1265,18 @@ def _tcp_fetch_obs():
     return ("cwnd_cuts", "retx_cnt", "q_hist") + FM_KEYS
 
 
-def _planted_divergence(finalize):
+def _planted_divergence(host, points):
     """``TPUDES_FUZZ_PLANTED_BUG=1``: deliberately corrupt CHUNKED-run
     results (replica 0, flow 0: ``delivered`` += 1) so the fuzz
     harness's planted-bug self-test (tests/test_fuzz.py + the CI step)
     can prove the scalar-vs-chunked oracle detects, shrinks and replays
     a real divergence end to end.  Never on outside that self-test —
-    the flag is read per call and gates nothing else."""
-
-    def wrapped(host):
-        out = finalize(host)
-        for point in out if isinstance(out, list) else [out]:
-            d = np.array(point["delivered"], copy=True)
-            d[0, 0] += 1
-            point["delivered"] = d
-        return out
-
-    return wrapped
+    the flag is read per call and gates nothing else.  (A
+    once-per-launch hook of the launch, run on the unpacked points.)"""
+    for point in points:
+        d = np.array(point["delivered"], copy=True)
+        d[0, 0] += 1
+        point["delivered"] = d
 
 
 def _tcp_unpack(host: dict, prog: DumbbellProgram, replicas: int,
@@ -1426,21 +1421,7 @@ def run_tcp_dumbbell(
     completed chunk, bit-equal to uninterrupted.  ``block=False``
     returns an :class:`~tpudes.parallel.runtime.EngineFuture`.
     """
-    from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
-    from tpudes.obs.spans import span
-    from tpudes.parallel.checkpoint import checkpoint_ctx
-    from tpudes.parallel.runtime import (
-        RUNTIME,
-        EngineFuture,
-        bucket_replicas,
-        chunk_bounds,
-        drive_chunks,
-        finalize_with_flush,
-        jit_advance,
-        shard_replica_axis,
-        stack_axis,
-        unstack_points,
-    )
+    from tpudes.parallel.runtime import Launch, chunk_bounds, stack_axis
 
     if variants is not None and traffic_sweep is not None:
         raise ValueError(
@@ -1448,38 +1429,35 @@ def run_tcp_dumbbell(
             "assignment (variants=[...]) or the workload "
             "(traffic_sweep=[...])"
         )
-    obs = device_metrics_enabled()
-    r_pad = bucket_replicas(replicas, mesh)
     sweep = "traffic" if traffic_sweep is not None else "variant"
     n_cfg = (
         len(variants) if variants is not None
         else (len(traffic_sweep) if traffic_sweep is not None else None)
     )
+    L = Launch("dumbbell", key, replicas, mesh, n_cfg)
+
     def build():
         init_state, fn = build_dumbbell_advance(
-            prog, r_pad, obs=obs, n_cfg=n_cfg, sweep=sweep
+            prog, L.r_pad, obs=L.obs, n_cfg=n_cfg, sweep=sweep
         )
-        return init_state, jit_advance("dumbbell", fn)
+        return (
+            lambda: (stack_axis((jnp.int32(0), init_state()), n_cfg),),
+            (L.axis,), fn, None,
+        )
 
-    # see dumbbell_prog_key for what is (deliberately) absent; the
-    # sweep KIND is a cache-key component (the two sweeps vmap
-    # different operands — different executables)
-    (init_state, fn), compiling = RUNTIME.runner(
-        "dumbbell",
-        lambda: dumbbell_prog_key(prog) + (r_pad, obs, n_cfg, sweep),
-        build,
-    )
+    if variants is None:
+        points = [np.asarray(prog.variant_idx, np.int32)]
+    else:
+        points = [_variant_point(p) for p in variants]
 
-    with span("launch.operands"):
+    def operands(parts):
         if variants is None:
-            points = [np.asarray(prog.variant_idx, np.int32)]
             ecns = [
                 np.asarray(prog.ecn, bool)
                 if prog.ecn is not None
                 else np.zeros(prog.n_flows, bool)
             ]
         else:
-            points = [_variant_point(p) for p in variants]
             ecns = [_variant_ecn(p) for p in points]
             for p in points:
                 if p.shape != (prog.n_flows,):
@@ -1487,23 +1465,11 @@ def run_tcp_dumbbell(
                         f"each sweep point assigns all {prog.n_flows} flows "
                         f"(got shape {p.shape})"
                     )
-        var = jnp.asarray(
-            points[0] if n_cfg is None or sweep == "traffic"
-            else np.stack(points)
-        )
-        ecn = jnp.asarray(
-            ecns[0] if n_cfg is None or sweep == "traffic"
-            else np.stack(ecns)
-        )
-
-        carry = (jnp.int32(0), init_state())
-        carry = stack_axis(carry, n_cfg)
-        carry = shard_replica_axis(
-            carry, mesh, r_pad, 0 if n_cfg is None else 1
-        )
-
-        # workload params ride as TRACED operands (None = the bulk path);
-        # the runner cache key above carries only the traffic shape key
+        one = n_cfg is None or sweep == "traffic"
+        var = points[0] if one else np.stack(points)
+        ecn = ecns[0] if one else np.stack(ecns)
+        # workload params ride as TRACED operands (None = the bulk
+        # path); the runner's key carries only the traffic shape key
         if traffic_sweep is not None:
             from tpudes.traffic.device import stack_traffic_operands
 
@@ -1519,43 +1485,41 @@ def run_tcp_dumbbell(
             tr = stack_traffic_operands(traffic_sweep)
         else:
             tr = None if prog.traffic is None else prog.traffic.operands()
-    ckpt = checkpoint_ctx(
-        checkpoint, engine="dumbbell", key=key, replicas=replicas,
-        r_pad=r_pad, n_cfg=n_cfg, obs=obs,
-        axis=0 if n_cfg is None else 1, mesh=mesh,
-        extra=dumbbell_prog_key(prog)
-        + (tuple(tuple(int(i) for i in p) for p in points),
-           None if prog.traffic is None else prog.traffic.param_key(),
-           None if traffic_sweep is None
-           else tuple(tp.param_key() for tp in traffic_sweep)),
-    )
-    with CompileTelemetry.timed("dumbbell", compiling):
-        carry, flush = drive_chunks(
-            "dumbbell",
-            chunk_bounds(prog.n_slots, chunk_slots or prog.n_slots),
-            carry,
-            lambda c, t_end: fn(c, key, var, ecn, jnp.int32(t_end), tr),
-            obs,
-            checkpoint=ckpt,
-        )
-        if compiling:
-            jax.block_until_ready(carry)
+        return parts[0], (var, ecn, tr)
 
-    keys = _TCP_FETCH + (_tcp_fetch_obs() if obs else ())
-    fetch = {k: carry[1][k] for k in keys}
-    finalize = finalize_with_flush(
-        flush,
-        unstack_points(
-            n_cfg, lambda host: _tcp_unpack(host, prog, replicas, obs)
-        ),
+    # see dumbbell_prog_key for what is (deliberately) absent; the
+    # sweep KIND is a cache-key component (the two sweeps vmap
+    # different operands — different executables)
+    L.prepare(
+        lambda: dumbbell_prog_key(prog) + (L.r_pad, L.obs, n_cfg, sweep),
+        build, operands,
     )
-    if (
-        chunk_slots is not None
-        and os.environ.get("TPUDES_FUZZ_PLANTED_BUG") == "1"
-    ):
-        finalize = _planted_divergence(finalize)
-    fut = EngineFuture("dumbbell", fetch, finalize)
-    return fut.result() if block else fut
+
+    def call(fn, carry, t_end, ops):
+        var, ecn, tr = ops
+        return fn(carry, key, var, ecn, t_end, tr)
+
+    names = _TCP_FETCH + (_tcp_fetch_obs() if L.obs else ())
+    return L.drive(
+        call,
+        chunk_bounds(prog.n_slots, chunk_slots or prog.n_slots),
+        lambda carry: {k: carry[1][k] for k in names},
+        lambda host: _tcp_unpack(host, prog, replicas, L.obs),
+        once=(
+            _planted_divergence
+            if chunk_slots is not None
+            and os.environ.get("TPUDES_FUZZ_PLANTED_BUG") == "1"
+            else None
+        ),
+        checkpoint=checkpoint,
+        identity=lambda: dumbbell_prog_key(prog) + (
+            tuple(tuple(int(i) for i in p) for p in points),
+            None if prog.traffic is None else prog.traffic.param_key(),
+            None if traffic_sweep is None
+            else tuple(tp.param_key() for tp in traffic_sweep),
+        ),
+        block=block,
+    )
 
 
 # --- trace manifest (tpudes.analysis.jaxpr) --------------------------------

@@ -423,7 +423,7 @@ def _replica_jitter(prog: WiredProgram, key, replicas: int,
 
         return jax.vmap(per_flow)(ids)
 
-    return jax.vmap(one)(jnp.arange(replicas) + int(replica_offset))
+    return jax.vmap(one)(jnp.arange(replicas) + jnp.int32(replica_offset))
 
 
 def _lane_tables(paths_np, pkt_flow_np, pkt_nhops_np, service_np,
@@ -1076,62 +1076,41 @@ def run_wired(
     ``block=False`` returns an
     :class:`~tpudes.parallel.runtime.EngineFuture`.
     """
-    import jax
     import jax.numpy as jnp
 
-    from tpudes.obs.device import CompileTelemetry, device_metrics_enabled
-    from tpudes.obs.spans import span
-    from tpudes.parallel.runtime import (
-        RUNTIME,
-        EngineFuture,
-        bucket_replicas,
-        chunk_bounds,
-        drive_chunks,
-        finalize_with_flush,
-        jit_advance,
-        shard_replica_axis,
-    )
+    from tpudes.parallel.runtime import Launch, chunk_bounds
 
-    r_pad = bucket_replicas(replicas, mesh)
-    obs = device_metrics_enabled()
+    L = Launch("wired", key, replicas, mesh, None)
+    r_pad = L.r_pad
 
     def build():
-        init_state, advance = build_wired_advance(prog, r_pad, obs=obs)
-        return init_state, jit_advance("wired", advance)
+        init_state, advance = build_wired_advance(prog, r_pad, obs=L.obs)
+
+        def init(key, replica_offset):
+            carry = init_state(key, replica_offset)
+            # a run without peers: no window has ingress traffic
+            none = jnp.full((r_pad, carry["hop"].shape[1]), -1, jnp.int32)
+            return carry, (none, none)
+
+        return init, (0, 0), advance, None
 
     # see wired_cache_key for what is (deliberately) absent;
-    # replica_offset only shifts host-side init-state construction
-    (init_state, fn), compiling = RUNTIME.runner(
-        "wired", lambda: wired_cache_key(prog) + (r_pad, obs), build
+    # replica_offset is a traced argument of the init program
+    L.prepare(
+        lambda: wired_cache_key(prog) + (r_pad, L.obs),
+        build, lambda parts: parts,
+        init_args=(key, np.int32(replica_offset)),
     )
 
-    with span("launch.operands"):
-        carry = init_state(key, replica_offset)
-        carry = shard_replica_axis(carry, mesh, r_pad, 0)
-        no_ingress = (
-            jnp.full((r_pad, carry["hop"].shape[1]), -1, jnp.int32),
-            jnp.full((r_pad, carry["hop"].shape[1]), -1, jnp.int32),
-        )
-    bounds = chunk_bounds(prog.n_slots, window_slots or prog.n_slots)
-    with CompileTelemetry.timed("wired", compiling):
-        carry, flush = drive_chunks(
-            "wired",
-            bounds,
-            carry,
-            lambda c, t_end: fn(c, *no_ingress, jnp.int32(t_end)),
-            obs,
-        )
-        if compiling:
-            jax.block_until_ready(carry)
+    def fetch(carry):
+        names = ("deliver", "served")
+        if L.obs:
+            from tpudes.obs.flowmon import FM_KEYS
 
-    fetch = dict(deliver=carry["deliver"], served=carry["served"])
-    if obs:
-        from tpudes.obs.flowmon import FM_KEYS
+            names += FM_KEYS
+        return {k: carry[k] for k in names}
 
-        for k in FM_KEYS:
-            fetch[k] = carry[k]
-
-    def finalize(host):
+    def unpack_one(host):
         out = _wired_unpack(host, prog, replicas)
         fm = {
             k: np.asarray(v)[:replicas]
@@ -1157,8 +1136,15 @@ def run_wired(
             out["flow"] = fm
         return out
 
-    fut = EngineFuture("wired", fetch, finalize_with_flush(flush, finalize))
-    return fut.result() if block else fut
+    return L.drive(
+        lambda fn, carry, t_grant, no_ingress: fn(
+            carry, *no_ingress, t_grant
+        ),
+        chunk_bounds(prog.n_slots, window_slots or prog.n_slots),
+        fetch,
+        unpack_one,
+        block=block,
+    )
 
 
 def run_wired_host(prog: WiredProgram, jitter: np.ndarray | None = None) -> dict:
