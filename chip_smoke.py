@@ -16,7 +16,8 @@ through the entry points a user calls:
    rerun;
 2. the LTE TTI step under ``TPUDES_PALLAS=1`` and ``=0``, naming the
    lowering each run COMPILED (read from the executable, not from the
-   environment variable) and the agreement found;
+   environment variable) and the agreement found, and what the unset
+   variable compiles at the batched and the unbatched lane count;
 3. every engine once more chunked under ``TpudesObs=1`` at a short
    horizon, so carry donation and the chunk-metric snapshots execute
    on a backend that donates;
@@ -241,10 +242,18 @@ def phase_lte_lowerings(prog, key, replicas: int,
     """Run the LTE program under ``TPUDES_PALLAS=1`` and ``=0``; name
     the lowering each executable holds (``expect_pallas`` is what =1
     must compile to on this backend: "mosaic" on a TPU, "xla" where
-    pallas runs discharged) and state the agreement found."""
+    pallas runs discharged) and state the agreement found.  With the
+    variable unset the engine picks by lane count
+    (``lte_sm._sm_use_pallas``): the batched launch and an unbatched
+    one are read back too (``lowered["unset"]``, ``["unset_solo"]``)."""
     import numpy as np
 
-    from tpudes.parallel.lte_sm import compiled_step_lowering, run_lte_sm
+    from tpudes.parallel.lte_sm import (
+        SM_KERNEL_MAX_LANES,
+        compiled_step_lowering,
+        run_lte_sm,
+    )
+    from tpudes.parallel.runtime import bucket_replicas
 
     outs, lowered, walls = {}, {}, {}
     for flag in ("1", "0"):
@@ -254,6 +263,17 @@ def phase_lte_lowerings(prog, key, replicas: int,
             walls[flag] = time.monotonic() - t0
             lowered[flag] = compiled_step_lowering(
                 prog, key, replicas=replicas
+            )
+    with mock.patch.dict(os.environ):
+        os.environ.pop("TPUDES_PALLAS", None)
+        for name, r in (("unset", replicas), ("unset_solo", None)):
+            lowered[name] = compiled_step_lowering(prog, key, replicas=r)
+            kernel = (bucket_replicas(r) or 1) <= SM_KERNEL_MAX_LANES
+            check(
+                lowered[name] == (expect_pallas if kernel else "xla"),
+                f"TPUDES_PALLAS unset, replicas={r}: compiled the "
+                f"{lowered[name]!r} step; the rule keeps the kernel up "
+                f"to {SM_KERNEL_MAX_LANES} lanes",
             )
     check(
         lowered["1"] == expect_pallas,
@@ -579,7 +599,9 @@ def main() -> int:
     )
     print(
         f"chip_smoke: lte lowering TPUDES_PALLAS=1 -> {low['lowered']['1']}, "
-        f"=0 -> {low['lowered']['0']}; bit_equal={low['bit_equal']} "
+        f"=0 -> {low['lowered']['0']}, unset -> {low['lowered']['unset']} "
+        f"({lte['replicas']} lanes) / {low['lowered']['unset_solo']} "
+        f"(1 lane); bit_equal={low['bit_equal']} "
         f"replicas_bit_equal={low['replicas_bit_equal']:.3f} "
         f"rel_diff_bits={low['rel_diff_bits']:.3e} "
         f"walls(1/0)={low['walls']['1']:.2f}s/{low['walls']['0']:.2f}s"

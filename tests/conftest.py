@@ -25,3 +25,23 @@ def fresh_world():
 
     yield
     reset_world()
+
+
+@pytest.fixture
+def sm_lowerings_built():
+    """``f(prog)`` -> the step lowerings among the cached ``lte_sm``
+    runners, read from their cache keys: ``{True}`` the Mosaic kernel
+    only, ``{False}`` the XLA step only, ``{True, False}`` both.  What
+    an A/B of the two lowerings asserts, so that it cannot silently
+    compare XLA with XLA (ISSUE 31: unset, ``TPUDES_PALLAS`` picks by
+    lane count)."""
+    from tpudes.parallel import lte_sm
+    from tpudes.parallel.runtime import RUNTIME
+
+    def built(prog) -> set:
+        flag = object()
+        # +1: the runtime prefixes the engine's name
+        at = 1 + lte_sm._sm_cache_key(prog, None, None, False, flag).index(flag)
+        return {k[at] for k in RUNTIME._runners if k[0] == "lte_sm"}
+
+    return built

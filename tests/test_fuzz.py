@@ -169,6 +169,29 @@ def test_corpus_entry_replays_clean(path):
 # --- planted bug: detect -> shrink -> artifact -> replay ------------------
 
 
+def test_lte_pallas_pair_builds_both_lowerings(monkeypatch, sm_lowerings_built):
+    """ISSUE 31: unset, ``TPUDES_PALLAS`` sends a batched draw to the
+    XLA step, so the pair forces its kernel side: it compares Mosaic
+    (interpret mode here) with XLA at every drawn replica count."""
+    from tpudes.fuzz import scenario_config
+    from tpudes.fuzz.engines import ENGINE_FUZZERS
+    from tpudes.parallel.lte_sm import SM_KERNEL_MAX_LANES
+    from tpudes.parallel.runtime import RUNTIME
+
+    monkeypatch.delenv("TPUDES_PALLAS", raising=False)
+    fz = ENGINE_FUZZERS["lte_sm"]
+    cfg = dict(
+        scenario_config("lte_sm", 3), replicas=SM_KERNEL_MAX_LANES + 1
+    )
+    prog = fz.build(cfg)
+    RUNTIME.clear("lte_sm")
+    canonical = fz.run_scalar(prog, cfg)
+    assert sm_lowerings_built(prog) == {False}    # above N: XLA
+    (pair,) = [f for n, f in fz.extra_pairs() if n == "pallas_vs_xla"]
+    assert pair(prog, cfg, canonical) is None
+    assert sm_lowerings_built(prog) == {True, False}
+
+
 @pytest.mark.slow  # ISSUE-21 tier-1 budget: CI's fuzz step runs the planted-bug drill
 def test_planted_bug_detected_shrunk_and_replayed(monkeypatch, tmp_path):
     from tpudes.fuzz import replay, run_scenario, shrink_divergence

@@ -8,6 +8,12 @@
 - **Flags are cache-key components**: flipping the kill switch or the
   precision mode compiles a distinct runner — never reuses a stale
   executable for different arithmetic.
+- **The lowering is picked by lane count** (ISSUE 31): with
+  ``TPUDES_PALLAS`` unset an unsharded launch of at most
+  ``SM_KERNEL_MAX_LANES`` lanes builds the kernel step, more lanes and
+  every mesh build the XLA step, for the three variants and the config
+  axis; ``=0`` / ``=1`` force.  Every A/B here sets ``=1`` on its
+  kernel side and asserts that both lowerings were built.
 - **Mixed precision**: the bf16 mode sweeps with ≤1 compile and one
   launch (the CI multi-device smoke rides this), stays within the
   engine-level throughput budget of the f32 mode, and holds the same
@@ -28,8 +34,10 @@ from tpudes.parallel.kernels_pallas import (
     build_sm_consts,
     build_sm_step_fn,
     pallas_enabled,
+    pallas_switch,
     sm_init_state,
 )
+from tpudes.parallel import lte_sm
 from tpudes.parallel.lte_sm import SM_SCHED_IDS, run_lte_sm
 from tpudes.parallel.programs import toy_lte_program
 from tpudes.parallel.runtime import RUNTIME
@@ -60,16 +68,123 @@ def _assert_same(a, b, msg=""):
 
 def test_pallas_knob_default_on_and_kill_switch(monkeypatch):
     monkeypatch.delenv("TPUDES_PALLAS", raising=False)
-    assert pallas_enabled()
+    assert pallas_enabled() and pallas_switch() is None
+    monkeypatch.setenv("TPUDES_PALLAS", " ")
+    assert pallas_enabled() and pallas_switch() is None
     for off in ("0", "false", "no", "OFF"):
         monkeypatch.setenv("TPUDES_PALLAS", off)
-        assert not pallas_enabled()
+        assert not pallas_enabled() and pallas_switch() is False
     monkeypatch.setenv("TPUDES_PALLAS", "1")
-    assert pallas_enabled()
+    assert pallas_enabled() and pallas_switch() is True
+
+
+# --- which lowering a launch builds (ISSUE 31) --------------------------
+
+N = lte_sm.SM_KERNEL_MAX_LANES
+
+
+def test_the_kernel_keeps_a_lane_count():
+    """The shapes below are written for a power of two >= 1; were the
+    kernel to lose at every lane count (N = 0) it would go, with these
+    tests, in a change of its own (ISSUE 31, tentpole step 5)."""
+    assert N >= 1 and N & (N - 1) == 0
+
+
+def _variant_prog(variant):
+    prog = _prog(n_ttis=8)
+    if variant == "traffic":
+        from tpudes.traffic import TrafficProgram
+
+        return dataclasses.replace(prog, traffic=TrafficProgram.cbr(
+            np.zeros(prog.n_ue, np.int32), np.full(prog.n_ue, 1, np.int64)
+        ))
+    if variant == "mobile":
+        from tpudes.scenarios import build_lena
+
+        lte, _ = build_lena(2, 3, mobility="const_velocity", speed=10.0)
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            return lte_sm.lower_lte_sm(lte, 0.008, geom_stride=2)
+    return prog
+
+
+def _built_step(prog, sm_lowerings_built, **launch):
+    """What ``run_lte_sm(prog, KEY, **launch)`` builds, read twice:
+    from the runner's cache key, and from the advance program's jaxpr
+    (a ``pallas_call`` is there or not)."""
+    RUNTIME.clear()
+    L, _, _ = lte_sm._sm_prepare(
+        prog, KEY, launch.get("replicas"), launch.get("mesh"),
+        launch.get("schedulers"),
+    )
+    jaxpr = lte_sm._sm_call(
+        L.fn.trace, L.carry, np.int32(prog.n_ttis), L.ops
+    ).jaxpr
+    (keyed,) = sm_lowerings_built(prog)
+    assert keyed == ("pallas_call" in str(jaxpr))
+    return "mosaic" if keyed else "xla"
+
+
+#: launch shape -> lanes, on both sides of N along the replica axis
+#: and along the config axis alone
+_SHAPES = {
+    "solo": (dict(), 1),
+    "at_N": (dict(replicas=N), N),
+    "above_N": (dict(replicas=N + 1), 2 * N),
+    "cfg_at_N": (dict(schedulers=["pf"] * N), N),
+    "cfg_above_N": (dict(schedulers=["pf", "rr"] * N), 2 * N),
+    "cfg_x_replicas": (dict(replicas=N, schedulers=["pf", "rr"]), 2 * N),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_SHAPES))
+@pytest.mark.parametrize("variant", ["plain", "traffic", "mobile"])
+def test_unset_switch_picks_the_lowering_by_lane_count(
+    monkeypatch, sm_lowerings_built, variant, shape
+):
+    monkeypatch.delenv("TPUDES_PALLAS", raising=False)
+    launch, lanes = _SHAPES[shape]
+    got = _built_step(_variant_prog(variant), sm_lowerings_built, **launch)
+    assert got == ("mosaic" if lanes <= N else "xla"), (lanes, N)
+
+
+@pytest.mark.parametrize("variant", ["plain", "traffic", "mobile"])
+def test_every_mesh_builds_the_xla_step(
+    monkeypatch, sm_lowerings_built, variant
+):
+    from tpudes.parallel.mesh import replica_mesh
+
+    prog = _variant_prog(variant)
+    for flag in (None, "1"):
+        if flag is None:
+            monkeypatch.delenv("TPUDES_PALLAS", raising=False)
+        else:
+            monkeypatch.setenv("TPUDES_PALLAS", flag)
+        for n_dev in (1, 2):
+            got = _built_step(
+                prog, sm_lowerings_built, replicas=2,
+                mesh=replica_mesh(n_dev),
+            )
+            assert got == "xla", (flag, n_dev)
+
+
+@pytest.mark.parametrize("shape", ["solo", "above_N", "cfg_x_replicas"])
+@pytest.mark.parametrize("variant", ["plain", "traffic", "mobile"])
+def test_set_switch_forces_the_lowering_at_every_lane_count(
+    monkeypatch, sm_lowerings_built, variant, shape
+):
+    prog = _variant_prog(variant)
+    launch, _ = _SHAPES[shape]
+    monkeypatch.setenv("TPUDES_PALLAS", "0")
+    assert _built_step(prog, sm_lowerings_built, **launch) == "xla"
+    monkeypatch.setenv("TPUDES_PALLAS", "1")
+    assert _built_step(prog, sm_lowerings_built, **launch) == "mosaic"
 
 
 @pytest.mark.parametrize("sched", list(SM_SCHED_IDS))
-def test_interpret_mode_bit_parity_every_scheduler(monkeypatch, sched):
+def test_interpret_mode_bit_parity_every_scheduler(
+    monkeypatch, sm_lowerings_built, sched
+):
     """The Pallas kernel (interpret on CPU) and the XLA fallback run the
     SAME math core: bit equality per scheduler id."""
     prog = _prog(scheduler=sched)
@@ -77,6 +192,7 @@ def test_interpret_mode_bit_parity_every_scheduler(monkeypatch, sched):
     on = run_lte_sm(prog, KEY)
     monkeypatch.setenv("TPUDES_PALLAS", "0")
     off = run_lte_sm(prog, KEY)
+    assert sm_lowerings_built(prog) == {True, False}
     _assert_same(on, off, sched)
 
 
@@ -153,7 +269,9 @@ def test_kernel_compiles_for_v5e_through_mosaic(monkeypatch):
 
 
 @pytest.mark.parametrize("bucketing", ["1", "0"])
-def test_ab_equality_under_bucketing(monkeypatch, bucketing):
+def test_ab_equality_under_bucketing(
+    monkeypatch, sm_lowerings_built, bucketing
+):
     """TPUDES_PALLAS=0 A/B equality composed with the replica-axis
     bucketing knob: 3 replicas pad to 4 (or not at all) identically in
     both kernel modes."""
@@ -163,19 +281,22 @@ def test_ab_equality_under_bucketing(monkeypatch, bucketing):
     on = run_lte_sm(prog, KEY, replicas=3)
     monkeypatch.setenv("TPUDES_PALLAS", "0")
     off = run_lte_sm(prog, KEY, replicas=3)
+    assert sm_lowerings_built(prog) == {True, False}
     assert on["rx_bits"].shape == (3, prog.n_ue)
     _assert_same(on, off, f"bucketing={bucketing}")
 
 
-def test_ab_equality_8_point_scheduler_sweep(monkeypatch):
-    """The config-axis megabatch sweeps identically through both
-    lowerings — point by point, bit for bit."""
+def test_ab_equality_8_point_scheduler_sweep(monkeypatch, sm_lowerings_built):
+    """The config-axis megabatch (16 lanes: a batched shape the unset
+    switch would send to XLA on both sides) sweeps identically through
+    both lowerings — point by point, bit for bit."""
     prog = _prog()
     scheds = list(SM_SCHED_IDS)[:8]
     monkeypatch.setenv("TPUDES_PALLAS", "1")
     on = run_lte_sm(prog, KEY, replicas=2, schedulers=scheds)
     monkeypatch.setenv("TPUDES_PALLAS", "0")
     off = run_lte_sm(prog, KEY, replicas=2, schedulers=scheds)
+    assert sm_lowerings_built(prog) == {True, False}
     assert len(on) == len(off) == 8
     for s, a, b in zip(scheds, on, off):
         _assert_same(a, b, s)

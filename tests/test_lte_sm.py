@@ -635,7 +635,7 @@ def _sha(a):
     return [list(a.shape), h.hexdigest()[:16]]
 
 
-def golden_entry(prog_name, lowering, obs, case):
+def golden_entry(prog_name, lowering, obs, case, built=None):
     """What one ``run_lte_sm`` call gives, as JSON: the integer result
     arrays verbatim, the FlowMonitor columns and the per-chunk metrics
     as ``[shape, sha256]``.  ``toy`` is the issue's program (every
@@ -661,6 +661,10 @@ def golden_entry(prog_name, lowering, obs, case):
     ChunkStream.reset()
     try:
         out = run_lte_sm(prog, jax.random.PRNGKey(26), **GOLDEN_CASES[case])
+        if built is not None:
+            # the side asked for is the side built (conftest's
+            # sm_lowerings_built), at every lane count of the cases
+            assert built(prog) == {lowering == "pallas"}
     finally:
         if saved is None:
             del os.environ["TPUDES_PALLAS"]
@@ -691,11 +695,15 @@ def golden_entry(prog_name, lowering, obs, case):
 @pytest.mark.parametrize("case", sorted(GOLDEN_CASES))
 @pytest.mark.parametrize("obs", [0, 1], ids=["obs0", "obs1"])
 @pytest.mark.parametrize("lowering", ["xla", "pallas"])
-def test_base_advance_reproduces_the_vmapped_while(lowering, obs, case):
+def test_base_advance_reproduces_the_vmapped_while(
+    sm_lowerings_built, lowering, obs, case
+):
     golden = json.loads(_GOLDEN.read_text())
     for prog_name in GOLDEN_PROGS:
         want = golden[f"{prog_name}.obs{obs}.{case}"]
-        got = golden_entry(prog_name, lowering, obs, case)
+        got = golden_entry(
+            prog_name, lowering, obs, case, built=sm_lowerings_built
+        )
         assert got == want, prog_name
         if obs and "chunk" in case:
             # one ok / drops / retx / flipped ring per lane and chunk,

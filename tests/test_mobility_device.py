@@ -550,13 +550,19 @@ class TestLteMobile:
                 np.asarray(solo[k]), np.asarray(swept[k]), err_msg=k
             )
 
-    def test_pallas_and_xla_lowerings_agree_mobile(self, monkeypatch):
+    def test_pallas_and_xla_lowerings_agree_mobile(
+        self, monkeypatch, sm_lowerings_built
+    ):
         from tpudes.parallel.lte_sm import run_lte_sm
+        from tpudes.parallel.runtime import RUNTIME
 
         prog = _lte_mobile_prog(stride=2)
+        RUNTIME.clear("lte_sm")
+        monkeypatch.setenv("TPUDES_PALLAS", "1")
         a = run_lte_sm(prog, jax.random.PRNGKey(5), replicas=2)
         monkeypatch.setenv("TPUDES_PALLAS", "0")
         b = run_lte_sm(prog, jax.random.PRNGKey(5), replicas=2)
+        assert sm_lowerings_built(prog) == {True, False}
         np.testing.assert_array_equal(
             np.asarray(a["rx_bits"]), np.asarray(b["rx_bits"])
         )

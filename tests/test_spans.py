@@ -192,6 +192,39 @@ def test_run_lifted_leaves_one_launch_with_children_and_results(kind, block):
     assert runners[1].args["hit"] is True
 
 
+@pytest.mark.parametrize("side", ["at_N", "above_N", "mesh"])
+def test_lte_launch_span_names_the_step_lowering_and_lanes(monkeypatch, side):
+    """ISSUE 31: how often the lane-count rule engages is read off the
+    ``launch`` span: ``step_lowering`` is the rule's answer for this
+    launch's ``lanes`` (``r_pad x n_cfg``), on both sides of
+    ``SM_KERNEL_MAX_LANES`` and under ``run_lifted``'s own mesh."""
+    import warnings
+
+    from tpudes.parallel.lte_sm import SM_KERNEL_MAX_LANES as N
+
+    monkeypatch.delenv("TPUDES_PALLAS", raising=False)
+    # odd replica counts keep run_lifted off the 8 virtual devices
+    # (it warns that they do not divide); 8 takes its mesh
+    replicas, lanes, want = {
+        "at_N": (1, 1, "mosaic" if N >= 1 else "xla"),
+        "above_N": (N + 1 + N % 2, None, "xla"),
+        "mesh": (8, 8, "xla"),
+    }[side]
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore", RuntimeWarning)
+        run_lifted("lte_sm", _toy("lte_sm"), replicas, jax.random.PRNGKey(7))
+    (launch,) = [s for s in spans.snapshot() if s.name == "launch"]
+    assert launch.args["step_lowering"] == want
+    if lanes is None:
+        assert launch.args["lanes"] > N
+    else:
+        assert launch.args["lanes"] == lanes
+    # the other engines' launches carry neither
+    run_lifted("bss", _toy("bss"), 8, jax.random.PRNGKey(7))
+    bss = [s for s in spans.snapshot() if s.name == "launch"][-1]
+    assert "step_lowering" not in bss.args and "lanes" not in bss.args
+
+
 @pytest.mark.parametrize("kind", ["bss", "lte_sm", "dumbbell", "as_flows"])
 def test_warm_launches_reuse_the_init_program(kind):
     """ISSUE 29, and ISSUE 30 for every engine: the launch carry comes

@@ -683,10 +683,16 @@ class LteSmFuzzer(EngineFuzzer):
     def _pallas_pair(self, prog, cfg, canonical):
         # the two lowerings of the fused TTI chain are pinned
         # bit-identical per backend (tests/test_lte_pallas.py) — the
-        # fuzzer extends the pin to every in-envelope geometry
+        # fuzzer extends the pin to every in-envelope geometry.  Both
+        # sides are forced: unset, the engine picks the lowering by
+        # lane count (lte_sm._sm_use_pallas), so the canonical run of
+        # a batched draw is already the XLA step and a lone =0 run
+        # would compare XLA with XLA
+        with _env("TPUDES_PALLAS", "1"):
+            kernel = self.run_scalar(prog, cfg)
         with _env("TPUDES_PALLAS", "0"):
             xla = self.run_scalar(prog, cfg)
-        return first_diff(canonical, xla)
+        return first_diff(canonical, kernel) or first_diff(kernel, xla)
 
     def _bf16_pair(self, prog, cfg, canonical):
         import dataclasses

@@ -14,7 +14,7 @@ with three structural properties the tests pin:
 
 - **One math core, two lowerings.**  :func:`sm_step_math` is the only
   definition of the TTI math; the Pallas kernel body and the plain-XLA
-  fallback both execute it, so ``TPUDES_PALLAS=1`` and ``=0`` produce
+  lowering both execute it, so ``TPUDES_PALLAS=1`` and ``=0`` produce
   BIT-identical results on the same backend.  On non-TPU backends the
   ``pallas_call`` runs in interpret mode (discharged to ordinary XLA
   ops at trace time — zero runtime overhead), so the CPU tier-1 suite
@@ -37,11 +37,18 @@ with three structural properties the tests pin:
   the SINR chain, MI/BLER budget) and tests/test_lte_sm.py (host
   parity holds under bf16 at the same tolerances).
 
-``TPUDES_PALLAS=0`` is the kill switch: the engine takes the plain XLA
-lowering of the same math core (and the runtime cache keys the flag,
-so A/B flips never collide on a stale executable).  Mesh-sharded
-launches take the XLA lowering on every backend — GSPMD cannot
-partition a Mosaic call (``lte_sm._sm_use_pallas``);
+Which lowering a launch takes is the engine's choice
+(``lte_sm._sm_use_pallas``), made from what it sees: under a mesh the
+XLA lowering on every backend (GSPMD cannot partition a Mosaic call);
+unsharded, by the number of lanes the step is ``vmap``ped over — the
+batching rule turns them into a sequential grid of this kernel, one
+``(1, U)`` row a step, so on a v5e the kernel wins unbatched (7.2 us a
+7 x 210 TTI against 10.1) and loses from two lanes on (62.8 against
+6.2 at 64; PERF.md section 6, PR 31).  ``TPUDES_PALLAS``
+(:func:`pallas_switch`) overrides the lane rule: ``0`` is the kill
+switch (XLA everywhere), an explicit ``1`` the kernel on every
+unsharded launch (the A/B tests' kernel side).  The runtime cache keys
+the resolved lowering, so flips never collide on a stale executable;
 ``lte_sm.compiled_step_lowering`` reads which step an executable
 actually holds.
 """
@@ -97,14 +104,22 @@ _MT_MAX = SM_SCHED_IDS["fdmt"]
 NEG = -1e30  # the "no candidate" metric fill (finite in bf16 too)
 
 
+def pallas_switch() -> bool | None:
+    """``TPUDES_PALLAS`` as set: ``None`` when unset or empty (the
+    engine picks the lowering by lane count,
+    ``lte_sm._sm_use_pallas``), ``False`` for ``0`` / ``false`` /
+    ``no`` / ``off`` (XLA everywhere), ``True`` for anything else (the
+    kernel on every unsharded launch).  Read per call so tests can A/B
+    without re-importing — the same contract as ``TPUDES_BUCKETING``."""
+    raw = (os.environ.get("TPUDES_PALLAS") or "").strip().lower()
+    if not raw:
+        return None
+    return raw not in {"0", "false", "no", "off"}
+
+
 def pallas_enabled() -> bool:
-    """The fused Pallas TTI kernel is on unless ``TPUDES_PALLAS`` says
-    otherwise (read per call so tests can A/B without re-importing —
-    the same contract as ``TPUDES_BUCKETING``)."""
-    raw = os.environ.get("TPUDES_PALLAS")
-    if raw is None:
-        return True
-    return raw.strip().lower() not in {"0", "false", "no", "off"}
+    """The fused Pallas TTI kernel is not switched off."""
+    return pallas_switch() is not False
 
 
 def _compute_dtype(precision: str):
@@ -408,7 +423,8 @@ def build_sm_step_fn(consts: dict, use_pallas: bool, dynamic: tuple = ()):
     state, SMEM scalars), interpret-mode (= discharged to ordinary XLA
     ops at trace time) everywhere else so the CPU tier-1 suite runs the
     very same kernel body.  ``False`` is the plain XLA lowering of the
-    same core — the ``TPUDES_PALLAS=0`` kill-switch path.
+    same core — what every batched or sharded launch takes
+    (``lte_sm._sm_use_pallas``).
 
     ``dynamic`` names const entries that arrive PER CALL as the ``dyn``
     dict instead of closing over the build-time tables — the

@@ -19,10 +19,14 @@ precomputed once at lowering time.
 The per-TTI math itself lives in
 :mod:`tpudes.parallel.kernels_pallas`: one fused kernel chain (retx
 admission → scheduler dispatch → MI/BLER decode → HARQ update) with a
-hand-written Pallas lowering on TPU, an interpret-mode path everywhere
-else, a ``TPUDES_PALLAS=0`` plain-XLA kill switch, and an optional
-bf16/f32 mixed-precision mode (``LteSmProgram.precision``) — both
-flags are cache-key components, never traced operands.
+hand-written Pallas lowering on TPU (interpret mode everywhere else)
+and a plain-XLA lowering of the same math core.  A launch takes the
+kernel only unsharded and at no more than :data:`SM_KERNEL_MAX_LANES`
+lanes, the vectorised XLA step otherwise (:func:`_sm_use_pallas`;
+``TPUDES_PALLAS=0`` / ``=1`` force either on unsharded launches).  The
+optional bf16/f32 mixed-precision mode (``LteSmProgram.precision``)
+and the resolved lowering are cache-key components, never traced
+operands.
 
 All NINE FF-MAC schedulers (models/lte/scheduler.py) lower: each is a
 per-UE metric whose per-cell argmax drives the same one-hot allocation
@@ -63,12 +67,13 @@ import numpy as np
 
 from tpudes.fuzz.envelope import FuzzEnvelope
 from tpudes.models.lte.scheduler import SCHEDULERS
+from tpudes.obs import spans
 from tpudes.parallel.kernels_pallas import (
     SM_PRECISIONS,
     SM_SCHED_IDS,
     build_sm_consts,
     build_sm_step_fn,
-    pallas_enabled,
+    pallas_switch,
     sm_init_state,
 )
 from tpudes.parallel.runtime import scoped_while_loop
@@ -410,7 +415,7 @@ RNG_SCOPE = "tpudes.lte_sm.rng"
 LANE_SCOPE = "tpudes.lte_sm.lane"
 
 
-def build_sm_step(prog: LteSmProgram, use_pallas: bool | None = None):
+def build_sm_step(prog: LteSmProgram, use_pallas: bool):
     """Returns ``(consts, init_state, step_fn)`` for the per-TTI loop
     body (single replica; :func:`build_sm_advance` vmaps it over the
     lanes inside its unbatched ``while_loop``).
@@ -424,8 +429,6 @@ def build_sm_step(prog: LteSmProgram, use_pallas: bool | None = None):
     id (:data:`SM_SCHED_IDS`), so the compiled program is
     scheduler-agnostic: ``prog.scheduler`` only picks the value fed in.
     """
-    if use_pallas is None:
-        use_pallas = pallas_enabled()
     consts_np = build_sm_consts(prog)
     fused = build_sm_step_fn(consts_np, use_pallas)
     E, U = prog.n_enb, prog.n_ue
@@ -554,8 +557,8 @@ def _sm_cache_key(prog: LteSmProgram, replicas, n_cfg, obs, use_pallas) -> tuple
     # scheduler×horizon sweep pays one compile, not one per point.
     # Likewise prog.geom_stride and every mobility PARAMETER (only the
     # mobility shape key + the pathloss branch are trace-time).
-    # prog.precision and the pallas flag ARE present: they select
-    # different arithmetic, i.e. different executables — flipping
+    # prog.precision and the resolved lowering (_sm_use_pallas) ARE
+    # present: they select different executables — flipping
     # TPUDES_PALLAS mid-process must not hit a stale runner.
     return (
         prog.gain.tobytes(), prog.serving.tobytes(),
@@ -1004,16 +1007,36 @@ def build_sm_traffic_advance(prog: LteSmProgram, r_pad: int | None = None,
     return init_carry, advance
 
 
-def _sm_use_pallas(mesh) -> bool:
-    """Which TTI-step lowering a launch takes.  Mosaic kernels cannot
-    be partitioned by GSPMD ("Mosaic kernels cannot be automatically
+#: the largest lane count (``(r_pad or 1) * (n_cfg or 1)``: what
+#: :func:`_vmap_lanes` maps the step over) at which the Mosaic kernel
+#: is still no slower than the vectorised XLA step: only the unbatched
+#: launch.  Warm 7 x 210 launches of 10 000 TTIs on one TPU v5e, us a
+#: TTI, kernel / XLA (``tools/lte_lane_sweep.py``, PERF.md section 6,
+#: PR 31): 1 lane 7.20 / 10.11, 2 lanes 6.95 / 5.55, 4: 9.27 / 5.78,
+#: 8: 12.99 / 5.03, 16: 20.35 / 4.82, 32: 34.21 / 5.22, 64: 62.75 /
+#: 6.19, 256: 219.17 / 10.11 (the kernel adds 0.85 us a lane).
+SM_KERNEL_MAX_LANES = 1
+
+
+def _sm_use_pallas(mesh, lanes: int) -> bool:
+    """Which TTI-step lowering a launch takes: True the Mosaic kernel,
+    False the plain-XLA lowering of the same math core.
+
+    Under a mesh always XLA, on every backend: GSPMD cannot partition
+    a Mosaic call ("Mosaic kernels cannot be automatically
     partitioned. Please wrap the call in a shard_map" — the TPU
     compiler's words for the replica-sharded program), and interpret-
-    mode pallas runs the kernel interpreter per shard, so every
-    mesh-sharded launch takes the plain-XLA lowering of the same math
-    core on every backend; unsharded launches follow
-    ``TPUDES_PALLAS``."""
-    return pallas_enabled() and mesh is None
+    mode pallas runs the kernel interpreter per shard.  Unsharded,
+    ``TPUDES_PALLAS`` decides where it is set (``0``: XLA, anything
+    else: the kernel) and the lane count where it is not: ``vmap``
+    turns the lanes into a SEQUENTIAL grid of the kernel (one
+    ``(1, U)`` row a step), while the XLA step puts them on the vector
+    unit, so the kernel only wins up to
+    :data:`SM_KERNEL_MAX_LANES`."""
+    if mesh is not None:
+        return False
+    wish = pallas_switch()
+    return lanes <= SM_KERNEL_MAX_LANES if wish is None else wish
 
 
 class _SmVariant(NamedTuple):
@@ -1243,9 +1266,16 @@ def _sm_prepare(prog: LteSmProgram, key, replicas, mesh, schedulers):
     else:
         v = _sm_plain(prog) if prog.mobility is None else _sm_mobile(prog)
     n_cfg = None if schedulers is None else len(schedulers)
-    use_pallas = _sm_use_pallas(mesh)
     L = Launch("lte_sm", key, replicas, mesh, n_cfg)
     r_pad = L.r_pad
+    lanes = (r_pad or 1) * (n_cfg or 1)
+    use_pallas = _sm_use_pallas(mesh, lanes)
+    launch = spans.current()
+    if launch is not None and launch.name == "launch":
+        # the wish; :func:`compiled_step_lowering` is the read-back
+        launch.args.update(
+            step_lowering="mosaic" if use_pallas else "xla", lanes=lanes
+        )
 
     def build():
         init_carry, fn, aux = v.build(
@@ -1299,9 +1329,11 @@ def compiled_step_lowering(prog: LteSmProgram, key, replicas=None,
                            mesh=None, schedulers=None) -> str:
     """Which TTI step the executable behind
     ``run_lte_sm(prog, key, replicas, mesh, schedulers=...)`` actually
-    holds — read from the COMPILED program, not from ``TPUDES_PALLAS``
-    (the wish): ``"mosaic"`` when its HLO carries a ``tpu_custom_call``
-    (the Pallas kernel, compiled by Mosaic), ``"xla"`` otherwise.
+    holds — read from the COMPILED program, not from the rule or
+    ``TPUDES_PALLAS`` (the wish, on the ``launch`` span as
+    ``step_lowering``): ``"mosaic"`` when its HLO carries a
+    ``tpu_custom_call`` (the Pallas kernel, compiled by Mosaic),
+    ``"xla"`` otherwise.
     Lowers the cached runner with a run's own operands, so after a run
     this is a compile-cache hit, not a second compile."""
     L, _, _ = _sm_prepare(prog, key, replicas, mesh, schedulers)
