@@ -443,3 +443,39 @@ def test_pallas_lowering_carries_the_kernel_name():
     # wrapped kernel name reads `vmap_tpudes_lte_sm_tti_` on the device
     assert f"vmap({lte_sm.LANE_SCOPE})/{SM_KERNEL_NAME}" in text
     assert f"vmap({lte_sm.LANE_SCOPE})/{lte_sm.RNG_SCOPE}" in text
+
+
+# --- the aggregated BSS exchange (ISSUE 32) -----------------------------------
+
+def _toy_ht_bss():
+    import dataclasses
+
+    return dataclasses.replace(
+        toy_bss_program(), max_mpdus=8, subframe_bytes=580
+    )
+
+
+def test_aggregated_bss_launch_span_names_max_mpdus():
+    """A ``bss`` launch whose exchanges are A-MPDUs says so on its
+    ``launch`` span (how much of a window the mechanism did is then a
+    count of spans); the legacy launch carries no such argument."""
+    run_lifted("bss", _toy_ht_bss(), 8, jax.random.PRNGKey(7))
+    (launch,) = [s for s in spans.snapshot() if s.name == "launch"]
+    assert launch.args["max_mpdus"] == 8
+    run_lifted("bss", _toy("bss"), 8, jax.random.PRNGKey(7))
+    legacy = [s for s in spans.snapshot() if s.name == "launch"][-1]
+    assert "max_mpdus" not in legacy.args
+
+
+@pytest.mark.parametrize("aggregated", [True, False], ids=["ht", "legacy"])
+def test_ampdu_scope_names_the_aggregated_step_only(aggregated):
+    """``tpudes.bss.ampdu`` is in the lowered program's debug info where
+    the A-MPDU arm is compiled in, and nowhere in the legacy program."""
+    from tpudes.parallel.replicated import _trace_entries
+
+    prog = _toy_ht_bss() if aggregated else toy_bss_program()
+    text = "".join(
+        _lowered(e) for e in _trace_entries(prog, scale=False) if e.kernel
+    )
+    assert "tpudes.bss.step" in text
+    assert ("tpudes.bss.ampdu" in text) is aggregated

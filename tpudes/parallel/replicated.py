@@ -26,8 +26,11 @@ echo traffic, beacons.  HT (802.11n) graphs lift too: QoS AC_BE AIFS,
 HT-mixed preamble timing, and A-MPDU aggregation under an established
 BlockAck session (every data exchange becomes backlog-sized A-MPDU +
 compressed BA, per-MPDU decode at the subframe bit share — the
-phy._end_rx_ampdu model vectorized).  The ADDBA handshake, like
-association/ARP, is warm-up and not modeled.  ``lower_bss`` builds the program's static inputs
+phy._end_rx_ampdu model vectorized — and a retry count per MPDU, as
+mac._finish_ampdu keeps it: a backlog rides the carry as its histogram
+over the count; ``tx_mpdus`` counts what the PPDUs carried).  The
+ADDBA handshake, like association/ARP, is warm-up and not modeled.
+``lower_bss`` builds the program's static inputs
 from the *live object graph* a scenario script constructed (helpers,
 attributes, station manager), so ``wifi-bss.py --replicas=R`` runs the
 same config the sequential engine runs.  The scalar DES remains the
@@ -90,6 +93,8 @@ DIFS = 34
 CW_MIN = 15
 CW_MAX = 1023
 RETRY_LIMIT = 7
+#: an MPDU's retry count runs 0..RETRY_LIMIT; one failure more drops it
+RETRY_CLASSES = RETRY_LIMIT + 1
 INF = np.int32(2**30)
 
 #: the association + ARP (and, under aggregation, ADDBA) warm-up the
@@ -161,7 +166,8 @@ class BssProgram:
     aifs_us: int = DIFS
     #: A-MPDU cap: >1 turns every data exchange into an aggregated
     #: PPDU + compressed BlockAck under an (assumed-established) BA
-    #: session; 1 = legacy single-MPDU DATA/ACK
+    #: session, with retries counted per MPDU; 1 = legacy single-MPDU
+    #: DATA/ACK (one retry count per node: it has one frame in flight)
     max_mpdus: int = 1
     #: on-air bytes of one A-MPDU subframe (delimiter + MPDU + FCS,
     #: padded to 4) — used instead of data_bytes when max_mpdus > 1
@@ -686,6 +692,15 @@ def build_bss_step(
                 geom_rx_w=jnp.zeros((R, n, n), jnp.float32),
                 geom_det=jnp.zeros((R, n, n), bool),
             )
+        if AGG:
+            # per-MPDU retry counts: a backlog is its histogram over the
+            # count (the queue is oldest first, so the count never rises
+            # along it and the histogram says all there is to say)
+            extra.update(
+                q_retry=jnp.zeros((R, n, RETRY_CLASSES), jnp.int32),
+                ap_retry=jnp.zeros((R, n, RETRY_CLASSES), jnp.int32),
+                tx_mpdus=jnp.zeros((R,), jnp.int32),
+            )
         return dict(
             **extra,
             t=jnp.zeros((R,), jnp.int32),
@@ -697,7 +712,8 @@ def build_bss_step(
             hold=jnp.zeros((R, n), jnp.int32),       # personal recontend time
             immediate=jnp.zeros((R, n), bool),       # zero-backoff grant armed
             cw=jnp.full((R, n), CW_MIN, jnp.int32),
-            retries=jnp.zeros((R, n), jnp.int32),
+            # the legacy exchange retries its one frame: a count per node
+            **({} if AGG else {"retries": jnp.zeros((R, n), jnp.int32)}),
             busy_until=jnp.zeros((R,), jnp.int32),
             srv_rx=jnp.zeros((R,), jnp.int32),
             cli_rx=jnp.zeros((R, n), jnp.int32),
@@ -751,9 +767,11 @@ def build_bss_step(
                 # draw dtypes pinned f32 (ambient x64 must not widen
                 # the streams — JXL002)
                 k_back, k_mpdu = jax.random.split(kk)
+                # two rows of per-MPDU coins: the AP's PPDU and the one
+                # PPDU to the AP that can decode (see the A-MPDU block)
                 return (
                     jax.random.uniform(k_back, (n,), jnp.float32),
-                    jax.random.uniform(k_mpdu, (n, K), jnp.float32),
+                    jax.random.uniform(k_mpdu, (2, K), jnp.float32),
                 )
 
             u_back, u_mpdu = jax.vmap(draw)(rkeys)
@@ -929,37 +947,113 @@ def build_bss_step(
         data_tx = winners & ~beacon_tx
         gate = data_tx & det & dst_idle
         if AGG:
-            # A-MPDU: the winner aggregates its whole backlog (up to the
-            # BA-window/MaxAmpduSize cap) into one PPDU; per-MPDU decode
-            # is the full-PPDU PSR at each subframe's bit share
-            # (phy.mpdu_success_probs — equal shares → psr^(1/k))
-            k_sta = jnp.minimum(s["queue"], K)
-            k_ap = jnp.minimum(
-                jnp.sum(
-                    jnp.where(ed_1h, s["ap_pend"], 0), axis=1,
-                    dtype=jnp.int32,
-                ),
-                K,
-            )[:, None]
-            k_agg = jnp.maximum(
-                jnp.where(is_ap[None, :], k_ap, k_sta), 1
-            ).astype(jnp.int32)
-            nsym = jnp.ceil(
-                (22.0 + 8.0 * prog.subframe_bytes * k_agg) / ndbps
-            )
-            dur_k = preamble_data + (nsym * 4).astype(jnp.int32)
-            nbits_k = (
-                jnp.float32(data_mode.data_rate_bps * 1e-6)
-                * dur_k.astype(jnp.float32)
-            )
-            psr = mode_chunk_success_rate(
-                sinr, nbits_k, jnp.asarray(prog.data_mode_idx)
-            )
-            p_mpdu = psr ** (1.0 / k_agg.astype(jnp.float32))
-            mpdu_ok = (u_mpdu < p_mpdu[..., None]) & (
-                jnp.arange(K)[None, None, :] < k_agg[..., None]
-            )
-            n_ok = jnp.where(gate, mpdu_ok.sum(-1, dtype=jnp.int32), 0)
+            with jax.named_scope("tpudes.bss.ampdu"):
+                # A-MPDU: the winner aggregates its whole backlog (up to
+                # the BA-window/MaxAmpduSize cap) into one PPDU; per-MPDU
+                # decode is the full-PPDU PSR at each subframe's bit
+                # share (phy.mpdu_success_probs: equal shares, psr^(1/k))
+                k_sta = jnp.minimum(s["queue"], K)
+                k_ap = jnp.minimum(
+                    jnp.sum(
+                        jnp.where(ed_1h, s["ap_pend"], 0), axis=1,
+                        dtype=jnp.int32,
+                    ),
+                    K,
+                )[:, None]
+                k_agg = jnp.maximum(
+                    jnp.where(is_ap[None, :], k_ap, k_sta), 1
+                ).astype(jnp.int32)
+                nsym = jnp.ceil(
+                    (22.0 + 8.0 * prog.subframe_bytes * k_agg) / ndbps
+                )
+                dur_k = preamble_data + (nsym * 4).astype(jnp.int32)
+                nbits_k = (
+                    jnp.float32(data_mode.data_rate_bps * 1e-6)
+                    * dur_k.astype(jnp.float32)
+                )
+                psr = mode_chunk_success_rate(
+                    sinr, nbits_k, jnp.asarray(prog.data_mode_idx)
+                )
+                p_mpdu = jnp.where(
+                    gate, psr ** (1.0 / k_agg.astype(jnp.float32)), 0.0
+                )
+                # the backlog a winner sends from, by retry count: its own
+                # queue, or the AP's echoes for echo_dst; oldest first is
+                # highest count first, so positions [0, upto[c]) of the
+                # PPDU hold the MPDUs that failed c times or more
+                hist = jnp.where(
+                    is_ap[None, :, None],
+                    jnp.sum(
+                        jnp.where(ed_1h[..., None], s["ap_retry"], 0),
+                        axis=1, dtype=jnp.int32,
+                    )[:, None, :],
+                    s["q_retry"],
+                )                                        # (R, N, C)
+                at_least = jax.lax.cumsum(hist, axis=2, reverse=True)
+                upto = jnp.where(
+                    data_tx[..., None],
+                    jnp.minimum(at_least, k_agg[..., None]), 0,
+                )
+                # the per-MPDU coins are tossed for two PPDUs a replica,
+                # not for all N: the AP's, and the likeliest of those sent
+                # to the AP.  No second one can decode there: two senders
+                # cannot both sit above 0 dB at one receiver (the product
+                # of their SINRs is below 1), and at or below 0 dB psr is
+                # 0.0 exactly in float32 for every mode at these lengths.
+                lead = jnp.argmax(
+                    jnp.where(is_ap[None, :], -1.0, p_mpdu), axis=1
+                )
+                tossed = jnp.stack(
+                    [
+                        jnp.broadcast_to(is_ap[None, :], (R, n)),
+                        jnp.arange(n)[None, :] == lead[:, None],
+                    ],
+                    axis=1,
+                ) & data_tx[:, None, :]                  # (R, 2, N)
+                p2 = jnp.sum(
+                    jnp.where(tossed, p_mpdu[:, None, :], 0.0), axis=2
+                )
+                upto2 = jnp.sum(
+                    jnp.where(tossed[..., None], upto[:, None], 0),
+                    axis=2, dtype=jnp.int32,
+                )                                        # (R, 2, C)
+                pos = jnp.arange(K)
+                lost_j = (pos < upto2[..., :1]) & ~(
+                    u_mpdu < p2[..., None]
+                )                                        # (R, 2, K)
+                lost_upto2 = jnp.sum(
+                    lost_j[:, :, None, :]
+                    & (pos < upto2[..., None]),
+                    axis=-1, dtype=jnp.int32,
+                )                                        # (R, 2, C)
+                # every other PPDU of the step loses all it carried
+                lost_upto = jnp.where(
+                    tossed.any(axis=1)[..., None],
+                    jnp.sum(
+                        jnp.where(
+                            tossed[..., None], lost_upto2[:, :, None], 0
+                        ),
+                        axis=1, dtype=jnp.int32,
+                    ),
+                    upto,
+                )                                        # (R, N, C)
+
+                def per_count(cum):
+                    # from "count c or more" to "count c"
+                    return cum - jnp.pad(
+                        cum[..., 1:], ((0, 0), (0, 0), (0, 1))
+                    )
+
+                sent, lost = per_count(upto), per_count(lost_upto)
+                n_ok = jnp.sum(sent - lost, axis=-1, dtype=jnp.int32)
+                # what the BlockAck did not acknowledge stays at the head
+                # with its own count one higher; past the limit it drops
+                # (block-ack-manager NotifyGotBlockAck / MissedBlockAck,
+                # as the host MAC's _finish_ampdu)
+                drop_n = lost[..., -1]
+                hist_after = hist - sent + jnp.pad(
+                    lost[..., :-1], ((0, 0), (0, 0), (1, 0))
+                )
         else:
             k_agg = jnp.ones((R, n), jnp.int32)
             dur_k = jnp.full((R, n), data_dur, jnp.int32)
@@ -982,13 +1076,17 @@ def build_bss_step(
         new_ap_pend = s["ap_pend"] + sta_ok - ed_i * got_echo[:, None]
         new_bcn = new_bcn - jnp.where(ap_sends_beacon, 1, 0)
 
-        # node-level retry counter: bumps on a zero-success exchange,
-        # resets on any success; at the limit the whole head A-MPDU
-        # drops (host: per-MPDU counts — coincides in the all-fail runs
-        # that actually reach the limit; partial-success histories drop
-        # slightly later here — documented deviation)
-        retry_exceeded = fail & (s["retries"] + 1 > RETRY_LIMIT)
-        drop_n = jnp.where(retry_exceeded, k_agg, 0)
+        if AGG:
+            # retries are counted per MPDU (drop_n: the A-MPDU block);
+            # the exchange is final, and the CW resets, when a PPDU that
+            # decoded nothing leaves nothing of itself to send again
+            retry_exceeded = fail & (drop_n >= k_agg)
+        else:
+            # the legacy exchange has one frame: a node-level counter
+            # bumps on a failed exchange, resets on a success; at the
+            # limit the frame drops
+            retry_exceeded = fail & (s["retries"] + 1 > RETRY_LIMIT)
+            drop_n = jnp.where(retry_exceeded, k_agg, 0)
         new_drops = s["drops"] + jnp.sum(
             drop_n, axis=1, dtype=jnp.int32
         )
@@ -997,11 +1095,12 @@ def build_bss_step(
             jnp.where(is_ap[None, :], drop_n, 0), axis=1, dtype=jnp.int32
         )
         new_ap_pend = new_ap_pend - ed_i * drop_echo[:, None]
-        new_retries = jnp.where(
-            success | retry_exceeded | beacon_tx,
-            0,
-            s["retries"] + fail.astype(jnp.int32),
-        )
+        if not AGG:
+            new_retries = jnp.where(
+                success | retry_exceeded | beacon_tx,
+                0,
+                s["retries"] + fail.astype(jnp.int32),
+            )
         new_cw = jnp.where(
             success | retry_exceeded | beacon_tx,
             CW_MIN,
@@ -1083,6 +1182,27 @@ def build_bss_step(
             extra.update(fm)
         if MOBILE:
             extra.update(geom_rx_w=rx_w_c, geom_det=det_c)
+        if AGG:
+            with jax.named_scope("tpudes.bss.ampdu"):
+                fresh = jnp.arange(RETRY_CLASSES) == 0   # count 0
+                extra.update(
+                    # a station's queue: what it sent comes back one count
+                    # higher or not at all; arrivals join at count 0
+                    q_retry=jnp.where(
+                        is_ap[None, :, None], 0, hist_after
+                    ) + (is_arr & ~is_ap[None, :])[..., None] * fresh,
+                    # the AP's echoes: echo_dst's row takes the AP's
+                    # outcome, every request decoded adds a fresh echo
+                    ap_retry=s["ap_retry"]
+                    + ed_i[..., None] * (hist_after - hist)[:, :1]
+                    + sta_ok[..., None] * fresh,
+                    tx_mpdus=s["tx_mpdus"] + jnp.sum(
+                        jnp.where(data_tx, k_agg, 0), axis=1,
+                        dtype=jnp.int32,
+                    ),
+                )
+        else:
+            extra.update(retries=new_retries)
         return dict(
             **extra,
             t=jnp.maximum(next_t, s["t"]),
@@ -1094,7 +1214,6 @@ def build_bss_step(
             hold=new_hold,
             immediate=new_immediate,
             cw=new_cw,
-            retries=new_retries,
             busy_until=new_busy,
             srv_rx=new_srv,
             cli_rx=new_cli,
@@ -1217,6 +1336,9 @@ def _bss_unpack(host: dict, replicas: int, obs: bool, prog=None) -> dict:
         steps=int(host["step"]),
         all_done=not bool(host["pending"][:R].any()),
     )
+    if "tx_mpdus" in host:
+        # aggregated exchanges only: MPDUs carried by the tx_data PPDUs
+        result["tx_mpdus"] = host["tx_mpdus"][:R]
     if obs:
         from tpudes.obs.flowmon import FM_KEYS
 
@@ -1316,6 +1438,7 @@ def run_replicated_bss(
       ``cli_rx``   (R,N)  echo replies decoded per STA (col 0 unused)
       ``tx_data``  (R,)   data-frame transmission attempts
       ``drops``    (R,)   frames dropped at retry limit
+      ``tx_mpdus`` (R,)   MPDUs those PPDUs carried (``max_mpdus > 1`` only)
       ``steps``    int    vector event-loop iterations executed
       ``all_done`` bool   every replica reached sim_end (sanity flag)
 
@@ -1387,6 +1510,12 @@ def run_replicated_bss(
     # extra loop iterations the padding may cause cannot corrupt real
     # replicas
     L = Launch("bss", key, replicas, mesh, n_cfg)
+    if prog.max_mpdus > 1:
+        from tpudes.obs import spans
+
+        launch = spans.current()
+        if launch is not None and launch.name == "launch":
+            launch.args["max_mpdus"] = int(prog.max_mpdus)
 
     def build():
         init_state, _, fn = build_bss_advance(
@@ -1449,6 +1578,8 @@ def run_replicated_bss(
         # trips
         out, still_pending = carry
         names = ("srv_rx", "cli_rx", "tx_data", "drops", "step")
+        if prog.max_mpdus > 1:
+            names += ("tx_mpdus",)
         if L.obs:
             from tpudes.obs.flowmon import FM_KEYS
 
