@@ -181,12 +181,28 @@ def test_reference_agrees_with_the_host_des_at_four_stations(interval_s):
 # --- the legacy program is the program it was --------------------------------
 
 #: ``jax.make_jaxpr`` of the legacy (max_mpdus == 1) init and advance at
-#: the trace manifest's toy size, counted at HEAD before this PR (where
-#: the two strings were equal to this tree's; a stored string is brittle
-#: across jax versions, the counts are not): carry leaves, equations of
-#: init, of the advance, of the event step (the loop's body) and of its
-#: condition
-LEGACY_SHAPE = dict(leaves=16, init=15, advance=30, body=382, cond=32)
+#: the trace manifest's toy size (a stored string is brittle across jax
+#: versions, the counts are not): carry leaves, equations of init, of the
+#: advance, of the loop's body and of its condition.  Counted at HEAD
+#: before PR 32 as advance=30, body=382, cond=32; since PR 33 the 30
+#: equations of the next-event search (``pending`` and its ``any``) are
+#: the body's last instead of the condition's first, the advance runs
+#: them once before the loop to seed the carried flag where it ran the
+#: 29 of ``pending`` after it, and the condition is a compare and an
+#: ``and``.  The event step itself is still the 382 it was.
+LEGACY_SHAPE = dict(leaves=16, init=15, advance=31, body=412, cond=2)
+
+
+#: the launch carry's leaves: what ``runtime.jit_init`` builds,
+#: ``drive_chunks`` donates, a checkpoint fingerprints, ``_bss_unpack`` reads
+LEGACY_LEAVES = [
+    "ap_pend", "backoff", "bcn_pend", "busy_until", "cli_rx", "cw",
+    "drops", "hold", "immediate", "next_arr", "queue", "retries",
+    "srv_rx", "step", "t", "tx_data",
+]
+HT_LEAVES = sorted(
+    set(LEGACY_LEAVES) - {"retries"} | {"q_retry", "ap_retry", "tx_mpdus"}
+)
 
 
 def test_legacy_program_keeps_its_carry_and_its_equations():
@@ -200,11 +216,7 @@ def test_legacy_program_keeps_its_carry_and_its_equations():
         s0, jax.random.PRNGKey(0), jnp.int32(64),
         jnp.int32(prog.sim_end_us), None, None,
     )
-    assert sorted(s0) == [
-        "ap_pend", "backoff", "bcn_pend", "busy_until", "cli_rx", "cw",
-        "drops", "hold", "immediate", "next_arr", "queue", "retries",
-        "srv_rx", "step", "t", "tx_data",
-    ]
+    assert sorted(s0) == LEGACY_LEAVES
     (loop,) = [e for e in advance.jaxpr.eqns if e.primitive.name == "while"]
     assert dict(
         leaves=len(jax.tree_util.tree_leaves(s0)),
@@ -213,6 +225,51 @@ def test_legacy_program_keeps_its_carry_and_its_equations():
         body=len(loop.params["body_jaxpr"].jaxpr.eqns),
         cond=len(loop.params["cond_jaxpr"].jaxpr.eqns),
     ) == LEGACY_SHAPE
+
+
+@pytest.mark.parametrize(
+    "over, leaves",
+    [({}, LEGACY_LEAVES), (dict(max_mpdus=8, subframe_bytes=580), HT_LEAVES)],
+    ids=["legacy", "ht"],
+)
+def test_loop_condition_reads_a_carried_scalar(over, leaves):
+    """The event step owns the loop's predicate: it searches the state
+    it produced for the next event, and the condition compares the step
+    budget and reads the carried flag.  No reduction, no search, in the
+    condition; and what rides beside the state never leaves the advance:
+    the launch carry is the leaves it was."""
+    from tpudes.analysis.jaxpr.trace import primitive_names, walk_eqns
+    from tpudes.parallel.replicated import _trace_prog
+
+    prog = _trace_prog(**over)
+    init, _, fn = build_bss_advance(prog, 4)
+    s0 = init()
+    assert sorted(s0) == leaves
+    advance, (state, still_pending, metrics) = jax.make_jaxpr(
+        fn, return_shape=True
+    )(
+        s0, jax.random.PRNGKey(0), jnp.int32(64),
+        jnp.int32(prog.sim_end_us), None, None,
+    )
+    (loop,) = [e for e in advance.jaxpr.eqns if e.primitive.name == "while"]
+    cond = [
+        e.primitive.name
+        for e in walk_eqns(loop.params["cond_jaxpr"].jaxpr)
+    ]
+    assert len(cond) <= 4, cond
+    assert not [
+        p for p in cond if p.startswith(("reduce_", "arg", "cum", "dot"))
+    ], cond
+    # the search is in the body, for the state the step has produced
+    assert {"reduce_or", "reduce_min"} <= primitive_names(
+        loop.params["body_jaxpr"]
+    )
+    # out: the state with its own leaves, the pending vector, the metrics
+    assert metrics == {}
+    assert still_pending.shape == (4,) and still_pending.dtype == jnp.bool_
+    assert {k: (v.shape, v.dtype) for k, v in state.items()} == {
+        k: (v.shape, v.dtype) for k, v in s0.items()
+    }
 
 
 def test_legacy_result_has_no_tx_mpdus_and_the_ht_one_does():

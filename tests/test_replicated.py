@@ -234,3 +234,150 @@ class TestShortHorizonGuard:
             warnings.simplefilter("error")
             prog = self._lower_at(1.6)
         assert prog.sim_end_us == 1_600_000
+
+
+# --------------------------------------------------------------------------
+# the loop's predicate rides its carry (build_bss_advance): bit-identity
+# against the loop whose condition searches the state again
+# --------------------------------------------------------------------------
+
+
+def _recomputing_advance(prog, r, obs=False, n_cfg=None, sweep="horizon"):
+    """The BSS loop in the shape it had before its predicate rode the
+    carry, assembled from ``build_bss_step``'s exported pieces: the
+    condition runs ``pending`` on the state, the body is ``step_fn``
+    alone."""
+    import jax.numpy as jnp
+
+    from tpudes.parallel.replicated import build_bss_step
+
+    init, pending, step_fn = build_bss_step(prog, r, obs=obs)
+
+    def advance(s, k, max_steps, sim_end, geom=None, tr=None):
+        tr_keys = (
+            step_fn.traffic_keys(k)
+            if step_fn.traffic_keys is not None else None
+        )
+        out = jax.lax.while_loop(
+            lambda st: jnp.logical_and(
+                st["step"] < max_steps, jnp.any(pending(st, sim_end))
+            ),
+            lambda st: step_fn(st, k, sim_end, geom, tr, tr_keys),
+            s,
+        )
+        return out, pending(out, sim_end)
+
+    if n_cfg is not None:
+        advance = jax.vmap(
+            advance,
+            in_axes=(
+                (0, None, None, 0, None, None) if sweep == "horizon"
+                else (0, None, None, None, None, 0)
+            ),
+        )
+    return init, advance
+
+
+def _carry_case(case):
+    """``(prog, kwargs of build_bss_advance, n_cfg, sim_end, geom, tr,
+    step budgets of the successive calls)`` of one program variant."""
+    import dataclasses
+
+    from tpudes.parallel.programs import toy_bss_program, toy_traffic_points
+    from tpudes.traffic import TrafficProgram
+
+    prog = toy_bss_program(n_sta=3, sim_end_us=120_000)
+    kw, n_cfg, geom, tr, budgets = {}, None, None, None, (10_000,)
+    sim_end = np.int32(prog.sim_end_us)
+    if case == "ampdu":
+        # every 2 ms a request: queues build and winners aggregate
+        prog = dataclasses.replace(
+            prog, max_mpdus=8, subframe_bytes=580,
+            interval_us=np.where(
+                np.arange(prog.n) == 0, prog.interval_us, 2_000
+            ).astype(np.int32),
+        )
+    elif case == "mobile":
+        from tpudes.ops.mobility import MobilityProgram
+
+        prog = dataclasses.replace(
+            prog,
+            mobility=MobilityProgram.constant_velocity(
+                np.asarray(prog.positions, np.float64),
+                np.tile([3.0, -2.0, 0.0], (prog.n, 1)),
+            ),
+            geom_stride=4,
+        )
+        geom = dict(stride=np.int32(4), **prog.mobility.operands())
+    elif case == "traffic":
+        prog = dataclasses.replace(
+            prog,
+            traffic=TrafficProgram.mmpp(
+                prog.n, 90.0, horizon_us=prog.sim_end_us, epoch_s=0.05,
+                start_us=prog.start_us, tr_seed=1,
+            ),
+        )
+        tr = prog.traffic.operands()
+    elif case == "obs":
+        kw = dict(obs=True)
+    elif case == "horizon_sweep":
+        n_cfg = 3
+        kw = dict(n_cfg=n_cfg, sweep="horizon")
+        sim_end = np.asarray([40_000, 120_000, 75_000], np.int32)
+    elif case == "traffic_sweep":
+        from tpudes.traffic.device import stack_traffic_operands
+
+        pts = toy_traffic_points(
+            prog.n, prog.sim_end_us, start_us=prog.start_us,
+            beacon=(int(prog.interval_us[0]), int(prog.start_us[0])),
+        )[:4]
+        prog = dataclasses.replace(prog, traffic=pts[0])
+        n_cfg = len(pts)
+        kw = dict(n_cfg=n_cfg, sweep="traffic")
+        tr = stack_traffic_operands(pts)
+    elif case == "chunked":
+        # three launches of the loop: the carry re-enters twice with
+        # events still pending, then runs out
+        budgets = (7, 19, 10_000)
+    else:
+        assert case == "legacy"
+    return prog, kw, n_cfg, sim_end, geom, tr, budgets
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["legacy", "ampdu", "mobile", "traffic", "obs", "horizon_sweep",
+     "traffic_sweep", "chunked"],
+)
+def test_carried_predicate_is_bit_identical_to_the_recomputing_loop(case):
+    from tpudes.parallel.replicated import build_bss_advance
+    from tpudes.parallel.runtime import stack_axis
+
+    R = 4
+    prog, kw, n_cfg, sim_end, geom, tr, budgets = _carry_case(case)
+    init, _, fn = build_bss_advance(prog, R, **kw)
+    ref_init, ref_fn = _recomputing_advance(prog, R, **kw)
+    key = jax.random.PRNGKey(9)
+    new, ref = jax.jit(fn), jax.jit(ref_fn)
+    s_new = stack_axis(init(), n_cfg)
+    s_ref = stack_axis(ref_init(), n_cfg)
+    assert sorted(s_new) == sorted(s_ref)
+    for budget in budgets:
+        s_new, p_new, _ = new(s_new, key, np.int32(budget), sim_end, geom, tr)
+        s_ref, p_ref = ref(s_ref, key, np.int32(budget), sim_end, geom, tr)
+        assert sorted(s_new) == sorted(s_ref)
+        for name in s_ref:
+            np.testing.assert_array_equal(
+                np.asarray(s_new[name]), np.asarray(s_ref[name]),
+                err_msg=f"{case}: {name} after {budget}",
+            )
+        np.testing.assert_array_equal(np.asarray(p_new), np.asarray(p_ref))
+    # the case ran to its horizon and did some work on the way
+    assert not np.asarray(p_ref).any()
+    assert int(np.sum(np.asarray(s_ref["tx_data"]))) > 0
+    if case == "chunked":
+        assert int(s_ref["step"]) > 19
+    if case == "ampdu":
+        assert int(np.sum(np.asarray(s_ref["tx_mpdus"]))) > int(
+            np.sum(np.asarray(s_ref["tx_data"]))
+        )

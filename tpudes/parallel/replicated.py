@@ -572,6 +572,14 @@ def build_bss_step(
     one compiled program serves every horizon and the config-axis
     sweep vmaps a batch of horizons alongside the replica axis.
 
+    ``pending`` is the next-event search as a predicate: per replica,
+    whether an arrival or a transmission still falls before its
+    horizon.  It is exported because the loop needs it and does not
+    own it: :func:`build_bss_advance` runs it on the state each step
+    HAS PRODUCED, inside the loop's body, and a loop assembled from
+    these pieces with ``pending`` in its condition is the same
+    simulation, one search a step slower.
+
     With ``prog.mobility`` the step gains a geometry stage: ``geom``
     (the mobility operands + the traced ``stride``) drives a
     closed-form position read at each replica's own event time and the
@@ -1263,7 +1271,30 @@ def build_bss_advance(prog: "BssProgram", replicas: int, obs: bool = False,
     config-vmapped) advance exactly as :func:`run_replicated_bss`'s
     launch jits it — factored out so the trace manifest
     (:func:`trace_manifest`) abstractly traces the same program the
-    runner cache compiles.  With ``n_cfg``, ``sweep`` picks the
+    runner cache compiles.
+
+    **Who owns the loop's predicate: the body.**  The ``while`` inside
+    ``fn`` carries ``(s, nxt)``.  ``s`` is the launch carry, leaf for
+    leaf what ``init_state`` builds; ``nxt`` is what the next-event
+    search found for that very ``s``: ``pending`` (R,) bool, the
+    replicas with an event still before their horizon, and ``more``,
+    a scalar, whether any has.  The body steps ``s`` and searches the
+    state it has just produced, so the search runs once an event step;
+    the condition is ``(step < max_steps) & more``: two scalars, no
+    reduction, no read of the state.  XLA compiles condition and body
+    as two computations that share nothing, so a condition that
+    searches repeats the body's opening lines every step, and (read on
+    the chip, ``PERF.md`` section 6, PR 33) a condition that reads the whole
+    state keeps the carry out of the chip's fast memory.  ``nxt`` is
+    seeded from the incoming ``s`` before the loop (once a launch or a
+    chunk) and never leaves ``fn``: ``fn`` returns the stepped ``s``,
+    ``nxt["pending"]`` and the metrics; what ``runtime.jit_init``
+    builds, ``drive_chunks`` donates and re-enters, a checkpoint
+    fingerprints and ``_bss_unpack`` reads is ``s`` alone.  Under the
+    config-axis ``vmap`` ``more`` is one flag a config point; on a
+    replica mesh its ``any`` is the step's one all-reduce.
+
+    With ``n_cfg``, ``sweep`` picks the
     config-axis operand: ``"horizon"`` vmaps (state, sim_end) — the
     classic horizon sweep — while ``"traffic"`` vmaps (state, traffic
     operands): an 8-point WORKLOAD sweep (mixed cbr/mmpp/onoff/trace
@@ -1280,17 +1311,20 @@ def build_bss_advance(prog: "BssProgram", replicas: int, obs: bool = False,
             if step_fn.traffic_keys is not None else None
         )
 
-        def cond(s):
-            return jnp.logical_and(
-                s["step"] < max_steps, jnp.any(pending(s, sim_end))
-            )
+        def search(st):
+            # ``nxt`` of the docstring, for the state ``st``
+            still = pending(st, sim_end)
+            return dict(more=jnp.any(still), pending=still)
 
-        out = scoped_while_loop(
-            "bss",
-            cond,
-            lambda st: step_fn(st, k, sim_end, geom, tr, tr_keys),
-            s,
-        )
+        def cond(c):
+            st, nxt = c
+            return jnp.logical_and(st["step"] < max_steps, nxt["more"])
+
+        def body(c):
+            new = step_fn(c[0], k, sim_end, geom, tr, tr_keys)
+            return new, search(new)
+
+        out, nxt = scoped_while_loop("bss", cond, body, (s, search(s)))
         # per-replica completion flags computed on-device so the
         # caller needs no second compiled program (no extra host
         # round trip); a vector so padded replicas can be sliced off
@@ -1311,7 +1345,7 @@ def build_bss_advance(prog: "BssProgram", replicas: int, obs: bool = False,
             if obs
             else {}
         )
-        return out, pending(out, sim_end), metrics
+        return out, nxt["pending"], metrics
 
     fn = advance
     if n_cfg is not None:
