@@ -19,9 +19,12 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import numbers
 import time
 
 import numpy as np
+
+from benchmark import stock
 
 #: keys made before the window (a launch takes one; the window never makes any)
 MAX_LAUNCHES = 4096
@@ -43,15 +46,17 @@ def _keys(seed: int):
 
 def _at_horizon(cfg: dict, prog, horizon_s: float):
     """The lifted program with its horizon field set (a traced operand of the
-    engine's loop: the executable is the same at every horizon)."""
+    engine's loop: the executable is the same at every horizon).  A field the
+    program holds as a whole number (TTIs, microseconds, slots) gets one; a
+    float of seconds stays a float."""
     field = cfg["horizon_field"]
-    return dataclasses.replace(
-        prog, **{field["name"]: int(round(horizon_s * field["per_second"]))}
-    )
+    value = horizon_s * field["per_second"]
+    if isinstance(getattr(prog, field["name"]), numbers.Integral):
+        value = int(round(value))
+    return dataclasses.replace(prog, **{field["name"]: value})
 
 
 def setup(cell) -> dict:
-    from benchmark import stock
     from tpudes.parallel.lift import run_lifted
 
     cfg, mix = cell.cfg, cell.traffic
@@ -70,7 +75,7 @@ def setup(cell) -> dict:
             f"lifted {res['kind']!r} x {res['replicas']}, the configuration "
             f"says {cfg['kind']!r} x {replicas}"
         )
-    failed = stock.criterion(cfg["kind"], res["out"])
+    failed = cell.reference.criterion(res["out"])
     if failed:
         raise RuntimeError(f"{cfg['script']}: exit criterion failed: {failed}")
     cell.split["script_first_run_s"] = time.monotonic() - t0
@@ -103,14 +108,6 @@ def _launch(state, cell, prog, index: int, spans=None) -> dict:
         return fut.result()
 
 
-def _iterations(cfg: dict, outs: list, horizon_s: float) -> float:
-    """Iterations of the engine's loop behind these launches."""
-    how = cfg["step_iterations"]
-    if "from_result" in how:
-        return float(sum(int(o[how["from_result"]]) for o in outs))
-    return float(len(outs) * horizon_s * how["per_sim_second"])
-
-
 def window(state, cell, seconds: float, spans=None, profile=None) -> dict:
     """The measured window; `spans` and `profile` only in a traced run."""
     mix = cell.traffic
@@ -125,7 +122,7 @@ def window(state, cell, seconds: float, spans=None, profile=None) -> dict:
                 for i in range(int(mix["trace_launches"]))
             ]
         index = len(traced)
-        record["trace_iterations"] = _iterations(cell.cfg, traced, t_h)
+        record["trace_iterations"] = stock.iterations(cell.cfg, traced, t_h)
         spans.set_aside("traced_")
     outs = []
     t0 = time.monotonic()
@@ -149,12 +146,12 @@ def attempted(record) -> int:
     return len(record["outs"])
 
 
-def check(state, cell, record, reference) -> dict:
+def check(state, cell, record) -> dict:
     """Every launch of the window against the plain reference, and the first
     one run again with its own key: the same seed gives the same replicas."""
     mix = cell.traffic
     outs = record["outs"]
-    numbers = reference.compare(
+    numbers = cell.reference.compare(
         cell.cfg, mix, outs, len(outs) * int(mix["replicas"]), cell.seed
     )
     again = _launch(state, cell, state["prog"], record["first_index"])
@@ -171,7 +168,7 @@ def reseed(state, cell, seed: int) -> None:
     state["keys"] = _keys(seed)
 
 
-def control(state, cell, seconds: float, reference) -> dict:
+def control(state, cell, seconds: float) -> dict:
     """The configuration's control, compared as a run would be: either the
     program with its own lower-precision path switched on, or the reference at
     the lower precision put in the program's place, at the cell's own size."""
@@ -181,8 +178,8 @@ def control(state, cell, seconds: float, reference) -> dict:
             state["prog"], **how["replace"]
         ))
         _launch(lowered, cell, lowered["prog"], MAX_LAUNCHES - 1)  # compiles
-        return check(lowered, cell, window(lowered, cell, seconds), reference)
-    mix = cell.traffic
+        return check(lowered, cell, window(lowered, cell, seconds))
+    mix, reference = cell.traffic, cell.reference
     stand_in = reference.simulate(
         cell.cfg, float(mix["horizon_s"]), int(mix["replicas"]),
         cell.seed + 1, **how["kwargs"]
@@ -192,5 +189,6 @@ def control(state, cell, seconds: float, reference) -> dict:
     )
 
 
-def counters(state, cell, record, reference) -> dict:
-    return {"kpi_mean": float(np.mean([reference.kpi(o) for o in record["outs"]]))}
+def counters(state, cell, record) -> dict:
+    kpi = cell.reference.kpi
+    return {"kpi_mean": float(np.mean([kpi(o) for o in record["outs"]]))}

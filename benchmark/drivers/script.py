@@ -23,30 +23,26 @@ from unittest import mock
 
 import numpy as np
 
+from benchmark import stock
+
 
 def _argv(cell) -> list[str]:
-    from benchmark import stock
-
     cfg, mix = cell.cfg, cell.traffic
     args = dict(cfg["args"], **{cfg["horizon_arg"]: mix["horizon_s"]})
     return stock.script_argv(args, int(mix["replicas"]))
 
 
 def _study(state, cell):
-    from benchmark import stock
-
     rc, res, wall = stock.run_main(state["main"], state["argv"])
     ok = (
         rc == 0 and res is not None and res["kind"] == cell.cfg["kind"]
         and res["replicas"] == int(cell.traffic["replicas"])
-        and stock.criterion(res["kind"], res["out"]) is None
+        and cell.reference.criterion(res["out"]) is None
     )
     return ok, res, wall
 
 
 def setup(cell) -> dict:
-    from benchmark import stock
-
     t0 = time.monotonic()
     state = dict(
         main=stock.load_example(cell.root, cell.cfg["script"]).main,
@@ -117,9 +113,10 @@ def window(state, cell, seconds: float, spans=None, profile=None) -> dict:
             traced = studies[:]
             del studies[:]
             record["elapsed_s"] = loop()
-            record["trace_iterations"] = float(sum(
-                int(out["steps"]) for _, out in traced if out
-            ))
+            record["trace_iterations"] = stock.iterations(
+                cell.cfg, [out for _, out in traced if out],
+                float(cell.traffic["horizon_s"]),
+            )
     record.update(studies=studies, failed=failed)
     return record
 
@@ -132,11 +129,11 @@ def attempted(record) -> int:
     return len(record["studies"])
 
 
-def check(state, cell, record, reference) -> dict:
+def check(state, cell, record) -> dict:
     """Every study's replicas against the plain reference; every study has the
     same arguments, hence the same key, hence bit-identical results."""
     outs = [out for _, out in record["studies"] if out is not None]
-    numbers = reference.compare(
+    numbers = cell.reference.compare(
         cell.cfg, cell.traffic, outs,
         len(record["studies"]) * int(cell.traffic["replicas"]), cell.seed,
     )
@@ -152,8 +149,8 @@ def reseed(state, cell, seed: int) -> None:
     cell.seed = seed
 
 
-def control(state, cell, seconds: float, reference) -> dict:
-    mix, how = cell.traffic, cell.cfg["control"]
+def control(state, cell, seconds: float) -> dict:
+    mix, how, reference = cell.traffic, cell.cfg["control"], cell.reference
     if how["how"] != "reference":
         raise ValueError("the script driver runs the reference's control only")
     stand_in = reference.simulate(
@@ -165,10 +162,10 @@ def control(state, cell, seconds: float, reference) -> dict:
     )
 
 
-def counters(state, cell, record, reference) -> dict:
+def counters(state, cell, record) -> dict:
     outs = [out for _, out in record["studies"] if out is not None]
     walls = sorted(w for w, _ in record["studies"])
     return {
-        "kpi_mean": float(np.mean([reference.kpi(o) for o in outs])),
+        "kpi_mean": float(np.mean([cell.reference.kpi(o) for o in outs])),
         "study_walls_s": walls,
     }

@@ -5,7 +5,8 @@ them is one file under `benchmark/` that this module resolves from the name alon
 so a later PR adds a file and a manifest entry and edits nothing that exists:
 
     configs/<config>.json        the deployment as it is run (+ its `reference`)
-    references/<reference>.py    the plain reference and the comparison
+    references/<reference>.py    the plain reference, the comparison and the
+                                 stock script's exit criterion (`REFERENCE_API`)
     traffic/<traffic>.json       the mix's parameters (+ its `driver`)
     drivers/<driver>.py          the one general generator for such mixes
     layers/<metric>.py           one small reader per per-layer metric
@@ -19,6 +20,12 @@ import json
 import os
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+
+#: what a reference module has to give: `criterion(out)` (None where the stock
+#: script's own exit criterion holds on a lifted result, else what failed),
+#: `compare(cfg, traffic, outs, expected_rows, seed)`, `simulate(cfg, horizon_s,
+#: replicas, seed, **control)` and `kpi(out)`
+REFERENCE_API = ("criterion", "compare", "simulate", "kpi")
 
 
 class ManifestError(ValueError):
@@ -82,9 +89,12 @@ class Manifest:
         return load_module(os.path.join(self.bench_dir, "drivers", name + ".py"))
 
     def reference(self, name: str):
-        return load_module(
-            os.path.join(self.bench_dir, "references", name + ".py")
-        )
+        path = os.path.join(self.bench_dir, "references", name + ".py")
+        mod = load_module(path)
+        lacks = [fn for fn in REFERENCE_API if not callable(getattr(mod, fn, None))]
+        if lacks:
+            raise ManifestError(f"{path} lacks {', '.join(lacks)}")
+        return mod
 
     def layer_reader(self, metric: str):
         return load_module(

@@ -36,7 +36,6 @@ def main(argv=None) -> int:
     run.require_chips(int(entry["chips"]))
     cell = run.make_cell(manifest, args.workload, args.first_seed, ROOT)
     driver = manifest.driver(cell.traffic["driver"])
-    reference = manifest.reference(cell.cfg["reference"])
     state = driver.setup(cell)
     for i in range(args.seeds + args.control_seeds):
         seed = args.first_seed + 7919 * i
@@ -44,11 +43,11 @@ def main(argv=None) -> int:
         t0 = time.monotonic()
         if i < args.seeds:
             record = driver.window(state, cell, args.seconds)
-            numbers, what = driver.check(state, cell, record, reference), "sound"
+            numbers, what = driver.check(state, cell, record), "sound"
             extra = dict(driver.end_to_end(state, cell, record),
                          attempted=driver.attempted(record))
         else:
-            numbers, what = driver.control(state, cell, args.seconds, reference), "control"
+            numbers, what = driver.control(state, cell, args.seconds), "control"
             extra = {}
         print(json.dumps(dict(reading=what, seed=seed, numbers=numbers,
                               seconds=time.monotonic() - t0, **extra)),

@@ -267,6 +267,14 @@ def reference_replicas(traffic: dict) -> int:
     return int(traffic.get("reference_replicas", 32))
 
 
+def criterion(out: dict) -> str | None:
+    """`wifi-bss.py`'s own exit criterion restated on the lifted result: None
+    where it holds, else what failed."""
+    if not (out["all_done"] and np.asarray(out["srv_rx"]).mean() > 0):
+        return "all_done and srv_rx.mean() > 0"
+    return None
+
+
 def kpi(out: dict) -> float:
     """Mean echo requests decoded at the server per replica."""
     return float(np.asarray(out["srv_rx"], float).mean())

@@ -12,7 +12,7 @@ program made: positions, physics and every MAC constant come from the configurat
 file; the NIST error model's constants are the public NIST/ns-3 ones.  The sibling
 `bss.py` is loaded by path for what the two deployments share (rounding for the
 controls, the legacy OFDM airtime of BlockAck and beacon, Boltzmann's constant, the
-64-QAM divisor, `kpi`); nothing there is edited.
+64-QAM divisor, `criterion`, `kpi`).
 
 Departures from upstream ns-3, each also a comment where it happens:
   D1 the BlockAck agreement is taken as established (no ADDBA exchange), association
@@ -296,6 +296,7 @@ def simulate(cfg: dict, horizon_s: float, replicas: int, seed: int,
 
 
 reference_replicas = legacy.reference_replicas
+criterion = legacy.criterion        # the same script, the same exit criterion
 kpi = legacy.kpi
 
 

@@ -176,6 +176,14 @@ def reference_replicas(traffic: dict) -> int:
     return int(traffic.get("reference_replicas", 8))
 
 
+def criterion(out: dict) -> str | None:
+    """`lena-simple.py`'s own exit criterion restated on the lifted result: None
+    where it holds, else what failed."""
+    if not np.asarray(out["rx_bits"]).sum() > 0:
+        return "aggregate DL Mbps > 0"
+    return None
+
+
 def kpi(out: dict) -> float:
     """Mean delivered megabits per replica (the simulated statistic a
     speed-only change must not move)."""

@@ -32,23 +32,27 @@ sys.path.insert(0, ROOT)
 
 @dataclasses.dataclass
 class Cell:
-    """What a driver gets: the cell's files, the seed, and the set-up split."""
+    """What a driver gets: the cell's files, the configuration's reference
+    module (loaded once: set-up asks it for the script's exit criterion, the
+    check for the comparison), the seed, and the set-up split."""
 
     root: str
     name: str
     chips: int
     cfg: dict
     traffic: dict
+    reference: object
     seed: int
     split: dict
 
 
 def make_cell(manifest, workload: str, seed: int, program_root: str) -> Cell:
     entry = manifest.cell(workload)
+    cfg = manifest.config(entry["config"])
     return Cell(
-        root=program_root, name=workload, chips=int(entry["chips"]),
-        cfg=manifest.config(entry["config"]),
-        traffic=manifest.traffic(entry["traffic"]), seed=seed,
+        root=program_root, name=workload, chips=int(entry["chips"]), cfg=cfg,
+        traffic=manifest.traffic(entry["traffic"]),
+        reference=manifest.reference(cfg["reference"]), seed=seed,
         split={"import_and_chip_init_s": time.monotonic() - PROCESS_START},
     )
 
@@ -126,7 +130,6 @@ def run_cell(manifest, workload: str, seed: int, seconds: float, traced: bool,
 
     cell = make_cell(manifest, workload, seed, program_root)
     driver = manifest.driver(cell.traffic["driver"])
-    reference = manifest.reference(cell.cfg["reference"])
     limits = manifest.limits(workload)
     with open(os.path.join(manifest.bench_dir, "peaks.json")) as f:
         peaks = json.load(f)["peaks"]
@@ -153,7 +156,7 @@ def run_cell(manifest, workload: str, seed: int, seconds: float, traced: bool,
     device = device_block(devices)
 
     t_check = time.monotonic()
-    numbers = driver.check(state, cell, record, reference)
+    numbers = driver.check(state, cell, record)
     print(json.dumps({"check_s": time.monotonic() - t_check}), flush=True)
     compared, correct = judge(numbers, limits)
 
@@ -165,7 +168,7 @@ def run_cell(manifest, workload: str, seed: int, seconds: float, traced: bool,
             spans=spans.durations, starts=spans.starts, trace=reduced,
             record=record,
             device=device, compiles_in_window=compiles,
-            counters=driver.counters(state, cell, record, reference),
+            counters=driver.counters(state, cell, record),
         )
         for m in manifest.metrics_of("per_layer", workload):
             value = manifest.layer_reader(m["name"])(context)

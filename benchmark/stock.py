@@ -1,7 +1,10 @@
 """The stock-script path: `python examples/<script>` in-process, with the one
-GlobalValue flip.  Copied from `chip_smoke.py` (`run_stock_script`,
-`_script_criterion`) so that a later change to that file cannot move the
-benchmark; the original is listed in PERF.md for a later PR to fold in.
+GlobalValue flip, and what a configuration says of the program it lifts to.
+`run_main` is copied from `chip_smoke.py` (`run_stock_script`) so that a later
+change to that file cannot move the benchmark; the original is listed in PERF.md
+for a later PR to fold in.  Nothing here knows an engine: a deployment's exit
+criterion is `criterion(out)` of its reference module, its loop and horizon are
+described by its configuration file.
 """
 
 from __future__ import annotations
@@ -58,17 +61,14 @@ def run_main(main, argv: list[str]):
     return rc, (kept[-1] if kept else None), wall
 
 
-def criterion(kind: str, out: dict) -> str | None:
-    """The script's own exit criterion restated on the result: None where it
-    holds, else what failed."""
-    import numpy as np
-
-    if kind == "bss":
-        if not (out["all_done"] and np.asarray(out["srv_rx"]).mean() > 0):
-            return "all_done and srv_rx.mean() > 0"
-    elif kind == "lte_sm":
-        if not np.asarray(out["rx_bits"]).sum() > 0:
-            return "aggregate DL Mbps > 0"
-    else:
-        return f"no exit criterion recorded for kind {kind!r}"
-    return None
+def iterations(cfg: dict, outs: list, horizon_s: float) -> float:
+    """Iterations of the lifted program's outermost loop behind these results, as
+    the configuration's `step_iterations` says to count them: a field of each
+    result (`from_result`), so many a simulated second (`per_sim_second`), or so
+    many a launch whatever the horizon (`per_launch`)."""
+    how = cfg["step_iterations"]
+    if "from_result" in how:
+        return float(sum(int(o[how["from_result"]]) for o in outs))
+    if "per_launch" in how:
+        return float(len(outs) * how["per_launch"])
+    return float(len(outs) * horizon_s * how["per_sim_second"])

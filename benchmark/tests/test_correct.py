@@ -86,9 +86,9 @@ def lte(toy_root):
     cfg = m.config("lena-hex7x30")
     mix = {"driver": "mc", "replicas": 16, "horizon_s": 1.5, "warm_launches": 0,
            "reference_replicas": 8}
-    cell = run.Cell(root=ROOT, name="t", chips=1, cfg=cfg, traffic=mix, seed=5,
-                    split={})
     driver, ref = m.driver("mc"), m.reference("lte_sm")
+    cell = run.Cell(root=ROOT, name="t", chips=1, cfg=cfg, traffic=mix,
+                    reference=ref, seed=5, split={})
     state = driver.setup(cell)
     record = driver.window(state, cell, 0.0)        # one launch
     return cfg, mix, cell, driver, ref, state, record
@@ -102,10 +102,10 @@ TEST_SIZE_SLACK = 3.0
 def test_lte_program_agrees_and_its_bf16_path_fails(lte):
     cfg, mix, cell, driver, ref, state, record = lte
     limits = {k: v * TEST_SIZE_SLACK for k, v in _limits("lte.mc").items()}
-    sound = driver.check(state, cell, record, ref)
+    sound = driver.check(state, cell, record)
     assert _correct(sound, limits), sound
     lowered = dict(state, prog=dataclasses.replace(state["prog"], precision="bf16"))
-    bad = driver.check(lowered, cell, driver.window(lowered, cell, 0.0), ref)
+    bad = driver.check(lowered, cell, driver.window(lowered, cell, 0.0))
     assert not _correct(bad, limits), bad
     assert bad["ue_rate_gap"] > 3 * sound["ue_rate_gap"]
 

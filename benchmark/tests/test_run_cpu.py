@@ -13,7 +13,7 @@ from benchmark import run
 from benchmark.manifest import Manifest
 
 CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
-DEVICE_ONLY = {"step_us", "collective_share", "device_idle_share",
+DEVICE_ONLY = {"step_us", "device_idle_share",
                "peak_hbm_bytes", "launch_device_ms", "run_host_ms",
                "script_device_idle_share"}
 
@@ -40,19 +40,21 @@ def test_driver_runs_end_to_end_and_prints_the_contracts_keys(toy_root, name, me
         assert result["correct"] is True
 
 
-@pytest.mark.parametrize("name", ["toy.bss", "toy.script"])
+@pytest.mark.parametrize("name", ["toy.bss", "toy.script", "toy.tcp", "toy.as"])
 def test_traced_run_reports_no_device_metric_without_a_device(toy_root, name):
     result = cell(toy_root, name, traced=True)
     assert not DEVICE_ONLY & set(result["metrics"])
     assert "busy_s" not in result["device"] and "breakdown" not in result
     assert list(result)[-1] == "compared" and result["correct"] is True
-    if name == "toy.bss":     # the dummy config, traffic mix and reader were found
-        assert result["metrics"]["toy_launches"]["value"] == result["attempted"]
-        assert {"dispatch_ms", "fetch_unpack_ms", "kpi_mean",
-                "compiles_in_window"} <= set(result["metrics"])
-        assert result["metrics"]["compiles_in_window"]["value"] == 0
-    else:
+    if name == "toy.script":
         assert {"graph_build_ms", "lower_ms", "study_p95_ms"} <= set(result["metrics"])
+        return
+    # the dummy config, reference, traffic mix and reader were found by name
+    assert result["metrics"]["toy_launches"]["value"] == result["attempted"]
+    assert {"dispatch_ms", "fetch_unpack_ms", "kpi_mean",
+            "compiles_in_window"} <= set(result["metrics"])
+    assert result["metrics"]["compiles_in_window"]["value"] == 0
+    assert result["metrics"]["kpi_mean"]["value"] > 0
 
 
 def test_main_refuses_without_the_chip(capsys):
