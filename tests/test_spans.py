@@ -479,3 +479,53 @@ def test_ampdu_scope_names_the_aggregated_step_only(aggregated):
     )
     assert "tpudes.bss.step" in text
     assert ("tpudes.bss.ampdu" in text) is aggregated
+
+
+# --- the TCP dumbbell's slot (ISSUE 35) ---------------------------------------
+
+@pytest.mark.parametrize("scope", [
+    "tpudes.dumbbell.rng", "tpudes.dumbbell.cc", "tpudes.dumbbell.queue",
+])
+def test_dumbbell_slot_names_its_three_parts(scope):
+    """The per-replica draws, the seventeen window rules and the
+    bottleneck queue are scopes of their own inside
+    ``tpudes.dumbbell.step``, in the lowered program's debug info."""
+    from tpudes.parallel import tcp_dumbbell
+
+    (base,) = [
+        v for v in tcp_dumbbell.trace_manifest().variants()
+        if v.name == "base"
+    ]
+    text = "".join(_lowered(e) for e in base.build() if e.kernel)
+    assert scope in (tcp_dumbbell.RNG_SCOPE, tcp_dumbbell.CC_SCOPE,
+                     tcp_dumbbell.QUEUE_SCOPE)
+    assert f"tpudes.dumbbell.step/{scope}" in text
+
+
+def test_dumbbell_launch_span_names_flows_and_variants():
+    """What the masked-dense rules were run for is read off the
+    ``launch`` span: the flow count and the sorted names of the variants
+    present, a sweep's points pooled; a BSS or LTE launch has neither."""
+    import dataclasses
+
+    import numpy as np
+
+    toy, key = _toy("dumbbell"), jax.random.PRNGKey(7)
+    prog = dataclasses.replace(toy, variant_idx=np.asarray([1, 1], np.int32))
+    run_lifted("dumbbell", prog, 4, key)
+    (launch,) = [s for s in spans.snapshot() if s.name == "launch"]
+    assert launch.args["n_flows"] == 2
+    assert launch.args["variants"] == ["TcpCubic"]
+    run_lifted("dumbbell", toy, 4, key)
+    toy_launch = [s for s in spans.snapshot() if s.name == "launch"][-1]
+    assert toy_launch.args["variants"] == ["TcpCubic", "TcpNewReno"]
+    run_lifted("dumbbell", prog, 4, key,
+               variants=[["TcpVegas", "TcpCubic"], ["TcpCubic", "TcpBbr"]])
+    swept = [s for s in spans.snapshot() if s.name == "launch"][-1]
+    assert swept.args["n_flows"] == 2
+    assert swept.args["variants"] == ["TcpBbr", "TcpCubic", "TcpVegas"]
+    for kind in ("bss", "lte_sm"):
+        run_lifted(kind, _toy(kind), 4, key)
+        other = [s for s in spans.snapshot() if s.name == "launch"][-1]
+        assert other.args["kind"] == kind
+        assert "n_flows" not in other.args and "variants" not in other.args

@@ -728,6 +728,16 @@ def _loss_response(var, cwnd, st, t_s):
 #: queue-occupancy histogram bins for the on-device obs accumulators
 OBS_QHIST_BINS = 16
 
+#: ``jax.named_scope`` names inside one slot, in ``tf_op`` of the
+#: operations they cover (names only: the arithmetic is what it was):
+#: the per-replica ``fold_in`` and the slot's draws; the window rules
+#: (``_cwnd_increase`` + ``_loss_response``, all seventeen variants,
+#: and the selects that apply them); departure and admission at the
+#: bottleneck queue
+RNG_SCOPE = "tpudes.dumbbell.rng"
+CC_SCOPE = "tpudes.dumbbell.cc"
+QUEUE_SCOPE = "tpudes.dumbbell.queue"
+
 
 def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False):
     """Return (init_state, step_fn) for the slot-stepped scan.
@@ -840,26 +850,29 @@ def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False)
         # function of (key, t, r) — independent of R — so runtime
         # replica-bucketing (padding R to a power of two) leaves every
         # real replica's stream bit-identical
-        rkeys = jax.vmap(lambda i: jax.random.fold_in(key, i))(jnp.arange(R))
-        if RED:
+        with jax.named_scope(RNG_SCOPE):
+            rkeys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
+                jnp.arange(R)
+            )
+            if RED:
 
-            def draw(kk):
-                # fixed-arity split of a fold_in-derived key: pure in
-                # (key, t, r), so bucketing/chunking stay bit-exact;
-                # draw dtypes pinned f32 (ambient x64 must not widen
-                # the streams — JXL002)
-                k_dep, k_red, k_mark = jax.random.split(kk, 3)
-                return (
-                    jax.random.uniform(k_dep, (), jnp.float32),
-                    jax.random.uniform(k_red, (F,), jnp.float32),
-                    jax.random.uniform(k_mark, (), jnp.float32),
-                )
+                def draw(kk):
+                    # fixed-arity split of a fold_in-derived key: pure in
+                    # (key, t, r), so bucketing/chunking stay bit-exact;
+                    # draw dtypes pinned f32 (ambient x64 must not widen
+                    # the streams — JXL002)
+                    k_dep, k_red, k_mark = jax.random.split(kk, 3)
+                    return (
+                        jax.random.uniform(k_dep, (), jnp.float32),
+                        jax.random.uniform(k_red, (F,), jnp.float32),
+                        jax.random.uniform(k_mark, (), jnp.float32),
+                    )
 
-            u_dep, u_red, u_mark = jax.vmap(draw)(rkeys)
-        else:
-            u_dep = jax.vmap(
-                lambda kk: jax.random.uniform(kk, (), jnp.float32)
-            )(rkeys)
+                u_dep, u_red, u_mark = jax.vmap(draw)(rkeys)
+            else:
+                u_dep = jax.vmap(
+                    lambda kk: jax.random.uniform(kk, (), jnp.float32)
+                )(rkeys)
 
         # 1. consume this slot's ack / loss / ECN-echo arrivals
         acks = s["ack_buf"][:, idx, :]
@@ -887,170 +900,177 @@ def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False)
         d_acked = jnp.where(win_done, 0.0, d_acked)
         d_marked = jnp.where(win_done, 0.0, d_marked)
 
-        in_recovery = t < s["recover_until"]
-        cwnd, ssthresh, side = _cwnd_increase(
-            var[None, :], s["cwnd"], s["ssthresh"],
-            jnp.where(in_recovery, 0, acks), t * slot_s, rtt, side,
-            acked_raw=acks,
-        )
-        # 2. one reduction per recovery window on loss or ECN echo
-        # (RFC 3168: an ECE ack triggers the variant's loss response;
-        # DCTCP's response is the alpha-scaled cut via ss_dctcp)
-        reduce = ((losses > 0) | ((marks > 0) & ecn_cap[None, :])) & ~in_recovery
-        ss_loss, side_loss = _loss_response(
-            var[None, :], cwnd, side, t * slot_s
-        )
-        ssthresh = jnp.where(reduce, ss_loss, ssthresh)
-        cwnd = jnp.where(reduce, ssthresh, cwnd)
-        side = {
-            k: jnp.where(reduce, side_loss[k], side[k]) for k in side
-        }
-        recover_until = jnp.where(
-            reduce, t + rtt_slots, s["recover_until"]
-        )
+        with jax.named_scope(CC_SCOPE):
+            in_recovery = t < s["recover_until"]
+            cwnd, ssthresh, side = _cwnd_increase(
+                var[None, :], s["cwnd"], s["ssthresh"],
+                jnp.where(in_recovery, 0, acks), t * slot_s, rtt, side,
+                acked_raw=acks,
+            )
+            # 2. one reduction per recovery window on loss or ECN echo
+            # (RFC 3168: an ECE ack triggers the variant's loss response;
+            # DCTCP's response is the alpha-scaled cut via ss_dctcp)
+            reduce = (
+                (losses > 0) | ((marks > 0) & ecn_cap[None, :])
+            ) & ~in_recovery
+            ss_loss, side_loss = _loss_response(
+                var[None, :], cwnd, side, t * slot_s
+            )
+            ssthresh = jnp.where(reduce, ss_loss, ssthresh)
+            cwnd = jnp.where(reduce, ssthresh, cwnd)
+            side = {
+                k: jnp.where(reduce, side_loss[k], side[k]) for k in side
+            }
+            recover_until = jnp.where(
+                reduce, t + rtt_slots, s["recover_until"]
+            )
 
-        # 3. departure: serve one packet, flow ∝ queue occupancy
-        q = s["q"]
-        # int reductions pin dtype=jnp.int32: an unpinned .sum()
-        # widens to i64 under ambient x64 (JXL002); bit-exact
-        # no-op under the default config
-        qtot = q.sum(axis=1, dtype=jnp.int32)
-        backlogged = qtot > 0
-        cum = jnp.cumsum(q, axis=1, dtype=jnp.int32)
-        thresh = (u_dep * qtot.astype(jnp.float32)).astype(jnp.int32)
-        dep = jnp.argmax(cum > thresh[:, None], axis=1)  # (R,)
-        dep_oh = jax.nn.one_hot(dep, F, dtype=jnp.int32) * backlogged[
-            :, None
-        ].astype(jnp.int32)
-        # the departing packet carries a CE mark with probability equal
-        # to the flow's marked share — INTEGER marks only (a fractional
-        # residue would keep the `marks > 0` loss response firing for
-        # hundreds of RTTs after a marking episode)
-        if RED:
-            dep_marked = dep_oh.astype(jnp.float32) * (
-                u_mark[:, None]
-                < s["q_marked"] / jnp.maximum(q, 1).astype(jnp.float32)
-            ).astype(jnp.float32)
-        else:
-            dep_marked = jnp.zeros((R, F), jnp.float32)
-        q_marked = jnp.maximum(s["q_marked"] - dep_marked, 0.0)
-        q = q - dep_oh
-        delivered = s["delivered"] + dep_oh
-        aidx = (t + prog.ack_lag) % L
-        ack_buf = ack_buf.at[:, aidx, :].add(dep_oh)
-        mark_buf = mark_buf.at[:, aidx, :].add(dep_marked)
-        rtt_buf = s["rtt_buf"].at[:, aidx].set(
-            prog.base_rtt_s + qtot.astype(jnp.float32) * slot_s
-        )
-
-        # 4. window-driven arrivals; AQM (RED mark/early-drop) then
-        # tail-drop past capacity
-        want = jnp.clip(
-            cwnd.astype(jnp.int32) - inflight, 0, burst
-        )
-        live = (t >= start[None, :]) & (t < stop[None, :]) & (
-            delivered + inflight < max_pkts[None, :]
-        )
-        want = jnp.where(live, want, 0)
-        if TRAFFIC:
-            # app-limited sending: the workload's cumulative offered
-            # segments (closed-form, shared across replicas — the
-            # realization IS the workload, like the mobility
-            # trajectory) caps what may ever have left the
-            # application — an EXACT clip, not a gate, so the send
-            # burst cannot overshoot the offered count.  Arrivals
-            # inside a slot are sendable in that slot (the slot-end
-            # evaluation — sub-slot timing is below this model's
-            # resolution either way)
-            app_cum = jnp.floor(
-                tr_cum(tr, (t + 1) * jnp.int32(slot_us))
-            ).astype(jnp.int32)                          # (F,)
-            want = jnp.minimum(
-                want,
-                jnp.maximum(
-                    app_cum[None, :] - delivered - inflight, 0
-                ),
-            )
-        red_avg = s["red_avg"]
-        red_marks = jnp.zeros((R, F), jnp.float32)
-        red_drops = jnp.zeros((R, F), jnp.int32)
-        if RED:
-            # EWMA over this slot's arrivals against the instantaneous
-            # queue (per-arrival updates folded into one (1-qw)^n step;
-            # idle-time decay not modeled — the bottleneck is backlogged
-            # in every regime this engine targets)
-            qnow = q.sum(axis=1, dtype=jnp.int32).astype(jnp.float32)
-            n_arr = want.sum(axis=1, dtype=jnp.int32)
-            red_avg = jnp.where(
-                n_arr > 0,
-                qnow
-                + (red_avg - qnow)
-                * jnp.float32(1.0 - prog.red_qw) ** n_arr,
-                red_avg,
-            )
-            p = jnp.where(
-                red_avg < prog.red_min_th,
-                0.0,
-                prog.red_max_p
-                * (red_avg - prog.red_min_th)
-                / max(prog.red_max_th - prog.red_min_th, 1e-9),
-            )
-            if prog.red_gentle:
-                p = jnp.where(
-                    red_avg >= prog.red_max_th,
-                    prog.red_max_p
-                    + (1.0 - prog.red_max_p)
-                    * (red_avg - prog.red_max_th) / prog.red_max_th,
-                    p,
-                )
-                forced = red_avg >= 2.0 * prog.red_max_th
+        with jax.named_scope(QUEUE_SCOPE):
+            # 3. departure: serve one packet, flow ∝ queue occupancy
+            q = s["q"]
+            # int reductions pin dtype=jnp.int32: an unpinned .sum()
+            # widens to i64 under ambient x64 (JXL002); bit-exact
+            # no-op under the default config
+            qtot = q.sum(axis=1, dtype=jnp.int32)
+            backlogged = qtot > 0
+            cum = jnp.cumsum(q, axis=1, dtype=jnp.int32)
+            thresh = (u_dep * qtot.astype(jnp.float32)).astype(jnp.int32)
+            dep = jnp.argmax(cum > thresh[:, None], axis=1)  # (R,)
+            dep_oh = jax.nn.one_hot(dep, F, dtype=jnp.int32) * backlogged[
+                :, None
+            ].astype(jnp.int32)
+            # the departing packet carries a CE mark with probability equal
+            # to the flow's marked share — INTEGER marks only (a fractional
+            # residue would keep the `marks > 0` loss response firing for
+            # hundreds of RTTs after a marking episode)
+            if RED:
+                dep_marked = dep_oh.astype(jnp.float32) * (
+                    u_mark[:, None]
+                    < s["q_marked"] / jnp.maximum(q, 1).astype(jnp.float32)
+                ).astype(jnp.float32)
             else:
-                forced = red_avg >= prog.red_max_th
-            p = jnp.clip(jnp.where(forced, 1.0, p), 0.0, 1.0)
-            # ECT packets are marked unless the forced region hard-drops
-            ect = ecn_cap[None, :] & prog.red_use_ecn
-            n_act = jnp.minimum(
-                want,
-                jnp.floor(
-                    want.astype(jnp.float32) * p[:, None] + u_red
-                ).astype(jnp.int32),
+                dep_marked = jnp.zeros((R, F), jnp.float32)
+            q_marked = jnp.maximum(s["q_marked"] - dep_marked, 0.0)
+            q = q - dep_oh
+            delivered = s["delivered"] + dep_oh
+            aidx = (t + prog.ack_lag) % L
+            ack_buf = ack_buf.at[:, aidx, :].add(dep_oh)
+            mark_buf = mark_buf.at[:, aidx, :].add(dep_marked)
+            rtt_buf = s["rtt_buf"].at[:, aidx].set(
+                prog.base_rtt_s + qtot.astype(jnp.float32) * slot_s
             )
-            mark_sel = ect & ~(
-                forced[:, None] & bool(prog.red_use_hard_drop)
+
+            # 4. window-driven arrivals; AQM (RED mark/early-drop) then
+            # tail-drop past capacity
+            want = jnp.clip(
+                cwnd.astype(jnp.int32) - inflight, 0, burst
             )
-            red_drops = jnp.where(mark_sel, 0, n_act)
-            red_marks = jnp.where(mark_sel, n_act, 0).astype(jnp.float32)
-            want_q = want - red_drops
-        else:
-            want_q = want
-        wtot = want_q.sum(axis=1, dtype=jnp.int32)
-        free = jnp.maximum(Q - q.sum(axis=1, dtype=jnp.int32), 0)
-        # proportional admission with largest-remainder rounding
-        scale = jnp.minimum(
-            free.astype(jnp.float32) / jnp.maximum(wtot, 1).astype(jnp.float32),
-            1.0,
-        )
-        exact = want_q.astype(jnp.float32) * scale[:, None]
-        acc = jnp.floor(exact).astype(jnp.int32)
-        rem = exact - acc
-        leftover = jnp.minimum(
-            free - acc.sum(axis=1, dtype=jnp.int32),
-            wtot - acc.sum(axis=1, dtype=jnp.int32),
-        )
-        order = jnp.argsort(-rem, axis=1)
-        rank = jnp.argsort(order, axis=1)
-        acc = acc + (
-            (rank < leftover[:, None]) & (acc < want_q)
-        ).astype(jnp.int32)
-        acc = jnp.minimum(acc, want_q)
-        rej = want_q - acc
-        q = q + acc
-        # marked packets are among the admitted ones (integer count)
-        q_marked = q_marked + jnp.minimum(red_marks, acc.astype(jnp.float32))
-        inflight = inflight + want
-        drops = s["drops"] + rej + red_drops
-        lidx = (t + prog.ack_lag) % L  # dupack-timed detection
-        loss_buf = loss_buf.at[:, lidx, :].add(rej + red_drops)
+            live = (t >= start[None, :]) & (t < stop[None, :]) & (
+                delivered + inflight < max_pkts[None, :]
+            )
+            want = jnp.where(live, want, 0)
+            if TRAFFIC:
+                # app-limited sending: the workload's cumulative offered
+                # segments (closed-form, shared across replicas — the
+                # realization IS the workload, like the mobility
+                # trajectory) caps what may ever have left the
+                # application — an EXACT clip, not a gate, so the send
+                # burst cannot overshoot the offered count.  Arrivals
+                # inside a slot are sendable in that slot (the slot-end
+                # evaluation — sub-slot timing is below this model's
+                # resolution either way)
+                app_cum = jnp.floor(
+                    tr_cum(tr, (t + 1) * jnp.int32(slot_us))
+                ).astype(jnp.int32)                          # (F,)
+                want = jnp.minimum(
+                    want,
+                    jnp.maximum(
+                        app_cum[None, :] - delivered - inflight, 0
+                    ),
+                )
+            red_avg = s["red_avg"]
+            red_marks = jnp.zeros((R, F), jnp.float32)
+            red_drops = jnp.zeros((R, F), jnp.int32)
+            if RED:
+                # EWMA over this slot's arrivals against the instantaneous
+                # queue (per-arrival updates folded into one (1-qw)^n step;
+                # idle-time decay not modeled — the bottleneck is backlogged
+                # in every regime this engine targets)
+                qnow = q.sum(axis=1, dtype=jnp.int32).astype(jnp.float32)
+                n_arr = want.sum(axis=1, dtype=jnp.int32)
+                red_avg = jnp.where(
+                    n_arr > 0,
+                    qnow
+                    + (red_avg - qnow)
+                    * jnp.float32(1.0 - prog.red_qw) ** n_arr,
+                    red_avg,
+                )
+                p = jnp.where(
+                    red_avg < prog.red_min_th,
+                    0.0,
+                    prog.red_max_p
+                    * (red_avg - prog.red_min_th)
+                    / max(prog.red_max_th - prog.red_min_th, 1e-9),
+                )
+                if prog.red_gentle:
+                    p = jnp.where(
+                        red_avg >= prog.red_max_th,
+                        prog.red_max_p
+                        + (1.0 - prog.red_max_p)
+                        * (red_avg - prog.red_max_th) / prog.red_max_th,
+                        p,
+                    )
+                    forced = red_avg >= 2.0 * prog.red_max_th
+                else:
+                    forced = red_avg >= prog.red_max_th
+                p = jnp.clip(jnp.where(forced, 1.0, p), 0.0, 1.0)
+                # ECT packets are marked unless the forced region hard-drops
+                ect = ecn_cap[None, :] & prog.red_use_ecn
+                n_act = jnp.minimum(
+                    want,
+                    jnp.floor(
+                        want.astype(jnp.float32) * p[:, None] + u_red
+                    ).astype(jnp.int32),
+                )
+                mark_sel = ect & ~(
+                    forced[:, None] & bool(prog.red_use_hard_drop)
+                )
+                red_drops = jnp.where(mark_sel, 0, n_act)
+                red_marks = jnp.where(mark_sel, n_act, 0).astype(jnp.float32)
+                want_q = want - red_drops
+            else:
+                want_q = want
+            wtot = want_q.sum(axis=1, dtype=jnp.int32)
+            free = jnp.maximum(Q - q.sum(axis=1, dtype=jnp.int32), 0)
+            # proportional admission with largest-remainder rounding
+            scale = jnp.minimum(
+                free.astype(jnp.float32)
+                / jnp.maximum(wtot, 1).astype(jnp.float32),
+                1.0,
+            )
+            exact = want_q.astype(jnp.float32) * scale[:, None]
+            acc = jnp.floor(exact).astype(jnp.int32)
+            rem = exact - acc
+            leftover = jnp.minimum(
+                free - acc.sum(axis=1, dtype=jnp.int32),
+                wtot - acc.sum(axis=1, dtype=jnp.int32),
+            )
+            order = jnp.argsort(-rem, axis=1)
+            rank = jnp.argsort(order, axis=1)
+            acc = acc + (
+                (rank < leftover[:, None]) & (acc < want_q)
+            ).astype(jnp.int32)
+            acc = jnp.minimum(acc, want_q)
+            rej = want_q - acc
+            q = q + acc
+            # marked packets are among the admitted ones (integer count)
+            q_marked = q_marked + jnp.minimum(
+                red_marks, acc.astype(jnp.float32)
+            )
+            inflight = inflight + want
+            drops = s["drops"] + rej + red_drops
+            lidx = (t + prog.ack_lag) % L  # dupack-timed detection
+            loss_buf = loss_buf.at[:, lidx, :].add(rej + red_drops)
 
         extra = {}
         if obs:
@@ -1436,6 +1456,21 @@ def run_tcp_dumbbell(
     )
     L = Launch("dumbbell", key, replicas, mesh, n_cfg)
 
+    if variants is None:
+        points = [np.asarray(prog.variant_idx, np.int32)]
+    else:
+        points = [_variant_point(p) for p in variants]
+    from tpudes.obs import spans
+
+    launch = spans.current()
+    if launch is not None and launch.name == "launch":
+        # what the seventeen masked rules were run FOR: the flow count
+        # and the sorted names of the variants this launch assigns
+        launch.args["n_flows"] = int(prog.n_flows)
+        launch.args["variants"] = sorted(
+            {VARIANTS[int(i)] for p in points for i in p}
+        )
+
     def build():
         init_state, fn = build_dumbbell_advance(
             prog, L.r_pad, obs=L.obs, n_cfg=n_cfg, sweep=sweep
@@ -1444,11 +1479,6 @@ def run_tcp_dumbbell(
             lambda: (stack_axis((jnp.int32(0), init_state()), n_cfg),),
             (L.axis,), fn, None,
         )
-
-    if variants is None:
-        points = [np.asarray(prog.variant_idx, np.int32)]
-    else:
-        points = [_variant_point(p) for p in variants]
 
     def operands(parts):
         if variants is None:
