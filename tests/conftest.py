@@ -45,3 +45,46 @@ def sm_lowerings_built():
         return {k[at] for k in RUNTIME._runners if k[0] == "lte_sm"}
 
     return built
+
+
+@pytest.fixture
+def scalar_step_keys(monkeypatch):
+    """``runtime.step_keys`` in the form every loop wrote by hand before
+    it (``replicated.py`` and ``tcp_dumbbell.py`` up to PR 35): the
+    counter folded into the key as a SCALAR, then one fold a replica.
+    What the helper has to equal bit for bit.
+
+    ``scalar_step_keys.same_bits(run, label)`` calls ``run()`` (which
+    builds an engine's loop and runs it) as the tree stands, then again
+    with this form over ``runtime.step_keys`` (the loop as it was),
+    requires the two results equal leaf for leaf, and returns them as
+    numpy."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tpudes.parallel import runtime
+
+    def scalar(engine, key, counter, n):
+        scalar.calls += 1
+        k = jax.random.fold_in(key, counter)
+        return jax.vmap(lambda r: jax.random.fold_in(k, r))(jnp.arange(n))
+
+    def same_bits(run, label):
+        new = jax.tree_util.tree_map(np.asarray, run())
+        scalar.calls = 0
+        with monkeypatch.context() as m:
+            m.setattr(runtime, "step_keys", scalar)
+            old = jax.tree_util.tree_map(np.asarray, run())
+        assert scalar.calls > 0, "the builder did not take the helper"
+        leaves, structure = jax.tree_util.tree_flatten_with_path(new)
+        assert structure == jax.tree_util.tree_structure(old)
+        for (path, a), b in zip(leaves, jax.tree_util.tree_leaves(old)):
+            np.testing.assert_array_equal(
+                a, b, err_msg=f"{label}: {jax.tree_util.keystr(path)}"
+            )
+        return old
+
+    scalar.calls = 0
+    scalar.same_bits = same_bits
+    return scalar

@@ -189,8 +189,14 @@ def test_reference_agrees_with_the_host_des_at_four_stations(interval_s):
 #: the body's last instead of the condition's first, the advance runs
 #: them once before the loop to seed the carried flag where it ran the
 #: 29 of ``pending`` after it, and the condition is a compare and an
-#: ``and``.  The event step itself is still the 382 it was.
-LEGACY_SHAPE = dict(leaves=16, init=15, advance=31, body=412, cond=2)
+#: ``and``.  The event step itself was still the 382 it was; since PR 36
+#: it is 387: ``runtime.step_keys`` adds five equations (body 412 ->
+#: 417): the counter's broadcast to a block of lanes, its
+#: ``optimization_barrier``, and the reshape, the ``tile`` (``(1, 1)``
+#: up to 1024 replicas) and the slice that make the folded block the
+#: rows of the per-replica fold; the counter's ``random_fold_in`` has a
+#: vector operand where it had a scalar one.  No other equation moved.
+LEGACY_SHAPE = dict(leaves=16, init=15, advance=31, body=417, cond=2)
 
 
 #: the launch carry's leaves: what ``runtime.jit_init`` builds,
@@ -270,6 +276,64 @@ def test_loop_condition_reads_a_carried_scalar(over, leaves):
     assert {k: (v.shape, v.dtype) for k, v in state.items()} == {
         k: (v.shape, v.dtype) for k, v in s0.items()
     }
+
+
+def _bss_loop_body(over):
+    from tpudes.parallel.replicated import _trace_prog
+
+    prog = _trace_prog(**over)
+    init, _, fn = build_bss_advance(prog, 4)
+    advance = jax.make_jaxpr(fn)(
+        init(), jax.random.PRNGKey(0), jnp.int32(64),
+        jnp.int32(prog.sim_end_us), None, None,
+    )
+    (loop,) = [e for e in advance.jaxpr.eqns if e.primitive.name == "while"]
+    return loop.params["body_jaxpr"].jaxpr
+
+
+def _dumbbell_loop_body(over):
+    from tpudes.parallel.tcp_dumbbell import (
+        _trace_prog,
+        build_dumbbell_advance,
+    )
+
+    prog = _trace_prog(**over)
+    init, fn = build_dumbbell_advance(prog, 4)
+    advance = jax.make_jaxpr(fn)(
+        (jnp.int32(0), init()), jax.random.PRNGKey(0),
+        jnp.asarray(prog.variant_idx, jnp.int32),
+        jnp.zeros(prog.n_flows, bool), jnp.int32(8), None,
+    )
+    (loop,) = [e for e in advance.jaxpr.eqns if e.primitive.name == "while"]
+    return loop.params["body_jaxpr"].jaxpr
+
+
+@pytest.mark.parametrize(
+    "body, over",
+    [
+        (_bss_loop_body, {}),
+        (_bss_loop_body, dict(max_mpdus=8, subframe_bytes=580)),
+        (_dumbbell_loop_body, {}),
+        (_dumbbell_loop_body, dict(qdisc="red")),
+    ],
+    ids=["bss-legacy", "bss-ht", "dumbbell-fifo", "dumbbell-red"],
+)
+def test_loop_body_folds_no_key_on_the_scalar_core(body, over):
+    """The shape that must not come back: a ``fold_in`` (or a bare
+    threefry) in a loop's body whose operands are all scalars.  XLA
+    leaves its ~124 operations unfused on the TPU's scalar core, inside
+    the ``while``, under no event of their own: 3.5 us of every BSS
+    step until PR 36.  The step's keys come from ``runtime.step_keys``,
+    whose folds have a replica-sized operand."""
+    from tpudes.analysis.jaxpr.trace import walk_eqns
+
+    folds = [
+        e for e in walk_eqns(body(over))
+        if e.primitive.name in ("random_fold_in", "threefry2x32")
+    ]
+    assert folds, "the step derives its keys in the loop's body"
+    for e in folds:
+        assert any(v.aval.ndim > 0 for v in e.invars), e
 
 
 def test_legacy_result_has_no_tx_mpdus_and_the_ht_one_does():

@@ -381,3 +381,41 @@ def test_carried_predicate_is_bit_identical_to_the_recomputing_loop(case):
         assert int(np.sum(np.asarray(s_ref["tx_mpdus"]))) > int(
             np.sum(np.asarray(s_ref["tx_data"]))
         )
+
+
+# --------------------------------------------------------------------------
+# the step's keys come from runtime.step_keys (vector operands):
+# bit-identity against the loop that folds the counter as a scalar
+# --------------------------------------------------------------------------
+
+
+CARRY_CASES = [
+    "legacy", "ampdu", "mobile", "traffic", "obs", "horizon_sweep",
+    "traffic_sweep", "chunked",
+]
+
+
+@pytest.mark.parametrize("case", CARRY_CASES)
+def test_vector_step_keys_are_bit_identical_to_the_scalar_fold(
+    case, scalar_step_keys
+):
+    """``build_bss_advance`` as it is against the same builder with
+    ``runtime.step_keys`` replaced by the scalar fold the step did by
+    hand: every carry leaf, the pending vector and the chunk metrics,
+    after every call."""
+    from tpudes.parallel.replicated import build_bss_advance
+    from tpudes.parallel.runtime import stack_axis
+
+    prog, kw, n_cfg, sim_end, geom, tr, budgets = _carry_case(case)
+    key = jax.random.PRNGKey(9)
+
+    def run():
+        init, _, fn = build_bss_advance(prog, 4, **kw)
+        fn, s, outs = jax.jit(fn), stack_axis(init(), n_cfg), []
+        for budget in budgets:
+            outs.append(fn(s, key, np.int32(budget), sim_end, geom, tr))
+            s = outs[-1][0]
+        return outs
+
+    state, still_pending, _ = scalar_step_keys.same_bits(run, case)[-1]
+    assert not still_pending.any() and state["tx_data"].sum() > 0

@@ -22,6 +22,7 @@ from tpudes.parallel.runtime import (
     configure_persistent_cache,
     pow2_bucket,
     replica_keys,
+    step_keys,
 )
 
 
@@ -125,6 +126,50 @@ def test_replica_keys_rows_independent_of_padding():
     a = np.asarray(replica_keys(k, 5))
     b = np.asarray(replica_keys(k, 8))
     np.testing.assert_array_equal(a, b[:5])
+
+
+@pytest.mark.parametrize("how", ["jit", "while", "vmap"])
+@pytest.mark.parametrize("n", [1, 3, 512, 1500])  # 1500: more than one block
+@pytest.mark.parametrize("t", [0, 1, 1958, 2**31 - 1])
+def test_step_keys_equal_the_scalar_fold(t, n, how, scalar_step_keys):
+    """Row r of ``step_keys(engine, key, t, n)`` is
+    ``fold_in(fold_in(key, t), r)`` bit for bit, whatever carries the
+    counter: a jitted argument, the carry of a ``while_loop`` (every
+    engine's case), or a config-axis ``vmap`` that batches the counter
+    and not the key (``build_bss_advance``'s sweeps)."""
+    import jax.numpy as jnp
+
+    key = jax.random.PRNGKey(36)
+    counters = np.asarray([t, t // 2, 7] if how == "vmap" else [t], np.int32)
+    if how == "jit":
+        got = jax.jit(lambda k, c: step_keys("toy", k, c, n))(
+            key, counters[0]
+        )[None]
+    elif how == "while":
+        # one iteration, its counter the loop's carry as in the engines
+        got = jax.jit(
+            lambda k, c0: jax.lax.while_loop(
+                lambda c: c[0] == c0,
+                lambda c: (c[0] + 1, step_keys("toy", k, c[0], n)),
+                (c0, jnp.zeros((n, 2), jnp.uint32)),
+            )
+        )(key, counters[0])[1][None]
+    else:
+        got = jax.jit(
+            jax.vmap(lambda k, c: step_keys("toy", k, c, n), (None, 0))
+        )(key, counters)
+    got = np.asarray(got)
+    for rows, c in zip(got, counters):
+        # the form the loops wrote by hand, and its definition row by
+        # row at both ends of the replica axis
+        np.testing.assert_array_equal(
+            rows, np.asarray(scalar_step_keys("toy", key, c, n))
+        )
+        k = jax.random.fold_in(key, c)
+        for r in {0, n - 1}:
+            np.testing.assert_array_equal(
+                rows[r], np.asarray(jax.random.fold_in(k, r))
+            )
 
 
 # --- warm-call guarantee: repeat-call compile count == 1 per engine -----

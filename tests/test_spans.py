@@ -15,6 +15,7 @@ stable device names (ISSUE 25).
 """
 
 import importlib
+import re
 import threading
 
 import jax
@@ -393,6 +394,12 @@ ENGINE_SCOPES = {
 }
 
 
+#: the engines whose loop folds a scalar counter into the launch key:
+#: their step keys come from ``runtime.step_keys`` (the LTE loops fold
+#: per lane, under ``lte_sm.RNG_SCOPE``)
+STEP_KEY_ENGINES = {"bss", "dumbbell"}
+
+
 def _lowered(entry) -> str:
     return jax.jit(entry.fn).lower(*entry.args).as_text(debug_info=True)
 
@@ -406,6 +413,15 @@ def test_engine_loop_lowers_with_stable_scope_names(module):
     for part in ("step", "cond"):
         scope = f"tpudes.{engine}.{part}"
         assert any(scope in t for t in texts), scope
+    if engine in STEP_KEY_ENGINES:
+        # the step's keys: runtime.step_keys, under a name of its own
+        # inside the step, down to the threefry of its folds
+        scope = f"tpudes.{engine}.step/tpudes.{engine}.rng/"
+        assert any(
+            scope + "optimization_barrier" in t
+            and re.search(re.escape(scope) + r"[^\"]*_threefry_fold_in", t)
+            for t in texts
+        ), scope
 
 
 @pytest.mark.parametrize("variant", ["base", "traffic"])

@@ -188,3 +188,96 @@ def test_mesh_sharded_run():
     out = run_tcp_dumbbell(prog, jax.random.PRNGKey(0), replicas=16, mesh=mesh)
     assert np.asarray(out["delivered"]).shape == (16, 2)
     assert int(np.asarray(out["delivered"]).sum()) > 0
+
+
+# --------------------------------------------------------------------------
+# the slot's keys come from runtime.step_keys (vector operands):
+# bit-identity against the loop that folds the slot counter as a scalar
+# --------------------------------------------------------------------------
+
+
+def _slot_case(case):
+    """``(prog, kwargs of build_dumbbell_advance, n_cfg, var, ecn, tr,
+    slot bounds of the successive calls)`` of one program variant."""
+    import dataclasses
+
+    from tpudes.parallel.programs import (
+        toy_dumbbell_program,
+        toy_traffic_points,
+    )
+    from tpudes.parallel.tcp_dumbbell import V_CUBIC, V_DCTCP, V_VEGAS
+
+    prog = toy_dumbbell_program(n_flows=3, n_slots=160)
+    kw, n_cfg, tr, bounds = {}, None, None, (prog.n_slots,)
+    var = np.asarray(prog.variant_idx, np.int32)
+    ecn = np.zeros(prog.n_flows, bool)
+    if case == "cubic":
+        var = np.full(prog.n_flows, V_CUBIC, np.int32)
+    elif case == "mixed":
+        # a variant sweep: the config axis batches the slot counter
+        n_cfg, kw = 2, dict(n_cfg=2, sweep="variant")
+        var = np.asarray(
+            [[V_CUBIC, V_VEGAS, V_DCTCP], var], np.int32
+        )
+        ecn = np.asarray([[False, False, True], [False] * 3])
+    elif case == "red_ecn":
+        prog = dataclasses.replace(prog, qdisc="red", red_use_ecn=True)
+        var = np.asarray([V_DCTCP, V_CUBIC, V_DCTCP], np.int32)
+        ecn = np.asarray([True, False, True])
+    elif case == "traffic_sweep":
+        from tpudes.traffic.device import stack_traffic_operands
+
+        pts = toy_traffic_points(prog.n_flows, prog.n_slots * 1000)[:3]
+        prog = dataclasses.replace(prog, traffic=pts[0])
+        n_cfg, kw = len(pts), dict(n_cfg=len(pts), sweep="traffic")
+        tr = stack_traffic_operands(pts)
+    elif case == "chunked":
+        # three calls: the carry re-enters twice at t > 0
+        bounds = (37, 90, prog.n_slots)
+    else:
+        assert case == "obs"
+        kw = dict(obs=True)
+    return prog, kw, n_cfg, var, ecn, tr, bounds
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["cubic", "mixed", "red_ecn", "traffic_sweep", "chunked", "obs"],
+)
+def test_vector_step_keys_are_bit_identical_to_the_scalar_fold(
+    case, scalar_step_keys
+):
+    """``build_dumbbell_advance`` as it is against the same builder with
+    ``runtime.step_keys`` replaced by the scalar fold (the slot key the
+    loop's body folded by hand, then one fold a replica): the slot
+    counter, every carry leaf and the chunk metrics, after every call."""
+    import jax.numpy as jnp
+
+    from tpudes.parallel.runtime import stack_axis
+    from tpudes.parallel.tcp_dumbbell import build_dumbbell_advance
+
+    prog, kw, n_cfg, var, ecn, tr, bounds = _slot_case(case)
+
+    def run():
+        init, fn = build_dumbbell_advance(prog, 4, **kw)
+        fn, outs = jax.jit(fn), []
+        for key in (jax.random.PRNGKey(9), jax.random.PRNGKey(10)):
+            carry = stack_axis((jnp.int32(0), init()), n_cfg)
+            for t_end in bounds:
+                carry, metrics = fn(
+                    carry, key, var, ecn, np.int32(t_end), tr
+                )
+                outs.append((carry, metrics))
+        return outs
+
+    old = scalar_step_keys.same_bits(run, case)
+    # the case ran to its horizon, did some work, and the draws
+    # decided some of it: the second key's run is another run
+    (t, s), _ = old[len(bounds) - 1]
+    (_, s_other), _ = old[-1]
+    assert (t == prog.n_slots).all() and s["delivered"].sum() > 0
+    assert any(
+        not np.array_equal(a, b) for a, b in zip(
+            jax.tree_util.tree_leaves(s), jax.tree_util.tree_leaves(s_other)
+        )
+    )

@@ -571,6 +571,9 @@ def build_bss_step(
     the simulation horizon ``sim_end`` (µs) is a RUNTIME operand, so
     one compiled program serves every horizon and the config-axis
     sweep vmaps a batch of horizons alongside the replica axis.
+    ``key`` is the LAUNCH key, the same at every step: ``step_fn``
+    derives the step's per-replica keys itself, from it and the
+    carried ``s["step"]``, through :func:`runtime.step_keys`.
 
     ``pending`` is the next-event search as a predicate: per replica,
     whether an arrival or a transmission still falls before its
@@ -603,6 +606,7 @@ def build_bss_step(
     n = prog.n
     R = replicas
     from tpudes.ops.wifi_error import ALL_MODES
+    from tpudes.parallel.runtime import step_keys
 
     if obs:
         from tpudes.obs.flowmon import (
@@ -765,8 +769,7 @@ def build_bss_step(
         # replica-bucketing (padding R to a power of two) leaves every
         # real replica's stream bit-identical.  A joint uniform(key,
         # (R, n)) draw would reshuffle all replicas whenever R changes.
-        k = jax.random.fold_in(key, s["step"])
-        rkeys = jax.vmap(lambda i: jax.random.fold_in(k, i))(jnp.arange(R))
+        rkeys = step_keys("bss", key, s["step"], R)
         if AGG:
 
             def draw(kk):

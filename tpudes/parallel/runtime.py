@@ -85,7 +85,10 @@ engines (replicated BSS, LTE SM, TCP dumbbell, AS flows, wired).
   ``result.wait`` / ``.fetch`` / ``.unpack`` in :class:`EngineFuture`,
   whose constructor also ends the ``launch`` span ``run_lifted``
   opened.  :func:`scoped_while_loop` gives every engine's outermost
-  loop the stable device names ``tpudes.<engine>.step`` / ``.cond``.
+  loop the stable device names ``tpudes.<engine>.step`` / ``.cond``;
+  :func:`step_keys`, which derives a step's per-replica keys for the
+  loops that fold a scalar counter into the launch key (BSS,
+  dumbbell), traces under ``tpudes.<engine>.rng``.
 """
 
 from __future__ import annotations
@@ -108,6 +111,7 @@ __all__ = [
     "replica_keys",
     "scoped_while_loop",
     "shard_replica_axis",
+    "step_keys",
     "stack_axis",
 ]
 
@@ -318,6 +322,44 @@ def scoped_while_loop(engine: str, cond, body, init):
         return inner
 
     return jax.lax.while_loop(scoped("cond", cond), scoped("step", body), init)
+
+
+#: the block :func:`step_keys` folds the step counter over: one TPU
+#: vector register of 32-bit lanes.  Read on the chip against the whole
+#: replica axis ``(R,)`` and against ``(128,)`` (PERF.md section 6, PR 36)
+_STEP_KEY_LANES = (8, 128)
+
+
+def step_keys(engine: str, key, counter, n: int):
+    """(n, …) per-replica keys of ONE step of an engine's loop: row
+    ``r`` is ``fold_in(fold_in(key, counter), r)`` — pure in ``(key,
+    counter, r)`` and independent of ``n``, so bucketing and chunked
+    re-entry (``counter`` > 0 on entry) keep every replica's stream.
+    ``counter`` is the loop's traced scalar step count.
+
+    Both folds run with VECTOR operands.  ``fold_in(key, counter)`` on
+    the scalar itself is ~124 unfused threefry operations on the TPU's
+    scalar core, inside the ``while``, with no event of their own (3.5
+    of the BSS loop's 5.2 µs a step, PERF.md section 6, PRs 33 and 36).
+    So the counter is broadcast to one vector register of lanes behind
+    an ``optimization_barrier`` (without it XLA folds the broadcast
+    back into the scalar computation), folded there as redundant lanes
+    — one fusion — and the rows of that block seed the per-replica
+    fold.  Same bits either way.  The keys trace under the device name
+    ``tpudes.<engine>.rng``."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.named_scope(f"tpudes.{engine}.rng"):
+        lanes = jax.lax.optimization_barrier(
+            jnp.broadcast_to(counter, _STEP_KEY_LANES)
+        )
+        step_key = jax.vmap(jax.vmap(lambda c: jax.random.fold_in(key, c)))(
+            lanes
+        ).reshape((-1,) + jnp.shape(key))
+        reps = (-(-n // len(step_key)),) + (1,) * jnp.ndim(key)
+        rows = jnp.tile(step_key, reps)[:n]
+        return jax.vmap(jax.random.fold_in)(rows, jnp.arange(n))
 
 
 def _named(fn, name: str):

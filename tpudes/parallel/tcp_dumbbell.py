@@ -730,7 +730,8 @@ OBS_QHIST_BINS = 16
 
 #: ``jax.named_scope`` names inside one slot, in ``tf_op`` of the
 #: operations they cover (names only: the arithmetic is what it was):
-#: the per-replica ``fold_in`` and the slot's draws; the window rules
+#: the slot's keys (``runtime.step_keys`` traces under the same
+#: ``tpudes.dumbbell.rng``) and its draws; the window rules
 #: (``_cwnd_increase`` + ``_loss_response``, all seventeen variants,
 #: and the selects that apply them); departure and admission at the
 #: bottleneck queue
@@ -742,7 +743,11 @@ QUEUE_SCOPE = "tpudes.dumbbell.queue"
 def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False):
     """Return (init_state, step_fn) for the slot-stepped scan.
 
-    ``step_fn(s, (t, key), var, ecn_cap)`` — the per-flow variant ids
+    ``step_fn(s, (t, key), var, ecn_cap)`` — ``t`` is the slot counter
+    and ``key`` the LAUNCH key, the same at every slot: ``step_fn``
+    derives the slot's per-replica keys itself, from the two, through
+    :func:`runtime.step_keys` (no caller folds ``t`` into the key).
+    The per-flow variant ids
     ``var`` (F,) and ECN-capability flags ``ecn_cap`` (F,) are RUNTIME
     operands, not trace-time constants: every variant assignment rides
     one compiled executable, and the config-axis sweep vmaps them
@@ -754,6 +759,8 @@ def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False)
     a bottleneck-occupancy histogram — fetched once at run end.  A
     disabled run compiles the exact pre-obs program.
     """
+    from tpudes.parallel.runtime import step_keys
+
     R, F, L = replicas, prog.n_flows, prog.buf_len
     if obs:
         from tpudes.obs.flowmon import (
@@ -850,10 +857,8 @@ def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False)
         # function of (key, t, r) — independent of R — so runtime
         # replica-bucketing (padding R to a power of two) leaves every
         # real replica's stream bit-identical
+        rkeys = step_keys("dumbbell", key, t, R)
         with jax.named_scope(RNG_SCOPE):
-            rkeys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
-                jnp.arange(R)
-            )
             if RED:
 
                 def draw(kk):
@@ -1196,6 +1201,9 @@ def build_dumbbell_advance(prog: DumbbellProgram, r_pad: int,
     the UNJITTED advance exactly as :func:`run_tcp_dumbbell` jits it —
     factored out so the trace manifest (:func:`trace_manifest`)
     abstractly traces the same program the runner cache compiles.
+    ``key`` is the launch key; the loop's body hands it to ``step_fn``
+    unchanged beside the carried slot counter, and ``step_fn`` derives
+    the slot's keys (:func:`runtime.step_keys`).
 
     With a config axis (``n_cfg``), ``sweep`` picks which operand
     carries it: ``"variant"`` vmaps the per-flow variant/ECN
@@ -1208,14 +1216,13 @@ def build_dumbbell_advance(prog: DumbbellProgram, r_pad: int,
     init_state, step_fn = build_dumbbell_step(prog, r_pad, obs=obs)
 
     def advance(carry, key, var, ecn, t_end, tr=None):
-        # per-slot key = fold_in(key, t): pure in (key, t), so the
-        # traced horizon needs no split-keys array shape and a
-        # chunked run re-enters at t>0 on the same slot streams
+        # a slot's keys are pure in (key, t) (runtime.step_keys, in
+        # step_fn), so the traced horizon needs no split-keys array
+        # shape and a chunked run re-enters at t>0 on the same slot
+        # streams
         def body(c):
             t, s = c
-            s, _ = step_fn(
-                s, (t, jax.random.fold_in(key, t)), var, ecn, tr
-            )
+            s, _ = step_fn(s, (t, key), var, ecn, tr)
             return t + 1, s
 
         t, s = scoped_while_loop(
