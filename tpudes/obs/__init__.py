@@ -40,10 +40,14 @@ which adds carry buffers and so compiles a DIFFERENT device program
 that measures the simulated network: these measure the simulator and
 change no executable.  To read them: ``tpudes.obs.spans.snapshot()``
 in process, or any ``jax.profiler`` trace, where the spans are the
-host-plane events ``tpudes:*`` and every engine's loop carries the
-scopes ``tpudes.<engine>.step`` / ``.cond`` (LTE also
-``tpudes.lte_sm.rng`` and the kernel ``tpudes_lte_sm_tti``) on its
-device operations.
+host-plane events ``tpudes:*`` and every engine's loop carries its
+scopes (``tpudes.<engine>.step`` / ``.cond`` and the engines' own
+``.rng``, ``.ampdu``, ``.cc``, ``.queue``: PERF.md section 3 has the
+list) on its device operations.  :mod:`tpudes.obs.explain` reads such a
+trace by those names into one table (a loop step by scope, the
+``while``'s own time, the idle gaps by span): ``explain.session()``
+around launches, ``explain.replay()`` of the last one, or ``python -m
+tpudes.obs --explain <trace>``; it is imported on use, not here.
 """
 
 from tpudes.obs import spans

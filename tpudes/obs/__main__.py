@@ -11,6 +11,7 @@ Usage::
     python -m tpudes.obs --grad <metrics.json> [more.json ...]
     python -m tpudes.obs --flowmon <flowmon.xml> [more.xml ...]
     python -m tpudes.obs --pcap <capture.pcap> [more.pcap ...]
+    python -m tpudes.obs --explain <trace dir or .xplane.pb> [more ...]
 
 Default mode checks Chrome-trace exports against the Trace Event
 format; ``--serving`` checks :class:`tpudes.obs.serving.ServingTelemetry`
@@ -31,8 +32,11 @@ non-finite canaries); ``--flowmon`` checks FlowMonitor XML exports
 (ours or upstream ns-3's ``SerializeToXmlFile``) for the standard
 FlowStats attribute set; ``--pcap`` structurally validates classic
 libpcap captures (both byte orders, µs and ns magic) record by record
-— these two read XML / raw bytes, not JSON.  Exit 0 when every
-file is valid, 1 on
+— these two read XML / raw bytes, not JSON.  ``--explain`` validates
+nothing: it prints :mod:`tpudes.obs.explain`'s table of a
+``jax.profiler`` trace of lifted runs (a loop step by ``tpudes.*``
+scope, the ``while``'s own time, the idle gaps by ``tpudes:`` span).
+Exit 0 when every file is valid, 1 on
 violations, 2 on usage / unreadable input.  These are the schema gates
 the CI smoke steps run over the artifacts an example (``TpudesObs=1``),
 the serving smoke, and the fuzz smoke produce.
@@ -62,20 +66,32 @@ def main(argv: list[str] | None = None) -> int:
     grad = "--grad" in argv
     flowmon = "--flowmon" in argv
     pcap = "--pcap" in argv
+    explain = "--explain" in argv
     argv = [
         a for a in argv
         if a not in ("--serving", "--fuzz", "--distributed",
                      "--geometry", "--traffic", "--grad",
-                     "--flowmon", "--pcap")
+                     "--flowmon", "--pcap", "--explain")
     ]
     if (
         not argv
         or serving + fuzz + distributed + geometry + traffic + grad
-        + flowmon + pcap > 1
+        + flowmon + pcap + explain > 1
         or any(a in ("-h", "--help") for a in argv)
     ):
         print(__doc__, file=sys.stderr)
         return 2
+    if explain:
+        from tpudes.obs.explain import format_table, load, reduce
+
+        for path in argv:
+            try:
+                events = load(path)
+            except (OSError, ImportError) as e:
+                print(f"{path}: unreadable ({e})", file=sys.stderr)
+                return 2
+            print(f"{path}:\n{format_table(reduce(events))}")
+        return 0
     if flowmon or pcap:
         # non-JSON modes: FlowMonitor XML / raw libpcap bytes
         from tpudes.obs.flowmon import validate_flowmon_xml, validate_pcap

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from tpudes.obs.spans import OWN, span
 from tpudes.parallel.replicated import UnliftableScenarioError
+from tpudes.parallel.runtime import RUNTIME
 
 
 def _iter_nodes():
@@ -298,6 +299,9 @@ def run_lifted(kind: str, prog, replicas: int, key=None, mesh=None,
     launch = span("launch", OWN, kind=kind, replicas=int(replicas)).open()
     try:
         import jax
+
+        # for tpudes.obs.explain.replay(): references the caller holds anyway
+        RUNTIME.last_lifted = (kind, prog, replicas, key, mesh, engine_kwargs)
 
         if key is None:
             key = lifted_key()

@@ -691,6 +691,10 @@ class Launch:
         from tpudes.obs.device import CompileTelemetry
 
         engine, fn, ops = self.engine, self.fn, self.ops
+        explain = RUNTIME.explain
+        if explain is not None:
+            # an open tpudes.obs.explain session stops the loop early
+            bounds = explain.shorten(self, call, bounds)
         ckpt = None
         if checkpoint is not None:
             from tpudes.parallel.checkpoint import checkpoint_ctx
@@ -762,6 +766,10 @@ class EngineRuntime:
         self.retired = 0
         self.max_in_flight = 0
         self._launches: dict[str, int] = {}
+        # tpudes.obs.explain: the open session (None: off), and what
+        # run_lifted was last called with, for explain.replay()
+        self.explain = None
+        self.last_lifted = None
 
     def runner(self, engine: str, key, build):
         """Return ``(value, compiled_new)``: the cached runner for
