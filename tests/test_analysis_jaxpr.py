@@ -503,6 +503,34 @@ def test_dumbbell_red_knobs_out_of_fifo_key():
     np.testing.assert_array_equal(out["delivered"], base["delivered"])
 
 
+def test_dumbbell_variant_set_is_keyed_and_the_assignment_is_not():
+    """The slot compiles the window rules of the variants assigned
+    (ISSUE 38): an assignment INSIDE the base program's set leaves every
+    trace identical and the runner's key equal; another set is another
+    program and another key (a live component).  The manifest keeps both
+    ends of what a set can compile on the lint surface: one variant's
+    rules (``var`` is then no declared-traced operand: nothing reads
+    it) and all seventeen's."""
+    from tpudes.analysis.jaxpr import trace as T
+    from tpudes.parallel import tcp_dumbbell
+
+    man = tcp_dumbbell.trace_manifest()
+    variants = {v.name: v for v in man.variants()}
+    assert list(variants)[0] == "base"
+    assert {"obs", "one_variant", "all_variants"} <= set(variants)
+    base = T.variant_fingerprints(variants["base"].build())
+    flips = man.flips()
+    inside, other = flips["variant_idx"], flips["variant_set"]
+    assert not inside.key_differs
+    assert T.variant_fingerprints(inside.build()) == base
+    assert other.key_differs
+    assert T.variant_fingerprints(other.build())["advance"] != base["advance"]
+    (one,) = [e for e in variants["one_variant"].build() if e.kernel]
+    (every,) = [e for e in variants["all_variants"].build() if e.kernel]
+    assert "var" not in one.traced and every.traced["var"] == 2
+    assert len(set(np.asarray(every.args[2]))) == len(tcp_dumbbell.VARIANTS)
+
+
 # --- JXL006 grad hygiene (ISSUE-15) ----------------------------------------
 
 

@@ -6,8 +6,10 @@ SURVEY.md §2.7/§2.9) — to a device-resident **packet-slot** program: one
 ``lax.scan`` step per bottleneck serialization time τ (= pkt_bytes·8/C),
 per-replica per-flow state in (R, F) arrays, all SEVENTEEN
 TcpCongestionOps variants (the full upstream family incl. BBR, DCTCP,
-H-TCP, YeAH, LEDBAT and TCP-LP) evaluated as masked vector rules in one
-fused step.  A RED root
+H-TCP, YeAH, LEDBAT and TCP-LP) as vector rules in one fused step: a
+table keyed by variant id, of which a launch compiles the rules of the
+variants it assigns and selects among them on the traced per-flow ids.
+A RED root
 qdisc on the bottleneck lowers too: EWMA average queue, early
 drop/CE-mark (RFC 3168 ECE triggers the variant's loss response; DCTCP
 scales its cut by the marked fraction), gentle mode, hard-drop forced
@@ -39,6 +41,7 @@ goodput, not per-packet equality.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 from dataclasses import dataclass
@@ -387,28 +390,112 @@ def lower_dumbbell(sim_end_s: float) -> DumbbellProgram:
     )
 
 
-def _cwnd_increase(var, cwnd, ssthresh, acked, t_s, rtt_s, st,
-                   acked_raw=None):
-    """Vectorized per-ack cwnd growth for all thirteen variants
-    (segments).
+# --- the window rules: a table keyed by variant id --------------------------
+#
+# A slot program is built for the SET of variants a launch assigns
+# (``present``: static, part of the runner's key); the per-flow ids
+# ``var`` stay a traced operand inside it.  ``_RULES`` gives each
+# variant its increase rule, its ssthresh rule, the shared estimators
+# it reads and the side leaves its own rules keep.  A program traces
+# the rules and estimators of ``present`` only, and its selects range
+# over ``present`` only.  A rule's arithmetic is elementwise over
+# (replica, flow), so a flow's window is bit for bit what the program
+# built for all seventeen gives it: only rules whose result that
+# program's select discarded are gone.
 
-    ``st`` carries the variant side-state dict; returns (new_cwnd, st').
-    Masked-dense: every rule computes, the variant index selects.
-    ``acked_raw`` (defaults to ``acked``) feeds the PktsAcked-analog
-    estimators (min-RTT, Westwood BWE, Illinois delay, BBR rounds) —
-    the host calls PktsAcked on every ack, recovery or not, while
-    window growth sees only the recovery-masked count.
-    """
-    w = jnp.maximum(cwnd, 1.0)
-    a = acked.astype(jnp.float32)
-    ar = a if acked_raw is None else acked_raw.astype(jnp.float32)
-    in_ss = cwnd < ssthresh
 
-    # --- PktsAcked-analog side estimators (raw acks) --------------------
-    sampled = ar > 0
-    min_rtt = jnp.where(
-        sampled, jnp.minimum(st["min_rtt"], rtt_s), st["min_rtt"]
+class _Flows:
+    """The traced per-flow variant ids, seen through the static set of
+    variants the program was built for."""
+
+    def __init__(self, var, present):
+        self.var, self.present = var, present
+
+    def __contains__(self, v):
+        return v in self.present
+
+    def mask(self, *ids):
+        """The flows that run one of ``ids`` (those of them that are
+        present; one at least): a fresh compare on the traced ids, or
+        ``True`` where the program was built for nothing else (no
+        compare at all)."""
+        ids = [v for v in ids if v in self.present]
+        if len(ids) == len(self.present):
+            return True
+        m = self.var == ids[0]
+        for v in ids[1:]:
+            m = m | (self.var == v)
+        return m
+
+
+def _where(mask, x, y):
+    """``jnp.where`` under a :meth:`_Flows.mask`."""
+    return x if mask is True else jnp.where(mask, x, y)
+
+
+def _both(mask, x):
+    """``mask & x`` under a :meth:`_Flows.mask`."""
+    return x if mask is True else mask & x
+
+
+def _select(cases, default=0):
+    """``jnp.select`` over ``(mask, value)`` cases, first match wins; a
+    ``True`` mask (the program's only variant) is the whole answer."""
+    if cases[0][0] is True:
+        return cases[0][1]
+    return jnp.select(
+        [m for m, _ in cases], [x for _, x in cases], default
     )
+
+
+class _Call:
+    """What the rules of one call share: the flows, the operands, the
+    side leaves as they came in (``st``) and those written so far
+    (``out``), and the values more than one rule reads, each traced
+    once, where the first rule asks for it."""
+
+    def __init__(self, flows, st, **operands):
+        self.flows, self.st, self.out = flows, st, {}
+        self.__dict__.update(operands)
+
+    @functools.cached_property
+    def sampled(self):
+        return self.ar > 0
+
+    @functools.cached_property
+    def inc_reno(self):
+        return self.a / self.w
+
+    @functools.cached_property
+    def diff(self):
+        # vegas / veno / yeah backlog estimate from the shared rtt sample
+        st = self.st
+        return self.w * (
+            1.0 - st["base_rtt"] / jnp.maximum(self.rtt_s, st["base_rtt"])
+        )
+
+    @functools.cached_property
+    def rho(self):
+        # Hybla: growth normalized by rho = RTT / 25 ms
+        return jnp.maximum(self.rtt_s / HYBLA_RRTT, 1.0)
+
+    @functools.cached_property
+    def in_infer(self):
+        return self.t_s < self.st["lp_until"]
+
+
+# --- PktsAcked-analog side estimators (raw acks), shared by variants -------
+
+
+def _est_min_rtt(c):
+    st = c.st
+    c.min_rtt = c.out["min_rtt"] = jnp.where(
+        c.sampled, jnp.minimum(st["min_rtt"], c.rtt_s), st["min_rtt"]
+    )
+
+
+def _est_westwood(c):
+    st, w, ar, rtt_s, sampled = c.st, c.w, c.ar, c.rtt_s, c.sampled
     # Westwood+: EWMA bandwidth once ~a cwnd's worth of acks arrived
     ww_acc = st["ww_acc"] + ar
     ww_done = sampled & (ww_acc >= w)
@@ -420,10 +507,21 @@ def _cwnd_increase(var, cwnd, ssthresh, acked, t_s, rtt_s, st,
         st["bwe"],
     )
     ww_acc = jnp.where(ww_done, 0.0, ww_acc)
-    # Illinois: delay-modulated alpha/beta
-    ill_max = jnp.where(
-        sampled, jnp.maximum(st["ill_max_rtt"], rtt_s), st["ill_max_rtt"]
+    c.out.update(ww_acc=ww_acc, bwe=bwe)
+
+
+def _est_ill_max_rtt(c):
+    st = c.st
+    c.ill_max = c.out["ill_max_rtt"] = jnp.where(
+        c.sampled, jnp.maximum(st["ill_max_rtt"], c.rtt_s),
+        st["ill_max_rtt"],
     )
+
+
+def _est_illinois(c):
+    st, rtt_s, sampled = c.st, c.rtt_s, c.sampled
+    ill_max, min_rtt = c.ill_max, c.min_rtt
+    # Illinois: delay-modulated alpha/beta
     dm = ill_max - min_rtt
     da = jnp.maximum(rtt_s - min_rtt, 0.0)
     d1 = 0.01 * dm
@@ -437,7 +535,7 @@ def _cwnd_increase(var, cwnd, ssthresh, acked, t_s, rtt_s, st,
         + (ILL_BETA_MAX - ILL_BETA_MIN) * da / jnp.maximum(dm, 1e-9),
         ILL_BETA_MIN, ILL_BETA_MAX,
     )
-    ill_alpha = jnp.where(
+    c.ill_alpha = jnp.where(
         sampled, jnp.where(dm <= 0.0, ILL_ALPHA_MAX, alpha_raw),
         st["ill_alpha"],
     )
@@ -445,6 +543,11 @@ def _cwnd_increase(var, cwnd, ssthresh, acked, t_s, rtt_s, st,
         sampled, jnp.where(dm <= 0.0, ILL_BETA_MIN, beta_raw),
         st["ill_beta"],
     )
+    c.out.update(ill_alpha=c.ill_alpha, ill_beta=ill_beta)
+
+
+def _est_bbr(c):
+    st, w, ar, rtt_s, sampled = c.st, c.w, c.ar, c.rtt_s, c.sampled
     # BBR: per-round max-filtered delivery rate + state machine
     bbr_acc = st["bbr_acc"] + ar
     round_done = sampled & (bbr_acc >= w)
@@ -473,17 +576,52 @@ def _cwnd_increase(var, cwnd, ssthresh, acked, t_s, rtt_s, st,
         (st["bbr_cycle"] + 1) % len(BBR_CYCLE_GAINS),
         st["bbr_cycle"],
     )
+    c.bbr_bw, c.bbr_state, c.bbr_cycle = bbr_bw, state, bbr_cycle
+    c.out.update(bbr_acc=bbr_acc, bbr_bw=bbr_bw, bbr_full_bw=bbr_full_bw,
+                 bbr_full_cnt=bbr_full_cnt, bbr_state=state,
+                 bbr_cycle=bbr_cycle)
 
-    # --- congestion avoidance rules (per ack batch) ---------------------
-    inc_reno = a / w
-    inc_scal = a / jnp.minimum(w, SCALABLE_AI)
+
+#: the shared estimators by name, with the side leaves each keeps.  The
+#: first five run before the increase rules, in this order (a later one
+#: reads an earlier one's result); ``diff`` is traced where the first
+#: rule asks for it (:attr:`_Call.diff`; its ``last_diff`` is written
+#: last) and ``dctcp`` is the marked-fraction EWMA in ``step_fn``
+_ESTIMATORS = {
+    "min_rtt": (_est_min_rtt, ("min_rtt",)),
+    "westwood": (_est_westwood, ("ww_acc", "bwe")),
+    "ill_max_rtt": (_est_ill_max_rtt, ("ill_max_rtt",)),
+    "illinois": (_est_illinois, ("ill_alpha", "ill_beta")),
+    "bbr": (_est_bbr, ("bbr_acc", "bbr_bw", "bbr_full_bw", "bbr_full_cnt",
+                       "bbr_state", "bbr_cycle")),
+    "diff": (None, ("base_rtt", "last_diff")),
+    "dctcp": (None, ("dctcp_alpha",)),
+}
+
+
+# --- congestion avoidance rules (per ack batch) ----------------------------
+
+
+def _grow_reno(c):
+    return c.inc_reno
+
+
+def _grow_scalable(c):
+    return c.a / jnp.minimum(c.w, SCALABLE_AI)
+
+
+def _grow_highspeed(c):
+    w, a = c.w, c.a
     a_hs = jnp.where(
         w <= HS_LOW_WINDOW, 1.0, jnp.maximum(1.0, 0.156 * w**0.8 / 2.0)
     )
-    inc_hs = a_hs * a / w
+    return a_hs * a / w
 
+
+def _grow_cubic(c):
+    st, w, a, t_s, rtt_s = c.st, c.w, c.a, c.t_s, c.rtt_s
     # cubic: (re)open an epoch on first CA ack after loss
-    fresh = (st["epoch_t"] < 0.0) & (a > 0) & ~in_ss
+    fresh = (st["epoch_t"] < 0.0) & (a > 0) & ~c.in_ss
     k = jnp.where(
         st["w_max"] > w,
         jnp.cbrt(jnp.maximum(st["w_max"] - w, 0.0) / CUBIC_C),
@@ -498,164 +636,121 @@ def _cwnd_increase(var, cwnd, ssthresh, acked, t_s, rtt_s, st,
     target = origin + CUBIC_C * (te - k) ** 3
     w_est = w_est + 3.0 * (1 - CUBIC_BETA) / (1 + CUBIC_BETA) * a / w
     target = jnp.maximum(target, w_est)
-    inc_cubic = jnp.clip((target - w) / w, 0.0, 0.5) * a
+    c.out.update(epoch_t=epoch_t, k=k, origin=origin, w_est=w_est)
+    return jnp.clip((target - w) / w, 0.0, 0.5) * a
 
-    # vegas / veno backlog estimate from the shared rtt sample
-    diff = w * (1.0 - st["base_rtt"] / jnp.maximum(rtt_s, st["base_rtt"]))
-    inc_vegas = jnp.where(
+
+def _grow_vegas(c):
+    w, a, diff = c.w, c.a, c.diff
+    return jnp.where(
         diff < VEGAS_ALPHA, a / w, jnp.where(diff > VEGAS_BETA, -a / w, 0.0)
     )
-    inc_veno = jnp.where(diff < VENO_BETA, inc_reno, 0.5 * inc_reno)
 
+
+def _grow_veno(c):
+    return jnp.where(c.diff < VENO_BETA, c.inc_reno, 0.5 * c.inc_reno)
+
+
+def _grow_linux_reno(c):
+    st, w, a = c.st, c.w, c.a
     # Linux reno (and DCTCP, which inherits it): whole-cwnd ack counting
-    is_lr = (var == V_LINUXRENO) | (var == V_DCTCP)
+    c.is_lr = c.flows.mask(V_LINUXRENO, V_DCTCP)
     cnt = st["cwnd_cnt"] + a
     whole = jnp.floor(cnt / w)
-    inc_lr = whole
-    new_cnt = jnp.where(
-        is_lr & ~in_ss & (a > 0), cnt - whole * w, st["cwnd_cnt"]
+    c.out["cwnd_cnt"] = jnp.where(
+        _both(c.is_lr, ~c.in_ss) & (a > 0), cnt - whole * w, st["cwnd_cnt"]
     )
+    return whole
 
+
+def _grow_bic(c):
+    st, w, a = c.st, c.w, c.a
     # BIC: binary search toward w_max, max-probe beyond it
     bic_mid = jnp.minimum((st["w_max"] - w) / 2.0, BIC_MAX_INCR)
     bic_probe = jnp.minimum(w - st["w_max"] + 1.0, BIC_MAX_INCR)
     bic_inc = jnp.maximum(
         jnp.where(w < st["w_max"], bic_mid, bic_probe), BIC_SMIN
     )
-    inc_bic = jnp.where(
+    return jnp.where(
         (w < BIC_LOW_WND) | (st["w_max"] == 0.0),
-        inc_reno, a * bic_inc / w,
+        c.inc_reno, a * bic_inc / w,
     )
 
-    inc_ill = ill_alpha * a / w
 
-    # Hybla: growth normalized by rho = RTT / 25 ms
-    rho = jnp.maximum(rtt_s / HYBLA_RRTT, 1.0)
-    inc_hybla = a * rho * rho / w
+def _grow_illinois(c):
+    return c.ill_alpha * c.a / c.w
 
+
+def _grow_hybla(c):
+    rho = c.rho
+    return c.a * rho * rho / c.w
+
+
+def _grow_htcp(c):
+    st = c.st
     # H-TCP: additive increase grows with time since the last congestion
     # event (quadratic past the 1 s low-speed boundary), scaled by the
     # adaptive backoff beta carried in st["htcp_beta"]
-    h_delta = jnp.maximum(t_s - st["htcp_last_cong"] - HTCP_DELTA_B, 0.0)
+    h_delta = jnp.maximum(c.t_s - st["htcp_last_cong"] - HTCP_DELTA_B, 0.0)
     h_alpha = jnp.maximum(
         2.0 * (1.0 - st["htcp_beta"])
         * (1.0 + 10.0 * h_delta + 0.25 * h_delta * h_delta),
         1.0,
     )
-    inc_htcp = h_alpha * a / w
+    return h_alpha * c.a / c.w
 
+
+def _grow_yeah(c):
+    w, a, diff = c.w, c.a, c.diff
     # YeAH: STCP fast mode while the backlog estimate (the shared
     # Vegas-style `diff`) stays under Q_max; Reno slow mode past it with
     # the precautionary decongestion shed spread over one cwnd of acks
-    inc_yeah = jnp.where(
+    return jnp.where(
         diff < YEAH_QMAX,
         a / jnp.minimum(w, YEAH_ALPHA),
         (1.0 - diff * (1.0 - YEAH_RHO)) * a / w,
     )
 
+
+def _grow_ledbat(c):
+    st, rtt_s = c.st, c.rtt_s
     # LEDBAT: window tracks the 100 ms queueing-delay target; negative
     # off-target shrinks the window (scavenger behavior)
     qdelay = jnp.maximum(rtt_s - jnp.minimum(st["min_rtt"], rtt_s), 0.0)
-    inc_ledbat = (
-        LEDBAT_GAIN * (LEDBAT_TARGET_S - qdelay) / LEDBAT_TARGET_S * a / w
+    return (
+        LEDBAT_GAIN * (LEDBAT_TARGET_S - qdelay) / LEDBAT_TARGET_S
+        * c.a / c.w
     )
 
+
+def _grow_lp(c):
     # TCP-LP: Reno growth outside the inference phase (the early-
-    # congestion collapse itself is applied after the select below)
-    in_infer = t_s < st["lp_until"]
-    inc_lp = jnp.where(in_infer, 0.0, inc_reno)
-
-    inc_ca = jnp.select(
-        [var == V_NEWRENO, var == V_CUBIC, var == V_SCALABLE,
-         var == V_HIGHSPEED, var == V_VEGAS, var == V_VENO,
-         is_lr, var == V_BIC, var == V_WESTWOOD,
-         var == V_ILLINOIS, var == V_HYBLA, var == V_HTCP,
-         var == V_YEAH, var == V_LEDBAT, var == V_LP],
-        [inc_reno, inc_cubic, inc_scal, inc_hs, inc_vegas, inc_veno,
-         inc_lr, inc_bic, inc_reno, inc_ill, inc_hybla, inc_htcp,
-         inc_yeah, inc_ledbat, inc_lp],
-    )
-    # slow start: +1 per ack (Hybla: 2^rho − 1 per ack); Vegas leaves SS
-    # once the backlog passes γ
-    vegas_exit = (var == V_VEGAS) & in_ss & (diff > VEGAS_GAMMA) & (a > 0)
-    ssthresh = jnp.where(vegas_exit, jnp.maximum(w - 1.0, 2.0), ssthresh)
-    inc_ss = jnp.where(var == V_HYBLA, a * (2.0**rho - 1.0), a)
-    inc = jnp.where(in_ss & ~vegas_exit, inc_ss, inc_ca)
-    # TCP-LP yields completely while inferring congestion: the collapsed
-    # 1-segment window must not slow-start straight back up, or the
-    # scavenger stops yielding (the host's ack-clocked hold is slower
-    # than this slot model's, so the gate covers slow start too)
-    inc = jnp.where((var == V_LP) & in_infer, 0.0, inc)
-    # TCP-LP's inference collapse holds at ONE segment (host behavior);
-    # every other variant keeps the usual 2-segment floor
-    floor = jnp.where(
-        (var == V_LP) & in_infer, jnp.float32(1.0), jnp.float32(2.0)
-    )
-    new_cwnd = jnp.maximum(cwnd + jnp.where(a > 0, inc, 0.0), floor)
-
-    # BBR replaces loss-driven AIMD entirely: cwnd tracks gain × BDP
-    gain = jnp.select(
-        [state == BBR_STARTUP, state == BBR_DRAIN],
-        [BBR_HIGH_GAIN, 1.0 / BBR_HIGH_GAIN],
-        # dtype pinned: an unpinned float table would ride f64 through
-        # the whole BBR lane under ambient x64 (JXL002)
-        jnp.asarray(BBR_CYCLE_GAINS, jnp.float32)[bbr_cycle],
-    )
-    bdp = bbr_bw * min_rtt
-    target = jnp.maximum(gain * bdp, 4.0)
-    cwnd_bbr = jnp.where(
-        bbr_bw == 0.0,
-        cwnd + a,                                 # first RTTs
-        jnp.where(
-            cwnd < target,
-            cwnd + jnp.minimum(a, target - cwnd + 1.0),
-            jnp.maximum(target, 4.0),
-        ),
-    )
-    new_cwnd = jnp.where(
-        var == V_BBR, jnp.where(a > 0, cwnd_bbr, cwnd), new_cwnd
-    )
-
-    # TCP-LP early-congestion inference: one-way delay past 15% of the
-    # observed delay range collapses the window to one segment and holds
-    # the inference phase for one RTT (host PktsAcked hook)
-    lp_trigger = (
-        (var == V_LP) & sampled & (ill_max > min_rtt)
-        & (rtt_s > min_rtt + LP_INFERENCE_FRAC * (ill_max - min_rtt))
-        & ~in_infer
-    )
-    new_cwnd = jnp.where(lp_trigger, 1.0, new_cwnd)
-    ssthresh = jnp.where(
-        lp_trigger, jnp.maximum(ssthresh / 2.0, 2.0), ssthresh
-    )
-    lp_until = jnp.where(
-        lp_trigger, t_s + rtt_s, st["lp_until"]
-    )
-
-    st = dict(st, epoch_t=epoch_t, k=k, origin=origin, w_est=w_est,
-              lp_until=lp_until,
-              last_diff=jnp.where(a > 0, diff, st["last_diff"]),
-              min_rtt=min_rtt, ww_acc=ww_acc, bwe=bwe,
-              ill_max_rtt=ill_max, ill_alpha=ill_alpha, ill_beta=ill_beta,
-              bbr_acc=bbr_acc, bbr_bw=bbr_bw, bbr_full_bw=bbr_full_bw,
-              bbr_full_cnt=bbr_full_cnt, bbr_state=state,
-              bbr_cycle=bbr_cycle, cwnd_cnt=new_cnt)
-    return new_cwnd, ssthresh, st
+    # congestion collapse itself is applied after the select)
+    return jnp.where(c.in_infer, 0.0, c.inc_reno)
 
 
-def _loss_response(var, cwnd, st, t_s):
-    """Vectorized GetSsThresh on a detected loss (segments).
+# --- GetSsThresh rules (on a detected loss) --------------------------------
 
-    ``t_s`` stamps H-TCP's last-congestion clock (its additive increase
-    grows with the time elapsed since this moment)."""
-    w = jnp.maximum(cwnd, 1.0)
-    ss_reno = w / 2.0
+
+def _cut_reno(c):
+    return c.w / 2.0
+
+
+def _cut_cubic(c):
+    st, w = c.st, c.w
     # cubic fast convergence: remember a reduced w_max when still climbing
-    new_wmax = jnp.where(
+    c.w_max[V_CUBIC] = jnp.where(
         w < st["w_max"], w * (1.0 + CUBIC_BETA) / 2.0, w
     )
-    ss_cubic = w * CUBIC_BETA
-    ss_scal = w * (1.0 - SCALABLE_MD)
+    return w * CUBIC_BETA
+
+
+def _cut_scalable(c):
+    return c.w * (1.0 - SCALABLE_MD)
+
+
+def _cut_highspeed(c):
+    w = c.w
     b_hs = jnp.where(
         w <= HS_LOW_WINDOW,
         0.5,
@@ -667,62 +762,316 @@ def _loss_response(var, cwnd, st, t_s):
             0.1,
         ),
     )
-    ss_hs = w * (1.0 - b_hs)
-    ss_veno = jnp.where(st["last_diff"] < VENO_BETA, w * 0.8, w * 0.5)
+    return w * (1.0 - b_hs)
+
+
+def _cut_veno(c):
+    w = c.w
+    return jnp.where(c.st["last_diff"] < VENO_BETA, w * 0.8, w * 0.5)
+
+
+def _cut_bic(c):
+    st, w = c.st, c.w
     # BIC fast convergence mirrors cubic's w_max bookkeeping at β=0.8
-    bic_wmax = jnp.where(w < st["w_max"], w * (1.0 + BIC_BETA) / 2.0, w)
-    ss_bic = w * BIC_BETA
+    c.w_max[V_BIC] = jnp.where(
+        w < st["w_max"], w * (1.0 + BIC_BETA) / 2.0, w
+    )
+    return w * BIC_BETA
+
+
+def _cut_westwood(c):
+    st, w = c.st, c.w
     # Westwood+: BWE · RTTmin instead of blind halving
-    ss_west = jnp.where(
+    return jnp.where(
         (st["bwe"] > 0.0) & jnp.isfinite(st["min_rtt"]),
         st["bwe"] * st["min_rtt"], w / 2.0,
     )
-    ss_ill = w * (1.0 - st["ill_beta"])
+
+
+def _cut_illinois(c):
+    return c.w * (1.0 - c.st["ill_beta"])
+
+
+def _cut_bbr(c):
+    st = c.st
     # BBR ignores loss beyond the BDP floor
-    ss_bbr = jnp.maximum(st["bbr_bw"] * jnp.where(
+    return jnp.maximum(st["bbr_bw"] * jnp.where(
         jnp.isfinite(st["min_rtt"]), st["min_rtt"], 0.0
     ), 4.0)
+
+
+def _cut_dctcp(c):
     # DCTCP: reduction fraction follows the marked-byte EWMA
-    ss_dctcp = w * (1.0 - st["dctcp_alpha"] / 2.0)
+    return c.w * (1.0 - c.st["dctcp_alpha"] / 2.0)
+
+
+def _cut_htcp(c):
+    st = c.st
     # H-TCP adaptive backoff: beta = RTTmin/RTTmax clamped to [0.5, 0.8]
     # once an RTT spread exists, default 0.5 before
     h_valid = (st["ill_max_rtt"] > 0.0) & jnp.isfinite(st["min_rtt"])
-    h_beta = jnp.where(
+    c.h_beta = jnp.where(
         h_valid,
         jnp.clip(
             st["min_rtt"] / jnp.maximum(st["ill_max_rtt"], 1e-9), 0.5, 0.8
         ),
         HTCP_DEFAULT_BACKOFF,
     )
-    ss_htcp = w * h_beta
+    return c.w * c.h_beta
+
+
+def _cut_yeah(c):
+    w = c.w
     # YeAH: shed the larger of the measured backlog and cwnd/8
-    ss_yeah = w - jnp.maximum(st["last_diff"], w / 8.0)
-    ssthresh = jnp.select(
-        [var == V_NEWRENO, var == V_CUBIC, var == V_SCALABLE,
-         var == V_HIGHSPEED, var == V_VEGAS, var == V_VENO,
-         var == V_LINUXRENO, var == V_BIC, var == V_WESTWOOD,
-         var == V_ILLINOIS, var == V_HYBLA, var == V_BBR,
-         var == V_DCTCP, var == V_HTCP, var == V_YEAH,
-         var == V_LEDBAT, var == V_LP],
-        [ss_reno, ss_cubic, ss_scal, ss_hs, ss_reno, ss_veno,
-         ss_reno, ss_bic, ss_west, ss_ill, ss_reno, ss_bbr, ss_dctcp,
-         ss_htcp, ss_yeah, ss_reno, ss_reno],
+    return w - jnp.maximum(c.st["last_diff"], w / 8.0)
+
+
+@dataclass(frozen=True)
+class _Rule:
+    """One variant's window rules."""
+
+    #: congestion-avoidance increase per ack batch; None: BBR keeps no
+    #: AIMD increase (its window tracks gain × BDP)
+    grow: object
+    #: ssthresh on a detected loss
+    cut: object
+    #: the shared estimators (``_ESTIMATORS``) its rules read
+    uses: tuple = ()
+    #: the side leaves its own rules keep (read and write)
+    leaves: tuple = ()
+
+
+_RULES = {
+    V_NEWRENO: _Rule(_grow_reno, _cut_reno),
+    V_CUBIC: _Rule(
+        _grow_cubic, _cut_cubic,
+        leaves=("w_max", "epoch_t", "k", "origin", "w_est"),
+    ),
+    V_SCALABLE: _Rule(_grow_scalable, _cut_scalable),
+    V_HIGHSPEED: _Rule(_grow_highspeed, _cut_highspeed),
+    V_VEGAS: _Rule(_grow_vegas, _cut_reno, uses=("diff",)),
+    V_VENO: _Rule(_grow_veno, _cut_veno, uses=("diff",)),
+    V_LINUXRENO: _Rule(_grow_linux_reno, _cut_reno, leaves=("cwnd_cnt",)),
+    V_BIC: _Rule(_grow_bic, _cut_bic, leaves=("w_max",)),
+    V_WESTWOOD: _Rule(_grow_reno, _cut_westwood, uses=("min_rtt", "westwood")),
+    V_ILLINOIS: _Rule(
+        _grow_illinois, _cut_illinois,
+        uses=("min_rtt", "ill_max_rtt", "illinois"),
+    ),
+    V_HYBLA: _Rule(_grow_hybla, _cut_reno),
+    V_BBR: _Rule(None, _cut_bbr, uses=("min_rtt", "bbr")),
+    V_DCTCP: _Rule(
+        _grow_linux_reno, _cut_dctcp, uses=("dctcp",), leaves=("cwnd_cnt",)
+    ),
+    V_HTCP: _Rule(
+        _grow_htcp, _cut_htcp, uses=("min_rtt", "ill_max_rtt"),
+        leaves=("htcp_beta", "htcp_last_cong"),
+    ),
+    V_YEAH: _Rule(_grow_yeah, _cut_yeah, uses=("diff",)),
+    V_LEDBAT: _Rule(_grow_ledbat, _cut_reno, uses=("min_rtt",)),
+    V_LP: _Rule(
+        _grow_lp, _cut_reno, uses=("min_rtt", "ill_max_rtt"),
+        leaves=("lp_until",),
+    ),
+}
+
+#: the order the increase rules are traced in: the masked-dense step's
+#: (HighSpeed before CUBIC), so that a program built for all seventeen
+#: is that step's, equation for equation; the ssthresh rules and every
+#: select go by variant id
+_GROW_ORDER = (V_NEWRENO, V_SCALABLE, V_HIGHSPEED, V_CUBIC) + tuple(
+    range(V_VEGAS, len(VARIANTS))
+)
+
+#: what a builder that is handed no set compiles: every rule
+ALL_VARIANTS = tuple(range(len(VARIANTS)))
+
+
+def variant_set(points) -> tuple:
+    """The sorted ids of the variants a launch assigns, a sweep's
+    points pooled: the static part of the runner's key that says which
+    window rules its slot program holds."""
+    return tuple(sorted({int(i) for p in points for i in p}))
+
+
+def _used(present) -> set:
+    """The shared estimators the rules of ``present`` read."""
+    return {name for v in present for name in _RULES[v].uses}
+
+
+def live_side_leaves(present) -> set:
+    """The side leaves some rule of ``present`` reads or writes.  The
+    slot passes every other one through untouched."""
+    return {
+        leaf for v in present for leaf in _RULES[v].leaves
+    } | {leaf for name in _used(present) for leaf in _ESTIMATORS[name][1]}
+
+
+def _trace_rules(c, order, kind) -> dict:
+    """``{rule: its result}`` of the ``kind`` (``grow`` / ``cut``) rules
+    of the present variants among ``order``, each rule traced once
+    (variants share rules) and in that order."""
+    done = {}
+    for v in order:
+        rule = getattr(_RULES[v], kind)
+        if v in c.flows and rule is not None and rule not in done:
+            done[rule] = rule(c)
+    return done
+
+
+def _cwnd_increase(flows, cwnd, ssthresh, acked, t_s, rtt_s, st,
+                   acked_raw=None):
+    """Vectorized per-ack cwnd growth (segments) for the variants
+    ``flows`` was built for.
+
+    ``st`` carries the variant side-state dict; returns (new_cwnd,
+    ssthresh, st').  Each present variant's increase rule computes for
+    every flow and the traced ids select among them; a rule, an
+    estimator or a side leaf that no present variant names is not
+    traced (its leaf passes through).  ``acked_raw`` (defaults to
+    ``acked``) feeds the PktsAcked-analog estimators (min-RTT, Westwood
+    BWE, Illinois delay, BBR rounds) — the host calls PktsAcked on
+    every ack, recovery or not, while window growth sees only the
+    recovery-masked count.
+    """
+    present = flows.present
+    w = jnp.maximum(cwnd, 1.0)
+    a = acked.astype(jnp.float32)
+    c = _Call(
+        flows, st, w=w, a=a,
+        ar=a if acked_raw is None else acked_raw.astype(jnp.float32),
+        in_ss=cwnd < ssthresh, t_s=t_s, rtt_s=rtt_s,
+    )
+    in_ss = c.in_ss
+    used = _used(present)
+    for name, (estimate, _) in _ESTIMATORS.items():
+        if estimate is not None and name in used:
+            estimate(c)
+
+    incs = _trace_rules(c, _GROW_ORDER, "grow")
+    new_cwnd = cwnd
+    if incs:
+        # one case a variant; LinuxReno and DCTCP share a rule and its
+        # mask, so they share a case
+        inc_ca = _select([
+            (c.is_lr if _RULES[v].grow is _grow_linux_reno
+             else flows.mask(v), incs[_RULES[v].grow])
+            for v in present
+            if _RULES[v].grow is not None
+            and not (v == V_DCTCP and V_LINUXRENO in flows)
+        ])
+        # slow start: +1 per ack (Hybla: 2^rho − 1 per ack); Vegas leaves
+        # SS once the backlog passes γ
+        vegas_exit = None
+        if V_VEGAS in flows:
+            vegas_exit = (
+                _both(flows.mask(V_VEGAS), in_ss) & (c.diff > VEGAS_GAMMA)
+                & (a > 0)
+            )
+            ssthresh = jnp.where(
+                vegas_exit, jnp.maximum(w - 1.0, 2.0), ssthresh
+            )
+        inc_ss = a
+        if V_HYBLA in flows:
+            inc_ss = _where(
+                flows.mask(V_HYBLA), a * (2.0**c.rho - 1.0), a
+            )
+        inc = jnp.where(
+            in_ss if vegas_exit is None else in_ss & ~vegas_exit,
+            inc_ss, inc_ca,
+        )
+        floor = jnp.float32(2.0)
+        if V_LP in flows:
+            # TCP-LP yields completely while inferring congestion: the
+            # collapsed 1-segment window must not slow-start straight
+            # back up, or the scavenger stops yielding (the host's
+            # ack-clocked hold is slower than this slot model's, so the
+            # gate covers slow start too)
+            inc = jnp.where(_both(flows.mask(V_LP), c.in_infer), 0.0, inc)
+            # TCP-LP's inference collapse holds at ONE segment (host
+            # behavior); every other variant keeps the usual 2-segment
+            # floor
+            floor = jnp.where(
+                _both(flows.mask(V_LP), c.in_infer),
+                jnp.float32(1.0), jnp.float32(2.0),
+            )
+        new_cwnd = jnp.maximum(cwnd + jnp.where(a > 0, inc, 0.0), floor)
+
+    if V_BBR in flows:
+        state, bbr_bw = c.bbr_state, c.bbr_bw
+        # BBR replaces loss-driven AIMD entirely: cwnd tracks gain × BDP
+        gain = jnp.select(
+            [state == BBR_STARTUP, state == BBR_DRAIN],
+            [BBR_HIGH_GAIN, 1.0 / BBR_HIGH_GAIN],
+            # dtype pinned: an unpinned float table would ride f64
+            # through the whole BBR lane under ambient x64 (JXL002)
+            jnp.asarray(BBR_CYCLE_GAINS, jnp.float32)[c.bbr_cycle],
+        )
+        bdp = bbr_bw * c.min_rtt
+        target = jnp.maximum(gain * bdp, 4.0)
+        cwnd_bbr = jnp.where(
+            bbr_bw == 0.0,
+            cwnd + a,                                 # first RTTs
+            jnp.where(
+                cwnd < target,
+                cwnd + jnp.minimum(a, target - cwnd + 1.0),
+                jnp.maximum(target, 4.0),
+            ),
+        )
+        new_cwnd = _where(
+            flows.mask(V_BBR), jnp.where(a > 0, cwnd_bbr, cwnd), new_cwnd
+        )
+
+    if V_LP in flows:
+        ill_max, min_rtt = c.ill_max, c.min_rtt
+        # TCP-LP early-congestion inference: one-way delay past 15% of
+        # the observed delay range collapses the window to one segment
+        # and holds the inference phase for one RTT (host PktsAcked hook)
+        lp_trigger = (
+            _both(flows.mask(V_LP), c.sampled) & (ill_max > min_rtt)
+            & (rtt_s > min_rtt + LP_INFERENCE_FRAC * (ill_max - min_rtt))
+            & ~c.in_infer
+        )
+        new_cwnd = jnp.where(lp_trigger, 1.0, new_cwnd)
+        ssthresh = jnp.where(
+            lp_trigger, jnp.maximum(ssthresh / 2.0, 2.0), ssthresh
+        )
+        c.out["lp_until"] = jnp.where(
+            lp_trigger, t_s + rtt_s, st["lp_until"]
+        )
+    if "diff" in used:
+        c.out["last_diff"] = jnp.where(a > 0, c.diff, st["last_diff"])
+    return new_cwnd, ssthresh, dict(st, **c.out)
+
+
+def _loss_response(flows, cwnd, st, t_s):
+    """Vectorized GetSsThresh on a detected loss (segments), of the
+    variants ``flows`` was built for.
+
+    ``t_s`` stamps H-TCP's last-congestion clock (its additive increase
+    grows with the time elapsed since this moment)."""
+    c = _Call(flows, st, w=jnp.maximum(cwnd, 1.0))
+    c.w_max = {}   # CUBIC's and BIC's fast convergence, by variant
+    ss = _trace_rules(c, flows.present, "cut")
+    ssthresh = _select(
+        [(flows.mask(v), ss[_RULES[v].cut]) for v in flows.present]
     )
     ssthresh = jnp.maximum(ssthresh, 2.0)
-    st = dict(
-        st,
-        w_max=jnp.select(
-            [var == V_CUBIC, var == V_BIC],
-            [new_wmax, bic_wmax],
-            st["w_max"],
-        ),
-        epoch_t=jnp.full_like(st["epoch_t"], -1.0),
-        htcp_beta=jnp.where(var == V_HTCP, h_beta, st["htcp_beta"]),
-        htcp_last_cong=jnp.where(
-            var == V_HTCP, t_s, st["htcp_last_cong"]
-        ),
-    )
-    return ssthresh, st
+    out = {}
+    if c.w_max:
+        out["w_max"] = _select(
+            [(flows.mask(v), x) for v, x in c.w_max.items()], st["w_max"]
+        )
+    if V_CUBIC in flows:
+        out["epoch_t"] = jnp.full_like(st["epoch_t"], -1.0)
+    if V_HTCP in flows:
+        out["htcp_beta"] = _where(
+            flows.mask(V_HTCP), c.h_beta, st["htcp_beta"]
+        )
+        out["htcp_last_cong"] = _where(
+            flows.mask(V_HTCP), t_s, st["htcp_last_cong"]
+        )
+    return ssthresh, dict(st, **out)
 
 
 #: queue-occupancy histogram bins for the on-device obs accumulators
@@ -732,15 +1081,16 @@ OBS_QHIST_BINS = 16
 #: operations they cover (names only: the arithmetic is what it was):
 #: the slot's keys (``runtime.step_keys`` traces under the same
 #: ``tpudes.dumbbell.rng``) and its draws; the window rules
-#: (``_cwnd_increase`` + ``_loss_response``, all seventeen variants,
-#: and the selects that apply them); departure and admission at the
-#: bottleneck queue
+#: (``_cwnd_increase`` + ``_loss_response``, of the variants the
+#: program was built for, and the selects that apply them); departure
+#: and admission at the bottleneck queue
 RNG_SCOPE = "tpudes.dumbbell.rng"
 CC_SCOPE = "tpudes.dumbbell.cc"
 QUEUE_SCOPE = "tpudes.dumbbell.queue"
 
 
-def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False):
+def build_dumbbell_step(prog: DumbbellProgram, replicas: int,
+                        obs: bool = False, present: tuple = ALL_VARIANTS):
     """Return (init_state, step_fn) for the slot-stepped scan.
 
     ``step_fn(s, (t, key), var, ecn_cap)`` — ``t`` is the slot counter
@@ -749,9 +1099,22 @@ def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False)
     :func:`runtime.step_keys` (no caller folds ``t`` into the key).
     The per-flow variant ids
     ``var`` (F,) and ECN-capability flags ``ecn_cap`` (F,) are RUNTIME
-    operands, not trace-time constants: every variant assignment rides
-    one compiled executable, and the config-axis sweep vmaps them
-    alongside the replica axis.
+    operands, not trace-time constants: every assignment drawn from
+    ``present`` rides one compiled executable, and the config-axis
+    sweep vmaps them alongside the replica axis.
+
+    ``present`` is the sorted set of variant ids the slot holds window
+    rules for (:func:`variant_set` of what the launch assigns; handed
+    none, every rule: the program that serves any ``var``).  ``var``
+    must stay inside it: a flow of an absent variant would run no rule
+    at all.  The carry keeps its shape whatever the set:
+    ``init_state()`` returns the same leaves, and a side leaf that no
+    present variant names (:func:`live_side_leaves`) passes through the
+    body untouched, so XLA takes it out of the loop.  Such a leaf ends
+    a run at its INITIAL value where the program built for all
+    seventeen would have updated it (that program runs CUBIC's epoch
+    clock, BBR's state machine, ... for every flow and discards them);
+    nothing a run returns (``_tcp_unpack``) reads one.
 
     ``obs=True`` (the ``TpudesObs`` knob at run time) threads three
     extra accumulators through the carry — per-lane cwnd-cut events,
@@ -762,6 +1125,7 @@ def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False)
     from tpudes.parallel.runtime import step_keys
 
     R, F, L = replicas, prog.n_flows, prog.buf_len
+    live_side = live_side_leaves(present)
     if obs:
         from tpudes.obs.flowmon import (
             FLOW_DELAY_BINS,
@@ -889,26 +1253,31 @@ def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False)
         mark_buf = s["mark_buf"].at[:, idx, :].set(0.0)
         inflight = s["inflight"] - acks - losses
 
-        # DCTCP per-window marked-fraction EWMA (PktsAcked/EceReceived)
-        d_acked = s["dctcp_acked"] + acks.astype(jnp.float32)
-        d_marked = s["dctcp_marked"] + marks
-        win_done = d_acked >= s["cwnd"]
-        side = dict(
-            s["side"],
-            dctcp_alpha=jnp.where(
-                win_done,
-                (1.0 - DCTCP_G) * s["side"]["dctcp_alpha"]
-                + DCTCP_G * d_marked / jnp.maximum(d_acked, 1.0),
-                s["side"]["dctcp_alpha"],
-            ),
-        )
-        d_acked = jnp.where(win_done, 0.0, d_acked)
-        d_marked = jnp.where(win_done, 0.0, d_marked)
+        side = s["side"]
+        d_acked, d_marked = s["dctcp_acked"], s["dctcp_marked"]
+        if V_DCTCP in present:
+            # DCTCP per-window marked-fraction EWMA (PktsAcked/EceReceived)
+            d_acked = d_acked + acks.astype(jnp.float32)
+            d_marked = d_marked + marks
+            win_done = d_acked >= s["cwnd"]
+            side = dict(
+                side,
+                dctcp_alpha=jnp.where(
+                    win_done,
+                    (1.0 - DCTCP_G) * side["dctcp_alpha"]
+                    + DCTCP_G * d_marked / jnp.maximum(d_acked, 1.0),
+                    side["dctcp_alpha"],
+                ),
+            )
+            d_acked = jnp.where(win_done, 0.0, d_acked)
+            d_marked = jnp.where(win_done, 0.0, d_marked)
 
         with jax.named_scope(CC_SCOPE):
             in_recovery = t < s["recover_until"]
+            # (``var[None, :]`` once a call, as the masked-dense step
+            # wrote it: the program of every rule keeps its equations)
             cwnd, ssthresh, side = _cwnd_increase(
-                var[None, :], s["cwnd"], s["ssthresh"],
+                _Flows(var[None, :], present), s["cwnd"], s["ssthresh"],
                 jnp.where(in_recovery, 0, acks), t * slot_s, rtt, side,
                 acked_raw=acks,
             )
@@ -919,12 +1288,15 @@ def build_dumbbell_step(prog: DumbbellProgram, replicas: int, obs: bool = False)
                 (losses > 0) | ((marks > 0) & ecn_cap[None, :])
             ) & ~in_recovery
             ss_loss, side_loss = _loss_response(
-                var[None, :], cwnd, side, t * slot_s
+                _Flows(var[None, :], present), cwnd, side, t * slot_s
             )
             ssthresh = jnp.where(reduce, ss_loss, ssthresh)
             cwnd = jnp.where(reduce, ssthresh, cwnd)
+            # a side leaf no present rule names stays the input leaf
+            # itself, so XLA takes it out of the loop
             side = {
-                k: jnp.where(reduce, side_loss[k], side[k]) for k in side
+                k: jnp.where(reduce, side_loss[k], x) if k in live_side else x
+                for k, x in side.items()
             }
             recover_until = jnp.where(
                 reduce, t + rtt_slots, s["recover_until"]
@@ -1177,7 +1549,11 @@ def dumbbell_prog_key(prog: DumbbellProgram) -> tuple:
     compiled program.  ``n_slots``, ``variant_idx`` and ``ecn`` are
     deliberately ABSENT: the horizon is a traced while_loop bound and
     the variant/ECN assignment a traced operand, so one executable
-    serves every horizon AND every variant assignment.  In fifo mode
+    serves every horizon AND every variant assignment drawn from one
+    SET of variants.  The set itself (:func:`variant_set`: which window
+    rules the slot holds) joins the runner's key beside this one, in
+    :func:`run_tcp_dumbbell`, because a sweep's set is the union of
+    its points and not this program's own.  In fifo mode
     the ``red_*`` parameters are absent too — they never reach the
     fifo program (keying on them was a dead cache-key component
     causing spurious recompiles across RED-parameter sweeps of
@@ -1196,11 +1572,14 @@ def dumbbell_prog_key(prog: DumbbellProgram) -> tuple:
 
 def build_dumbbell_advance(prog: DumbbellProgram, r_pad: int,
                            obs: bool = False, n_cfg: int | None = None,
-                           sweep: str = "variant"):
+                           sweep: str = "variant",
+                           present: tuple = ALL_VARIANTS):
     """``(init_state, fn)`` with ``fn(carry, key, var, ecn, t_end)``
     the UNJITTED advance exactly as :func:`run_tcp_dumbbell` jits it —
     factored out so the trace manifest (:func:`trace_manifest`)
     abstractly traces the same program the runner cache compiles.
+    ``present`` is the set of variants whose window rules the slot
+    holds (:func:`build_dumbbell_step`).
     ``key`` is the launch key; the loop's body hands it to ``step_fn``
     unchanged beside the carried slot counter, and ``step_fn`` derives
     the slot's keys (:func:`runtime.step_keys`).
@@ -1213,7 +1592,9 @@ def build_dumbbell_advance(prog: DumbbellProgram, r_pad: int,
     tables fan out)."""
     from tpudes.parallel.runtime import scoped_while_loop
 
-    init_state, step_fn = build_dumbbell_step(prog, r_pad, obs=obs)
+    init_state, step_fn = build_dumbbell_step(
+        prog, r_pad, obs=obs, present=present
+    )
 
     def advance(carry, key, var, ecn, t_end, tr=None):
         # a slot's keys are pure in (key, t) (runtime.step_keys, in
@@ -1339,6 +1720,9 @@ def tcp_study(prog: DumbbellProgram, key, replicas, mesh=None):
     per-flow variant/ECN assignment is the traced sweep operand, so two
     dumbbell studies coalesce onto one (C, R, F) launch whenever their
     static fields, slot horizon, key, replica count and mesh all match.
+    The set of variants is no part of ``ck``: studies of different
+    variants still coalesce, and the one launch compiles the window
+    rules of their union.
 
     A program whose declared ``ecn`` disagrees with the variants'
     ``REQUIRES_ECN`` flags is marked ``solo``: sweep points derive ECN
@@ -1385,7 +1769,11 @@ def tcp_study(prog: DumbbellProgram, key, replicas, mesh=None):
 
     def warm(n_points):
         # the slot horizon is a traced operand: a 1-slot run compiles
-        # the exact executable every real horizon reuses
+        # the exact executable every real horizon reuses.  It warms
+        # the study's OWN set of variants: a coalesced launch whose
+        # points pool a wider set is another runner and compiles once
+        # more, at its first launch (a runner miss, so
+        # CompileTelemetry counts it)
         tiny = dataclasses.replace(prog, n_slots=1)
         if n_points == 1:
             run_tcp_dumbbell(tiny, key, replicas=replicas, mesh=mesh)
@@ -1423,7 +1811,9 @@ def run_tcp_dumbbell(
     (R,F) and ``queue_hist`` (R, OBS_QHIST_BINS).  The slot horizon AND
     the per-flow variant/ECN assignments are traced operands and the
     replica axis is runtime-bucketed, so horizon/variant/replica sweeps
-    all reuse one executable per replica bucket.
+    all reuse one executable per replica bucket and SET of variants
+    assigned (the slot compiles the window rules of the variants the
+    launch assigns, a sweep's points pooled, and no others).
 
     ``variants=[point, ...]`` (each point an (F,)-sequence of variant
     names or ids) runs a **config-axis sweep**: one launch of a
@@ -1469,18 +1859,23 @@ def run_tcp_dumbbell(
         points = [_variant_point(p) for p in variants]
     from tpudes.obs import spans
 
+    # the variants this launch assigns, a sweep's points pooled: the
+    # slot program holds their window rules and no others
+    present = variant_set(points)
     launch = spans.current()
     if launch is not None and launch.name == "launch":
-        # what the seventeen masked rules were run FOR: the flow count
-        # and the sorted names of the variants this launch assigns
+        # what the slot's window rules were compiled FOR: the flow
+        # count, the sorted names of the variants this launch assigns,
+        # and how many rule sets that is (17: every rule, the program
+        # before the set was part of the key; an all-CUBIC launch: 1)
         launch.args["n_flows"] = int(prog.n_flows)
-        launch.args["variants"] = sorted(
-            {VARIANTS[int(i)] for p in points for i in p}
-        )
+        launch.args["variants"] = sorted(VARIANTS[i] for i in present)
+        launch.args["cc_rules"] = len(present)
 
     def build():
         init_state, fn = build_dumbbell_advance(
-            prog, L.r_pad, obs=L.obs, n_cfg=n_cfg, sweep=sweep
+            prog, L.r_pad, obs=L.obs, n_cfg=n_cfg, sweep=sweep,
+            present=present,
         )
         return (
             lambda: (stack_axis((jnp.int32(0), init_state()), n_cfg),),
@@ -1524,11 +1919,15 @@ def run_tcp_dumbbell(
             tr = None if prog.traffic is None else prog.traffic.operands()
         return parts[0], (var, ecn, tr)
 
-    # see dumbbell_prog_key for what is (deliberately) absent; the
-    # sweep KIND is a cache-key component (the two sweeps vmap
-    # different operands — different executables)
+    # see dumbbell_prog_key for what is (deliberately) absent; the SET
+    # of variants assigned is a cache-key component (the slot holds
+    # their rules only) while the assignment itself is an operand; the
+    # sweep KIND is one too (the two sweeps vmap different operands —
+    # different executables)
     L.prepare(
-        lambda: dumbbell_prog_key(prog) + (L.r_pad, L.obs, n_cfg, sweep),
+        lambda: dumbbell_prog_key(prog) + (
+            present, L.r_pad, L.obs, n_cfg, sweep
+        ),
         build, operands,
     )
 
@@ -1583,13 +1982,20 @@ def _trace_entries(
     JXL007 axis declarations (the axis builders re-enter here)."""
     from tpudes.analysis.jaxpr.spec import TraceEntry
 
-    init_state, fn = build_dumbbell_advance(prog, _TRACE_R, obs=obs)
+    present = variant_set([prog.variant_idx])
+    init_state, fn = build_dumbbell_advance(
+        prog, _TRACE_R, obs=obs, present=present
+    )
     key = jax.random.PRNGKey(0)
     var = jnp.asarray(prog.variant_idx, jnp.int32)
     ecn = jnp.asarray(_variant_ecn(np.asarray(prog.variant_idx)))
     carry = (jnp.int32(0), init_state())
     tr = None if prog.traffic is None else prog.traffic.operands()
-    traced = {"var": 2, "ecn": 3, "t_end": 4}
+    traced = {"ecn": 3, "t_end": 4}
+    if len(present) > 1:
+        # a one-variant program selects on nothing: its ``var`` is an
+        # operand no equation reads, and the set is in the key
+        traced["var"] = 2
     if tr is not None:
         traced["tr"] = 5
     return [
@@ -1610,12 +2016,19 @@ def _scale_axes():
     """JXL007 scale axis for the dumbbell advance kernel: per-flow
     cwnd/ring state is (R, F) — linear in the flow count, budget
     1.0 (an all-pairs fairness table would fire it)."""
+    import dataclasses
+
     from tpudes.analysis.jaxpr.spec import ScaleAxis
 
     from tpudes.parallel.programs import toy_dumbbell_program
 
     def at(v):
+        # the flow count scales, the set of window rules does not
         prog = toy_dumbbell_program(n_flows=int(v), n_slots=30)
+        prog = dataclasses.replace(
+            prog,
+            variant_idx=np.resize(_trace_prog().variant_idx, int(v)),
+        )
         return _trace_entries(prog, scale=False)[1]
 
     return (
@@ -1632,11 +2045,14 @@ def _trace_flips():
 
     base = _trace_prog()
 
+    def runner_key(prog):
+        return dumbbell_prog_key(prog), variant_set([prog.variant_idx])
+
     def flip(**over):
         prog = dataclasses.replace(base, **over)
         return FlipSpec(
             build=lambda p=prog: _trace_entries(p),
-            key_differs=dumbbell_prog_key(prog) != dumbbell_prog_key(base),
+            key_differs=runner_key(prog) != runner_key(base),
         )
 
     from tpudes.traffic import TrafficProgram
@@ -1655,13 +2071,18 @@ def _trace_flips():
             build=lambda: _trace_entries(base, obs=True),
             key_differs=True,
         ),
+        # another SET of variants is another slot program (other
+        # window rules), and the set is in the runner's key
+        "variant_set": flip(
+            variant_idx=np.asarray([3, 5], np.int32)
+        ),
         # excluded-by-design fields must leave every trace identical:
-        # the horizon/variant assignment are traced operands, and in
-        # fifo mode the RED knobs never reach the program (the JXL004-
-        # found dead components)
+        # the horizon and the variant assignment INSIDE the program's
+        # set are traced operands, and in fifo mode the RED knobs never
+        # reach the program (the JXL004-found dead components)
         "n_slots": flip(n_slots=60),
         "variant_idx": flip(
-            variant_idx=np.asarray([3, 5], np.int32)
+            variant_idx=base.variant_idx[::-1].copy()
         ),
         "red_qw": flip(red_qw=0.5),
     }
@@ -1670,6 +2091,8 @@ def _trace_flips():
 def trace_manifest():
     """Per-engine trace manifest (see :mod:`tpudes.analysis.jaxpr`)."""
     from tpudes.analysis.jaxpr.spec import TraceManifest, TraceVariant
+
+    from tpudes.parallel.programs import toy_dumbbell_program
 
     return TraceManifest(
         engine="dumbbell",
@@ -1683,6 +2106,28 @@ def trace_manifest():
             # must pass the registered SparseSite contract (JXL008)
             TraceVariant(
                 "obs", lambda: _trace_entries(_trace_prog(), obs=True)
+            ),
+            # the two ends of what a launch's set of variants can
+            # compile: one variant's rules (no select, ``var`` unread)
+            # and all seventeen's, so that every rule stays on the
+            # lint surface whatever the toy program assigns
+            TraceVariant(
+                "one_variant",
+                lambda: _trace_entries(
+                    _trace_prog(variant_idx=np.asarray(
+                        [V_CUBIC, V_CUBIC], np.int32
+                    )),
+                    scale=False,
+                ),
+            ),
+            TraceVariant(
+                "all_variants",
+                lambda: _trace_entries(
+                    toy_dumbbell_program(
+                        n_flows=len(VARIANTS), n_slots=30
+                    ),
+                    scale=False,
+                ),
             ),
         ],
         flips=_trace_flips,
