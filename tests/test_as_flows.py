@@ -3,15 +3,23 @@
 Strategy mirrors upstream's global-routing and BRITE integration tests:
 generator structure, SPF-vs-oracle distance parity, end-to-end delivery
 parity against the packet-level scalar DES, overload direction, and the
-lift seam.
+lift seam; then the engine against the benchmark's plain reference replica
+by replica, the control and faults that reference has to catch, and the
+device names and span arguments (which change no equation and no bit).
 """
 
+import contextlib
+import copy
 import heapq
+import json
+import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.manifest import load_module
 from tpudes.core import Seconds, Simulator
 from tpudes.helper.topology import BriteTopologyHelper
 from tpudes.parallel.as_flows import (
@@ -370,3 +378,154 @@ def test_flow_endpoints_ride_the_seeded_stream_api():
     stdlib_random.seed(999)
     assert endpoints(seed=4) == a  # stdlib state is irrelevant
     assert endpoints(seed=5) != a  # but the seed argument is not
+
+
+# ------------------------------------------------- the benchmark's plain reference
+# `benchmark/references/as_flows.py` is the float64 numpy reference the cell `as.mc`
+# decides `correct` with: it generates the stock script's graph and flows again,
+# routes them on the hop metric and runs the fluid fixed point replica by replica
+# on the program's own draws.  Here, small and on the CPU.
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REF = load_module(os.path.join(ROOT, "benchmark", "references", "as_flows.py"))
+with open(os.path.join(ROOT, "benchmark", "configs", "brite-as-10k.json")) as f:
+    CONFIG = json.load(f)
+
+N_NODES, N_FLOWS, REPLICAS, SEED = 200, 16, 32, 3_000_000_019
+#: sparse (no link near its rate) and overfilled (a tenth of the flows lose
+#: packets on some link, so the gate, its compounding and the rounds matter)
+SPARSE_KBPS, OVERLOAD_KBPS = 400.0, 24000.0
+
+#: the largest relative error, engine against reference, replica by replica, and
+#: why this much: float32 against float64 reads up to 1.0e-6 in goodput (the
+#: program's float32 erfinv in its draw: 2e-5 in z where |z| nears 5, 0.3 of it
+#: in the rate), 1.3e-7 to 1.8e-7 in the other fields sparse, and 5.2e-6 in the
+#: delay of the overloaded deployment (a link at rho 0.99 scales its rounding by
+#: a hundred); the control and the faults read above 3.8e-3 in the number that
+#: tells them (FAULTS)
+TOLERANCE = {"goodput_bps": 1e-5, "delivered_frac": 1e-5, "delay_s": 3e-5,
+             "max_util": 1e-5}
+
+
+def _deployment(flow_kbps: float) -> dict:
+    cfg = copy.deepcopy(CONFIG)
+    cfg["topology"].update(n_nodes=N_NODES, n_flows=N_FLOWS)
+    cfg["physics"]["flow_kbps"] = flow_kbps
+    return cfg
+
+
+def _worst(got, want, mask=True) -> float:
+    got, want = np.asarray(got, float), np.asarray(want, float)
+    mask = np.broadcast_to(mask, got.shape)
+    return float(np.max(np.abs(got[mask] - want[mask]) / np.abs(want[mask])))
+
+
+@pytest.mark.parametrize("flow_kbps", [SPARSE_KBPS, OVERLOAD_KBPS],
+                         ids=["sparse", "overload"])
+def test_engine_matches_the_plain_reference_replica_by_replica(flow_kbps):
+    cfg = _deployment(flow_kbps)
+    build_as_network(N_NODES, N_FLOWS, 2.0, flow_kbps=flow_kbps)
+    prog = lower_as_flows(2.0)
+    topo = REF.topology(cfg)
+    # the reference builds the stock script's graph and flows by itself
+    for field in ("edges", "delay_s", "rate_bps", "src", "dst", "flow_bps"):
+        np.testing.assert_array_equal(getattr(prog, field), topo[field])
+    out = run_as_flows(prog, REF.launch_key(SEED, 0), replicas=REPLICAS)
+    want = REF.simulate(cfg, 2.0, REPLICAS, SEED)
+    np.testing.assert_array_equal(out["hops"], want["hops"])
+    np.testing.assert_array_equal(out["unreachable"], want["unreachable"])
+    assert not want["unreachable"].any()
+    for field, tolerance in TOLERANCE.items():
+        assert _worst(out[field], want[field]) < tolerance, field
+    frac = np.asarray(out["delivered_frac"])
+    assert (frac < 0.99).any() == (flow_kbps == OVERLOAD_KBPS)
+    numbers = REF.compare(cfg, {"reference_replicas": REPLICAS}, [out], REPLICAS, SEED)
+    assert numbers["rows_missing"] == numbers["hops_differ"] == 0
+    assert max(numbers[k] for k in ("goodput_gap", "delay_gap", "max_util_gap")) < 3e-5
+
+
+#: a fault of the reference, the deployment it shows in, the number that tells
+#: it and what it read here
+FAULTS = [
+    # the control: link loads held in bfloat16 between hops (0.0038)
+    (dict(precision="bfloat16"), SPARSE_KBPS, "max_util"),
+    # routes on propagation delay, not hops: 6 of 16 flows' paths change
+    (dict(metric="delay"), SPARSE_KBPS, "hops"),
+    # the relaxation stopped a round early, where links overfill (0.17)
+    (dict(rounds=3), OVERLOAD_KBPS, "goodput_bps"),
+]
+
+
+@pytest.mark.parametrize("fault,flow_kbps,field", FAULTS)
+def test_the_control_and_the_faults_fail_the_tolerance(fault, flow_kbps, field):
+    cfg = _deployment(flow_kbps)
+    sound = REF.simulate(cfg, 2.0, REPLICAS, SEED)
+    faulty = REF.simulate(cfg, 2.0, REPLICAS, SEED, **fault)
+    if field == "hops":
+        assert (faulty["hops"] != sound["hops"]).sum() >= 3
+    else:
+        assert _worst(faulty[field], sound[field]) > 3 * TOLERANCE[field]
+
+
+def _toy_run(n_replicas=2):
+    from tpudes.parallel.as_flows import _as_replica_draws, build_as_run
+    from tpudes.parallel.programs import toy_as_program
+
+    prog = toy_as_program(n_nodes=40, n_flows=4, spf_rounds=12)
+    E2, F = 2 * prog.edges.shape[0], len(prog.src)
+    args = (
+        (jnp.int32(0), jnp.zeros((n_replicas, E2 + 1), jnp.float32),
+         jnp.zeros((n_replicas, F), jnp.float32),
+         jnp.zeros((n_replicas, E2), jnp.float32)),
+        _as_replica_draws(prog, jax.random.PRNGKey(5), n_replicas),
+        jnp.float32(3.0), jnp.int32(4),
+    )
+    return jax.jit(build_as_run(prog, n_replicas)), args
+
+
+def test_the_three_scopes_are_in_the_lowered_text():
+    run, args = _toy_run()
+    text = run.lower(*args).as_text(debug_info=True)
+    step = "tpudes.as_flows.step"
+    assert f"{step}/tpudes.as_flows.load" in text
+    assert "tpudes.as_flows.spf" in text and "tpudes.as_flows.delay" in text
+    # the shortest paths and the delay sum run outside the loop body
+    assert f"{step}/tpudes.as_flows.spf" not in text
+    assert f"{step}/tpudes.as_flows.delay" not in text
+
+
+def test_the_launch_span_names_the_topology():
+    from tpudes.obs import spans
+    from tpudes.parallel.lift import run_lifted
+    from tpudes.parallel.programs import toy_as_program
+
+    prog = toy_as_program(n_nodes=40, n_flows=4, spf_rounds=12)
+    run_lifted("as_flows", prog, 8, jax.random.PRNGKey(1))
+    (launch,) = [s for s in spans.snapshot() if s.name == "launch"][-1:]
+    assert {k: launch.args[k] for k in (
+        "n_nodes", "n_edges", "n_flows", "n_dests", "fp_rounds")} == dict(
+        n_nodes=40, n_edges=prog.edges.shape[0], n_flows=4, n_dests=4, fp_rounds=4)
+
+
+def test_the_scopes_change_no_equation_and_no_bit(monkeypatch):
+    """The runner's jaxpr keeps the parent's equations (106 at its top, 220
+    walked, one `while` of 13), and a run traced with every scope taken out
+    gives the same bits."""
+    from tpudes.analysis.jaxpr.trace import walk_eqns
+    from tpudes.parallel import as_flows as af
+
+    (entry,) = af._trace_entries(af._trace_prog())
+    jaxpr = jax.make_jaxpr(entry.fn)(*entry.args).jaxpr
+    (loop,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    assert (len(jaxpr.eqns), len(list(walk_eqns(jaxpr))),
+            len(loop.params["body_jaxpr"].jaxpr.eqns)) == (106, 220, 13)
+
+    run, args = _toy_run()
+    scoped = jax.tree_util.tree_map(np.asarray, run(*args))
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    bare, _ = _toy_run()
+    assert "tpudes." not in bare.lower(*args).as_text(debug_info=True)
+    unscoped = jax.tree_util.tree_map(np.asarray, bare(*args))
+    for a, b in zip(jax.tree_util.tree_leaves(scoped),
+                    jax.tree_util.tree_leaves(unscoped)):
+        np.testing.assert_array_equal(a, b)

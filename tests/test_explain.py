@@ -152,6 +152,75 @@ def test_device_time_outside_the_loop_goes_to_its_program():
     assert table["busy_ms"] == pytest.approx((19 + 30) * 1e-3)
 
 
+AS = "jit(tpudes_as_flows_advance)/jit(main)"
+SPF, DELAY = AS + "/tpudes.as_flows.spf", AS + "/tpudes.as_flows.delay"
+AS_STEP = AS + "/while/body/tpudes.as_flows.step"
+
+
+def as_launch(t0: float) -> list:
+    """The device operations of one launch of a program with three outermost
+    whiles: a 20 us shortest-path scan of 4 rounds before the loop, the loop of
+    4 rounds of 10 us (each a 6 us load walk under `.load`, 3 us under `.step`),
+    a 5 us delay scan after it, and 2 us of output assembly under no scope."""
+    us = lambda t: t0 + t * US
+    ops = [("fusion.1", "jit(tpudes_as_flows_init)/normal", us(0), 4 * US),
+           ("while.3", SPF + "/while", us(10), 20 * US)]
+    ops += [("fusion.2", SPF + "/while/body/scatter", us(10 + 5 * i), 4 * US)
+            for i in range(4)]
+    ops.append(("while.8", AS + "/while", us(30), 40 * US))
+    for i in range(4):
+        t = 30 + 10 * i
+        ops += [("while.9", AS_STEP + "/tpudes.as_flows.load/while", us(t), 6 * US),
+                ("fusion.3", AS_STEP + "/tpudes.as_flows.load/while/body/scatter",
+                 us(t), 5 * US),
+                ("fusion.4", AS_STEP + "/log", us(t + 6), 3 * US)]
+    ops += [("while.10", DELAY + "/while", us(70), 5 * US),
+            ("fusion.5", DELAY + "/while/body/add", us(70), 4 * US),
+            ("fusion.6", AS + "/concatenate", us(75), 2 * US)]
+    return ops
+
+
+def test_outside_the_loop_by_scope_adds_up_to_it_by_program():
+    host, ops, modules = [], [], []
+    for k in range(2):
+        t0 = 1000 * US * (k + 1)
+        ops += as_launch(t0)
+        modules += [("jit_tpudes_as_flows_init(1)", t0, 5 * US),
+                    ("jit_tpudes_as_flows_advance(2)", t0 + 9 * US, 70 * US)]
+        host.append(("launch", t0 - 5 * US, 4 * US, {"kind": "as_flows"}))
+    lowered = {"tpudes.as_flows.step", "tpudes.as_flows.cond", "tpudes.as_flows.load",
+               "tpudes.as_flows.spf", "tpudes.as_flows.delay"}
+    events = dict(devices={PLANE: ops}, modules={PLANE: modules}, host=host,
+                  lowered={"tpudes_as_flows_advance": lowered})
+    table = explain.reduce(events)
+    assert table["withheld"] is None
+    # the loop is the while under `.step`; the other two are outside it
+    loop = table["loop"]
+    assert loop["iterations"] == 4 and loop["step_us"] == pytest.approx(10.0)
+    assert loop["scopes"]["tpudes.as_flows.load"]["us"] == pytest.approx(6.0)
+    assert loop["scopes"]["tpudes.as_flows.step"]["us"] == pytest.approx(3.0)
+    assert table["outside_loop_ms"] == {
+        "jit_tpudes_as_flows_init": pytest.approx(4e-3),
+        "jit_tpudes_as_flows_advance": pytest.approx(27e-3),
+    }
+    scopes = table["outside_scopes_ms"]
+    assert scopes == {
+        "tpudes.as_flows.spf": pytest.approx(20e-3),
+        "tpudes.as_flows.delay": pytest.approx(5e-3),
+        explain.NO_SCOPE: pytest.approx(6e-3),
+    }
+    assert sum(scopes.values()) == pytest.approx(sum(table["outside_loop_ms"].values()))
+    assert "tpudes.as_flows.spf" in explain.format_table(table)
+    # a scope outside the loop that the tree's lowered program does not name
+    lowered.discard("tpudes.as_flows.spf")
+    table = explain.reduce(events)
+    assert "stale" in table["withheld"] and "tpudes.as_flows.spf" in table["withheld"]
+    assert table["outside_scopes_ms"] is None and table["outside_loop_ms"] is None
+    # the BSS launch has one loop and no scope outside it
+    assert explain.reduce(hand_made())["outside_scopes_ms"] == {
+        explain.NO_SCOPE: pytest.approx(19e-3)}
+
+
 def test_a_gap_is_split_over_the_spans_by_overlap_not_by_its_middle():
     table = explain.reduce(hand_made())
     idle = table["idle_ms"]
@@ -210,13 +279,14 @@ def test_launch_carries_the_span_arguments_and_the_runtime_counts():
 
 def numbers(table) -> list:
     return [table[k] for k in
-            ("loop", "outside_loop_ms", "idle_ms", "wall_ms", "busy_ms")]
+            ("loop", "outside_loop_ms", "outside_scopes_ms", "idle_ms", "wall_ms",
+             "busy_ms")]
 
 
 def test_a_launch_of_another_thread_in_the_window_is_withheld():
     table = explain.reduce(hand_made(foreign=1))
     assert "other threads" in table["withheld"]
-    assert numbers(table) == [None] * 5 and table["idle_each_ms"] is None
+    assert numbers(table) == [None] * 6 and table["idle_each_ms"] is None
 
 
 def test_a_cut_trace_is_withheld_the_loops_event_lost():
@@ -227,7 +297,7 @@ def test_a_cut_trace_is_withheld_the_loops_event_lost():
     events["devices"][PLANE].remove(whiles[-1])
     table = explain.reduce(events)
     assert "cut" in table["withheld"] and "fusion.10" in table["withheld"]
-    assert numbers(table) == [None] * 5
+    assert numbers(table) == [None] * 6
     assert table["launch"]["kind"] == "bss" and table["launches"] == 2
 
 
@@ -239,7 +309,7 @@ def test_a_cut_trace_is_withheld_a_launch_without_a_loop():
     ]
     table = explain.reduce(events)
     assert "launch 2 of 2 has no loop" in table["withheld"]
-    assert numbers(table) == [None] * 5
+    assert numbers(table) == [None] * 6
 
 
 def test_stale_names_are_withheld_and_scopes_without_events_listed():
@@ -253,7 +323,7 @@ def test_stale_names_are_withheld_and_scopes_without_events_listed():
     lowered.discard("tpudes.bss.ampdu")
     table = explain.reduce(hand_made(lowered={"tpudes_bss_advance": lowered}))
     assert "stale" in table["withheld"] and "tpudes.bss.ampdu" in table["withheld"]
-    assert numbers(table) == [None] * 5
+    assert numbers(table) == [None] * 6
 
 
 def test_nothing_to_read_is_withheld_not_an_error():

@@ -18,8 +18,9 @@ clock.  This module turns one such trace into one table:
 - :func:`reduce` is pure on those lists (tier-1 tests it on the CPU):
   a loop step split by innermost ``tpudes.*`` scope, the ``while``'s
   own time, the copies, the device time outside the loop by program
-  name, every idle gap of the first device SPLIT over the innermost
-  ``tpudes:`` span the host was in, the ``launch`` span's arguments;
+  name and by innermost scope, every idle gap of the first device
+  SPLIT over the innermost ``tpudes:`` span the host was in, the
+  ``launch`` span's arguments;
   a trace that is cut, or whose names another tree wrote, yields
   ``withheld`` and no number;
 - :class:`session` takes the trace (a profiler window of its own, in
@@ -59,6 +60,8 @@ SCOPE_PREFIX = "tpudes."
 OUTSIDE = "_outside_every_span_"
 #: device time of an operation that no program's module event covers
 NO_PROGRAM = "_no_program_"
+#: device time outside the loop of an operation under no ``tpudes.*`` scope
+NO_SCOPE = "_no_scope_"
 #: where a session stops every launch's loop, and how often
 #: :func:`replay` launches.  Stopping the profiler costs about 30 us a
 #: device event on a v5e, so the slowest loop's reading (the dumbbell
@@ -204,6 +207,16 @@ def scopes_in(text: str) -> set[str]:
     return set(re.findall(r"(?<![\w.(])tpudes\.[A-Za-z0-9_.]*[A-Za-z0-9_]", text))
 
 
+def steps(tf_op: str) -> bool:
+    """Whether a ``tf_op`` path runs inside an engine's loop body: one of
+    its components is a ``tpudes.*`` scope whose last part is ``step``
+    (:func:`~tpudes.parallel.runtime.scoped_while_loop`)."""
+    return any(
+        part.startswith(SCOPE_PREFIX) and part.split(":", 1)[0].endswith(".step")
+        for part in tf_op.split("/")
+    )
+
+
 def program_name(module_event: str) -> str:
     """``jit_tpudes_bss_advance(1234)`` -> ``jit_tpudes_bss_advance``."""
     return re.sub(r"\(\d+\)$", "", module_event)
@@ -213,10 +226,10 @@ class _Loop:
     """One outermost ``while`` of a device plane, filled by :func:`_walk`:
     ``ops[name] = [self ns, events, tf_op, direct child]``."""
 
-    __slots__ = ("name", "start", "end", "own", "ops")
+    __slots__ = ("name", "tf_op", "start", "end", "own", "ops")
 
-    def __init__(self, name, start, end):
-        self.name, self.start, self.end = name, start, end
+    def __init__(self, name, tf_op, start, end):
+        self.name, self.tf_op, self.start, self.end = name, tf_op, start, end
         self.own = 0.0
         self.ops: dict[str, list] = {}
 
@@ -237,7 +250,7 @@ class _Loop:
 def _walk(ops: list):
     """One pass over a plane's ``XLA Ops`` events, which nest (a
     ``while`` spans its body's operations): ``(outer, loops)``, the
-    depth-0 operations as ``(start, end, name)`` and every outermost
+    depth-0 operations as ``(start, end, name, tf_op)`` and every outermost
     ``while`` as a :class:`_Loop` (a ``while`` inside one counts in it,
     as an operation).  An operation's self time is its duration less
     its children's, so a loop's parts sum to its duration."""
@@ -275,9 +288,9 @@ def _walk(ops: list):
                 end = parent[0]
             parent[1] += end - start
         else:
-            outer.append((start, end, name))
+            outer.append((start, end, name, tf_op))
         if loop is None and name.startswith("while"):
-            loop, loop_depth = _Loop(name, start, end), len(stack)
+            loop, loop_depth = _Loop(name, tf_op, start, end), len(stack)
             loops.append(loop)
         stack.append([end, 0.0, name, tf_op, start])
     while stack:
@@ -325,11 +338,18 @@ def _plane(plane: str, ops: list, runs: list, starts: list):
     outer, loops = _walk(ops)
     outer = [o for o in outer if o[0] >= starts[0]]
     loops = [lp for lp in loops if lp.start >= starts[0]]
+    # a program may run other outermost whiles beside its engine's loop
+    # (a shortest-path scan before it, a delay sum after it): where one
+    # holds the loop body's scope, that one is the loop, and the others
+    # are device time outside it
+    stepped = [lp for lp in loops
+               if any(steps(row[2]) for row in lp.ops.values())]
+    loops = stepped or loops
     if runs:
         # an outermost operation runs once a program run: more often,
         # and a loop's event was lost, its body reading as outermost
         often: dict[str, int] = {}
-        for _, _, name in outer:
+        for _, _, name, _ in outer:
             often[name] = often.get(name, 0) + 1
         name, n = max(often.items(), key=lambda kv: kv[1], default=("", 0))
         if n > len(runs):
@@ -370,11 +390,16 @@ def _plane(plane: str, ops: list, runs: list, starts: list):
         return next((p for p, a, b in runs if a <= t < b), NO_PROGRAM)
 
     outside: list[dict] = [{} for _ in starts]
-    for s, e, _ in outer:
-        row, program = outside[launch_of(s)], program_at(s)
-        row[program] = row.get(program, 0.0) + (e - s)
+    by_scope: list[dict] = [{} for _ in starts]
+    for s, e, _, tf_op in outer:
+        for rows, key in ((outside, program_at(s)),
+                          (by_scope, scope_of(tf_op) or NO_SCOPE)):
+            row = rows[launch_of(s)]
+            row[key] = row.get(key, 0.0) + (e - s)
     for lp in loops:
         outside[launch_of(lp.start)][program_at(lp.start)] -= lp.end - lp.start
+        by_scope[launch_of(lp.start)][scope_of(lp.tf_op) or NO_SCOPE] -= (
+            lp.end - lp.start)
     return dict(
         outer=outer, names=names, by_op=by_op, iterations=iterations,
         parts_us={k: v / iterations * 1e-3 for k, v in parts.items()},
@@ -384,6 +409,7 @@ def _plane(plane: str, ops: list, runs: list, starts: list):
             events_per_step=n_events / iterations,
         ),
         outside_ms={k: v * 1e-6 for k, v in _median(outside).items()},
+        outside_scopes_ms={k: v * 1e-6 for k, v in _median(by_scope).items()},
     )
 
 
@@ -395,7 +421,9 @@ def reduce(events: dict) -> dict:
     events, in sequence, less the first ``warm_up`` of them: a launch
     owns the trace from its start to the next one's.
 
-    - ``loop``: of the outermost ``while`` loops, per iteration, in us,
+    - ``loop``: of the outermost ``while`` loops (those whose operations
+      run under a loop body's ``tpudes.*.step`` scope, where one does:
+      :func:`steps`), per iteration, in us,
       over every launch's iterations and averaged over the device
       planes: ``step_us``; ``own_us`` (the ``while``'s duration less its
       children's); ``scopes[scope] = {"us", "ops"}``, self time and
@@ -409,6 +437,9 @@ def reduce(events: dict) -> dict:
       its consumer; None without the lowered text);
     - a launch, each number the MEDIAN over the launches:
       ``outside_loop_ms[program]``, device busy time outside the loop;
+      ``outside_scopes_ms[scope]``, the same time by the innermost
+      ``tpudes.*`` scope of each operation outside the loop (a ``while``
+      there whole, by its own scope), :data:`NO_SCOPE` for none;
       ``idle_ms[span]``, the first device's idle time, each gap split
       over the innermost ``tpudes:`` span open on the host;
       ``wall_ms``, ``busy_ms``, a launch's share of the trace and the
@@ -442,7 +473,8 @@ def reduce(events: dict) -> dict:
     if warm_up:
         launch["warm_up"] = warm_up
     table = dict(
-        withheld=None, loop=None, outside_loop_ms=None, idle_ms=None,
+        withheld=None, loop=None, outside_loop_ms=None,
+        outside_scopes_ms=None, idle_ms=None,
         idle_each_ms=None, wall_ms=None, busy_ms=None, launch=launch,
         launches=len(launches), devices=len(devices), took_s=None,
     )
@@ -476,11 +508,13 @@ def _fill(table: dict, events: dict, launches: list) -> None:
         for plane in sorted(devices)
     ]
     seen = {k for p in planes for k in p["parts_us"] if k.startswith(SCOPE_PREFIX)}
+    outside = {k for p in planes for k in p["outside_scopes_ms"]} - {NO_SCOPE}
     lowered = events.get("lowered")
     known = None if lowered is None else set().union(*lowered.values())
-    if known is not None and not seen <= known:
+    if known is not None and not seen | outside <= known:
         raise _Withheld(
-            f"the names are stale: device events carry {sorted(seen - known)}, "
+            "the names are stale: device events carry "
+            f"{sorted((seen | outside) - known)}, "
             "which this tree's lowered program does not name (another tree's "
             "executable, from the persistent compile cache?)"
         )
@@ -503,9 +537,10 @@ def _fill(table: dict, events: dict, launches: list) -> None:
             [name, scope, ns / iterations * 1e-3]
             for (name, scope), ns in sorted(by_op.items(), key=lambda kv: -kv[1])[:TOP]
         ],
-        no_event=None if known is None else sorted(known - seen),
+        no_event=None if known is None else sorted(known - seen - outside),
     )
     table["outside_loop_ms"] = _mean([p["outside_ms"] for p in planes])
+    table["outside_scopes_ms"] = _mean([p["outside_scopes_ms"] for p in planes])
 
     # the first device's gaps, launch by launch
     t_end = max(
@@ -514,7 +549,7 @@ def _fill(table: dict, events: dict, launches: list) -> None:
     )
     idle, busy, wall = [], [], []
     for r0, r1 in zip(starts, starts[1:] + [t_end]):
-        inside = sorted((s, e) for s, e, _ in planes[0]["outer"] if r0 <= s < r1)
+        inside = sorted((s, e) for s, e, _, _ in planes[0]["outer"] if r0 <= s < r1)
         busy.append(sum(e - s for s, e in inside))
         wall.append(r1 - r0)
         edges = [r0] + [t for s, e in inside for t in (s, min(e, r1))] + [r1]
@@ -559,6 +594,8 @@ def format_table(table: dict) -> str:
             for name, scope, us in loop["top"]]
     out.append("device busy outside the loop, a launch (ms):")
     out += [f"  {k:<44}{v:8.3f}" for k, v in table["outside_loop_ms"].items()]
+    out.append("the same time by scope (ms):")
+    out += [f"  {k:<44}{v:8.3f}" for k, v in table["outside_scopes_ms"].items()]
     out.append("first device idle, a launch, by the host's span (ms; medians "
                "over launches that idled " + " ".join(
                    f"{v:.3f}" for v in table["idle_each_ms"]) + "):")

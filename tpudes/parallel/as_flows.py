@@ -327,12 +327,16 @@ def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap2, lfrac_link):
         lg = lg + lfrac_link[:, e_h]
         return (lg, load), None
 
-    (lg, load), _ = jax.lax.scan(
-        walk,
-        (jnp.zeros((R, F), jnp.float32),
-         jnp.zeros((R, E2 + 1), jnp.float32)),
-        hs,
-    )
+    # the hop walk's scatter-add: the relaxation's bulk, named apart
+    # from the utilisation, log and gate below (they read under the
+    # loop body's own scope)
+    with jax.named_scope("tpudes.as_flows.load"):
+        (lg, load), _ = jax.lax.scan(
+            walk,
+            (jnp.zeros((R, F), jnp.float32),
+             jnp.zeros((R, E2 + 1), jnp.float32)),
+            hs,
+        )
     util = load[:, :E2] / cap2[None, :]
     hard = _fluid_pad(
         jnp.log(jnp.minimum(1.0, 1.0 / jnp.maximum(util, 1e-9)))
@@ -361,19 +365,20 @@ def _fluid_delay(prog: AsFlowsProgram, path, hs, util, cap2, dly2):
     both runners, like :func:`_fluid_round`)."""
     R = util.shape[0]
     F = path.shape[0]
-    rho = jnp.minimum(util, 0.99)
-    q_delay = (
-        rho / (1.0 - rho) * (8.0 * prog.pkt_bytes / cap2)[None, :]
-    )
-    serial = (8.0 * prog.pkt_bytes / cap2)[None, :]
-    ldel = _fluid_pad(q_delay + serial + dly2[None, :])
+    with jax.named_scope("tpudes.as_flows.delay"):
+        rho = jnp.minimum(util, 0.99)
+        q_delay = (
+            rho / (1.0 - rho) * (8.0 * prog.pkt_bytes / cap2)[None, :]
+        )
+        serial = (8.0 * prog.pkt_bytes / cap2)[None, :]
+        ldel = _fluid_pad(q_delay + serial + dly2[None, :])
 
-    def acc_hop(dl, h):
-        return dl + ldel[:, path[:, h]], None
+        def acc_hop(dl, h):
+            return dl + ldel[:, path[:, h]], None
 
-    dl, _ = jax.lax.scan(
-        acc_hop, jnp.zeros((R, F), jnp.float32), hs
-    )
+        dl, _ = jax.lax.scan(
+            acc_hop, jnp.zeros((R, F), jnp.float32), hs
+        )
     return dl
 
 #: result keys carrying a leading replica axis (sliced back after
@@ -477,11 +482,14 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
     hs = jnp.arange(H, dtype=jnp.int32)
 
     def topo():
-        ddst, dist, nh_edge, nh_node = device_spf(prog, mesh)
-        path, hops, arrived = _walk_paths(prog, ddst, nh_edge, nh_node)
-        reached = (
-            dist[ddst, jnp.asarray(prog.src)] < INF
-        ) & arrived
+        # Bellman-Ford, next hops and the path walk: replica-independent,
+        # outside the relaxation loop, under one device name
+        with jax.named_scope("tpudes.as_flows.spf"):
+            ddst, dist, nh_edge, nh_node = device_spf(prog, mesh)
+            path, hops, arrived = _walk_paths(prog, ddst, nh_edge, nh_node)
+            reached = (
+                dist[ddst, jnp.asarray(prog.src)] < INF
+            ) & arrived
         return path, hops, reached
 
     def relax(carry, z, scale, rounds_end, path, reached, mult):
@@ -682,11 +690,21 @@ def run_as_flows(
     bit-equal to uninterrupted.  ``block=False`` returns an
     :class:`~tpudes.parallel.runtime.EngineFuture`.
     """
+    from tpudes.obs import spans
     from tpudes.parallel.runtime import Launch, chunk_bounds, stack_axis
 
     n_cfg = None if rate_scale is None else len(rate_scale)
     L = Launch("as_flows", key, replicas, mesh, n_cfg)
     r_pad = L.r_pad
+    launch = spans.current()
+    if launch is not None and launch.name == "launch":
+        # the topology the SPF was compiled for (its rows: the distinct
+        # destinations) and the relaxation's round count
+        launch.args["n_nodes"] = int(prog.n)
+        launch.args["n_edges"] = int(prog.edges.shape[0])
+        launch.args["n_flows"] = int(len(prog.src))
+        launch.args["n_dests"] = int(len(np.unique(prog.dst)))
+        launch.args["fp_rounds"] = FP_ROUNDS
 
     def build():
         E2 = 2 * prog.edges.shape[0]
