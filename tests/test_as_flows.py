@@ -467,16 +467,105 @@ def test_the_control_and_the_faults_fail_the_tolerance(fault, flow_kbps, field):
         assert _worst(faulty[field], sound[field]) > 3 * TOLERANCE[field]
 
 
-def _toy_run(n_replicas=2):
-    from tpudes.parallel.as_flows import _as_replica_draws, build_as_run
+# ------------------------------------------------- the link table the relaxation runs over
+# The relaxation runs over min(F·H, 2E + 1) columns: fewer than the 2E + 1 ids where
+# F·H < 2E + 1, every link in use and the sentinel elsewhere; both hold the reference
+# at the cell's own limits.
+
+with open(os.path.join(ROOT, "benchmark", "limits", "as.mc.json")) as f:
+    LIMITS = json.load(f)["limits"]
+
+
+@pytest.mark.parametrize("n_nodes,n_flows,compact", [(40, 4, True), (24, 8, False)],
+                         ids=["links-in-use", "every-link"])
+def test_the_link_table_holds_the_reference_at_the_cells_limits(n_nodes, n_flows, compact):
+    from tpudes.obs import spans
+    from tpudes.parallel.as_flows import relax_links
+    from tpudes.parallel.lift import run_lifted
+
+    cfg = copy.deepcopy(CONFIG)
+    cfg["topology"].update(n_nodes=n_nodes, n_flows=n_flows)
+    build_as_network(n_nodes, n_flows, 2.0)
+    prog = lower_as_flows(2.0)
+    E2 = 2 * prog.edges.shape[0]
+    width = n_flows * prog.max_hops if compact else E2 + 1
+    assert relax_links(prog) == width and (width < E2 + 1) == compact
+    out = run_lifted("as_flows", prog, REPLICAS, REF.launch_key(SEED, 0))
+    launch = [s for s in spans.snapshot() if s.name == "launch"][-1]
+    assert launch.args["relax_links"] == width
+    numbers = REF.compare(cfg, {"reference_replicas": REPLICAS}, [out], REPLICAS, SEED)
+    assert {"rows_missing", "hops_differ", "goodput_gap", "max_util_gap"} <= set(numbers)
+    for name, value in numbers.items():
+        assert value <= LIMITS[name], name
+
+
+@pytest.mark.parametrize("gate_temp", [None, 4.0], ids=["hard-gate", "soft-gate"])
+def test_the_link_table_leaves_only_links_no_path_crosses(gate_temp):
+    """Every link's utilisation by the formula over all 2E links (the pad column the
+    done hops write into, the gate and FP_ROUNDS rounds, on the same draws) is the
+    compact table's where the table holds the link and 0 where it does not, so the
+    engine's max_util is the max over every link; and the done hops add nothing to a
+    flow's delivery, under the hard gate and under a soft one whose value at 0 is not 0."""
+    import dataclasses
+
+    from tpudes.diff import Surrogacy
+    from tpudes.parallel.as_flows import (
+        FP_ROUNDS, _as_carry, _as_replica_draws, _link_table, _walk_paths, build_as_run,
+        relax_links,
+    )
     from tpudes.parallel.programs import toy_as_program
 
     prog = toy_as_program(n_nodes=40, n_flows=4, spf_rounds=12)
-    E2, F = 2 * prog.edges.shape[0], len(prog.src)
+    if gate_temp is not None:
+        prog = dataclasses.replace(prog, surrogate=Surrogacy(gate_temp=gate_temp))
+    E2, R = 2 * prog.edges.shape[0], 4
+    assert relax_links(prog) < E2 + 1
+    z = _as_replica_draws(prog, jax.random.PRNGKey(9), R)
+    carry, out, _ = jax.jit(build_as_run(prog, R))(
+        (jnp.int32(0),) + _as_carry(prog, R), z, jnp.float32(400.0), jnp.int32(FP_ROUNDS))
+    ddst, dist, nh_edge, nh_node = device_spf(prog)
+    path, _, arrived = _walk_paths(prog, ddst, nh_edge, nh_node)
+    cap2 = np.tile(prog.rate_bps, 2).astype(np.float32)
+    _, cap, _ = _link_table(prog, path, jnp.asarray(cap2), jnp.zeros(E2, jnp.float32))
+    links = np.asarray(jnp.unique(path, size=relax_links(prog), fill_value=E2))
+
+    def gate(util):
+        if gate_temp is None:
+            return jnp.log(jnp.minimum(1.0, 1.0 / jnp.maximum(util, 1e-9)))
+        t = jnp.float32(gate_temp)
+        return -jax.nn.softplus(jnp.log(jnp.maximum(util, jnp.float32(1e-9))) / t) * t
+
+    rate = jnp.asarray(prog.flow_bps, jnp.float32) * 400.0 * jnp.exp(
+        prog.rate_jitter * z - 0.5 * prog.rate_jitter**2)
+    rate = jnp.where(arrived[None, :], rate, 0.0)
+    lfrac = jnp.zeros((R, E2 + 1), jnp.float32)
+    for _ in range(FP_ROUNDS):
+        load, lg = jnp.zeros((R, E2 + 1), jnp.float32), jnp.zeros_like(rate)
+        for h in range(prog.max_hops):
+            load = load.at[:, path[:, h]].add(rate * jnp.exp(lg))
+            lg = lg + lfrac[:, path[:, h]]
+        util = load[:, :E2] / cap2[None, :]
+        lfrac = jnp.concatenate([gate(util), jnp.zeros((R, 1))], 1)
+    util = np.asarray(util)
+    compact = np.asarray(carry[3])
+    live = np.isfinite(np.asarray(cap))
+    np.testing.assert_allclose(compact[:, live], util[:, links[live]], rtol=1e-6)
+    assert not compact[:, ~live].any()
+    gone = np.setdiff1d(np.arange(E2), links)
+    assert gone.size > E2 // 2 and not util[:, gone].any()
+    np.testing.assert_allclose(np.asarray(out["max_util"]), util.max(axis=1), rtol=1e-6)
+    frac = np.asarray(out["delivered_frac"])
+    assert (frac < 0.999).any()  # the gate gated
+    np.testing.assert_allclose(frac, np.exp(np.asarray(lg)), rtol=1e-6)
+
+
+def _toy_run(n_replicas=2):
+    from tpudes.parallel.as_flows import _as_carry, _as_replica_draws, build_as_run
+    from tpudes.parallel.programs import toy_as_program
+
+    prog = toy_as_program(n_nodes=40, n_flows=4, spf_rounds=12)
     args = (
-        (jnp.int32(0), jnp.zeros((n_replicas, E2 + 1), jnp.float32),
-         jnp.zeros((n_replicas, F), jnp.float32),
-         jnp.zeros((n_replicas, E2), jnp.float32)),
+        (jnp.int32(0),) + _as_carry(prog, n_replicas),
         _as_replica_draws(prog, jax.random.PRNGKey(5), n_replicas),
         jnp.float32(3.0), jnp.int32(4),
     )
@@ -508,9 +597,10 @@ def test_the_launch_span_names_the_topology():
 
 
 def test_the_scopes_change_no_equation_and_no_bit(monkeypatch):
-    """The runner's jaxpr keeps the parent's equations (106 at its top, 220
-    walked, one `while` of 13), and a run traced with every scope taken out
-    gives the same bits."""
+    """The runner's jaxpr holds the program's equations (131 at its top, 284
+    walked, one `while` of 14: the trace toy's F·H = 32 < 2E + 1, so the
+    link table is built with the paths), and a run traced with every scope
+    taken out gives the same bits."""
     from tpudes.analysis.jaxpr.trace import walk_eqns
     from tpudes.parallel import as_flows as af
 
@@ -518,7 +608,7 @@ def test_the_scopes_change_no_equation_and_no_bit(monkeypatch):
     jaxpr = jax.make_jaxpr(entry.fn)(*entry.args).jaxpr
     (loop,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
     assert (len(jaxpr.eqns), len(list(walk_eqns(jaxpr))),
-            len(loop.params["body_jaxpr"].jaxpr.eqns)) == (106, 220, 13)
+            len(loop.params["body_jaxpr"].jaxpr.eqns)) == (131, 284, 14)
 
     run, args = _toy_run()
     scoped = jax.tree_util.tree_map(np.asarray, run(*args))

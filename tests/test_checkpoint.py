@@ -289,3 +289,50 @@ def test_lte_base_carry_layout(layout, tmp_path):
     ckpt.save(old, 40, [20, 40, 60], (np.full((4,), t, np.int32), s))
     with pytest.raises(CheckpointError, match="fingerprint"):
         _lte(checkpoint=ckpt)
+
+
+#: the fingerprint ``_as``'s checkpoint carried while the relaxation's
+#: per-link carry leaves were always 2E + 1 wide (no layout tag)
+_AS_WIDE_CARRY_FINGERPRINT = (
+    "b1ddd3b9cbdaece3831be7cc15ad5aedc397dc8a9198dd52b40ffeaa2c7c04e6"
+)
+
+
+@pytest.mark.parametrize("layout", ["link-table", "wide"])
+def test_as_flows_carry_layout(layout, tmp_path):
+    """A file of the link-table layout (per-link leaves as wide as the
+    links the paths use) resumes bit-equal; a file written while they
+    were 2E + 1 wide is refused by the fingerprint, not loaded."""
+    import pickle
+
+    from tpudes.parallel.as_flows import as_prog_key, relax_links
+    from tpudes.parallel.checkpoint import checkpoint_ctx
+    from tpudes.parallel.programs import toy_as_program
+
+    ckpt = CarryCheckpoint(tmp_path / "layout.ckpt")
+    chaos.arm(ChaosSchedule([
+        ChaosEvent("checkpoint_kill", "checkpoint_save", nth=1),
+    ]))
+    with pytest.raises(ChaosInjected):
+        _as(checkpoint=ckpt)
+    chaos.disarm()
+    with open(ckpt.path, "rb") as f:
+        doc = pickle.load(f)
+    (i, lfrac, lg, util), out = doc["carry"]
+    prog = toy_as_program(n_nodes=64, n_flows=3)
+    E2 = 2 * prog.edges.shape[0]
+    assert int(i) == doc["bound"] == 2 and relax_links(prog) < E2 + 1
+    assert np.shape(lfrac) == np.shape(util) == (4, relax_links(prog))
+    if layout == "link-table":
+        _assert_equal(_as(checkpoint=ckpt), _as())
+        return
+    old = checkpoint_ctx(
+        ckpt, engine="as_flows", key=KEY, replicas=4, r_pad=4, n_cfg=None,
+        obs=False, axis=0, extra=as_prog_key(prog) + (None, None),
+    )
+    assert old.fingerprint == _AS_WIDE_CARRY_FINGERPRINT
+    wide = (i, np.zeros((4, E2 + 1), np.float32), lg,
+            np.zeros((4, E2), np.float32))
+    ckpt.save(old, 2, doc["bounds"], (wide, out))
+    with pytest.raises(CheckpointError, match="fingerprint"):
+        _as(checkpoint=ckpt)

@@ -127,15 +127,28 @@ class TestSurrogateExactness:
     def test_diff_runner_forward_bit_equal_to_engine(self):
         """The scan-based differentiable runner reproduces the
         production while-loop engine bit for bit (same fluid cores,
-        fixed FP_ROUNDS)."""
+        fixed FP_ROUNDS), here over the links the paths use
+        (F·H < 2E + 1)."""
+        self._forward_equal(_as_prog(), compact=True)
+
+    def test_diff_runner_forward_bit_equal_to_engine_over_every_link(self):
+        """The same where F·H ≥ 2E + 1 (few nodes, many flows): the
+        link table is 2E + 1 wide, every link in use and the sentinel."""
+        self._forward_equal(
+            toy_as_program(n_nodes=12, n_flows=8), compact=False
+        )
+
+    @staticmethod
+    def _forward_equal(prog, compact):
         from tpudes.parallel.as_flows import (
             _as_replica_draws,
             build_as_diff,
+            relax_links,
             run_as_flows,
         )
         from tpudes.parallel.runtime import bucket_replicas
 
-        prog = _as_prog()
+        assert (relax_links(prog) < 2 * len(prog.edges) + 1) == compact
         out = run_as_flows(prog, KEY, replicas=5)
         r_pad = bucket_replicas(5, None)
         diff_run = jax.jit(build_as_diff(prog, r_pad))

@@ -368,6 +368,15 @@ SPARSE_SITES: tuple = (
              "unique_indices=True upstream is a known follow-up",
     ),
     SparseSite(
+        site="as_flows.link_table",
+        engine="as_flows", entry="*/run",
+        primitive="gather", mode="clip",
+        provenance=("const", "operand"),
+        note="the compact link table's binary search (jnp.searchsorted "
+             "of the path ids into the sorted links in use) reads at "
+             "midpoints XLA clamps",
+    ),
+    SparseSite(
         site="diff.as_loss_tables",
         engine="diff", entry="*",
         primitive="gather", mode="promise_in_bounds",
@@ -389,6 +398,13 @@ SPARSE_SITES: tuple = (
         provenance=("const", "iota", "operand"),
         unique_indices=False,
         note="as_flows.relax_scatter through the loss wrapper",
+    ),
+    SparseSite(
+        site="diff.as_loss_link_table",
+        engine="diff", entry="*",
+        primitive="gather", mode="clip",
+        provenance=("const", "operand"),
+        note="as_flows.link_table through the loss wrapper",
     ),
     # -- device FlowMonitor packet rings (tpudes/obs/flowmon.py) ------
     # One site per engine: flow_ring_write's dynamic_update_slice at

@@ -298,19 +298,60 @@ def _walk_paths(prog: AsFlowsProgram, ddst, nh_edge, nh_node):
 FP_ROUNDS = 4
 
 
-def _fluid_pad(x):
-    """Append the sentinel column hop-index E2 writes into (the
-    done-hop landfill)."""
-    return jnp.concatenate(
-        [x, jnp.zeros((x.shape[0], 1), x.dtype)], axis=1
+def relax_links(prog: AsFlowsProgram) -> int:
+    """Static width W of the fluid relaxation's link table.  The (F, H)
+    path table holds at most F·H distinct ids (directed links and the
+    done-hop sentinel 2E), and there are 2E + 1 ids in all."""
+    return min(len(prog.src) * prog.max_hops, 2 * prog.edges.shape[0] + 1)
+
+
+def _link_table(prog: AsFlowsProgram, path, cap2, dly2):
+    """``(path, cap, dly)`` the fluid relaxation runs over, built once
+    with the paths (replica-independent).  The table holds the distinct
+    links the paths use, sorted, then the done-hop sentinel 2E and its
+    fill, :func:`relax_links` columns: ``path`` becomes column ids into
+    it and the per-link capacity and delay are gathered to its columns.
+    The sentinel's capacity is inf, so its utilisation reads 0 and it
+    adds no serialisation, no queueing and (sealed,
+    :func:`_fluid_seal`) no loss."""
+    E2 = cap2.shape[0]
+    # the F·H path entries sorted, each repeat turned into the sentinel
+    # and sorted again: the distinct links ascending, then 2E.  Where
+    # F·H >= 2E + 1 some entry repeats or is 2E (there are 2E links),
+    # so the first 2E + 1 hold every link in use and the sentinel
+    flat = jnp.sort(path.reshape(-1))
+    first = jnp.concatenate([jnp.ones((1,), bool), flat[1:] != flat[:-1]])
+    links = jnp.sort(jnp.where(first, flat, E2))[: relax_links(prog)]
+    cap = jnp.concatenate([cap2, jnp.full((1,), jnp.inf, jnp.float32)])
+    dly = jnp.concatenate([dly2, jnp.zeros((1,), jnp.float32)])
+    return jnp.searchsorted(links, path), cap[links], dly[links]
+
+
+def _fluid_seal(x, cap):
+    """A per-link quantity with the done-hop sentinel's column, and its
+    fill, 0 where ``cap`` is inf (a done hop adds no loss and no
+    delay)."""
+    return jnp.where(jnp.isinf(cap)[None, :], 0.0, x)
+
+
+def _as_carry(prog: AsFlowsProgram, r_pad: int):
+    """The relaxation's carry ``(lfrac, lg, util)``: per-link log
+    delivery over the link table, per-flow log delivery, per-link
+    utilisation over the link table."""
+    W, F = relax_links(prog), len(prog.src)
+    return (
+        jnp.zeros((r_pad, W), jnp.float32),
+        jnp.zeros((r_pad, F), jnp.float32),
+        jnp.zeros((r_pad, W), jnp.float32),
     )
 
 
-def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap2, lfrac_link):
+def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap, lfrac_link):
     """ONE fluid fixed-point round — the walk/load/delivery core shared
     by the while-loop runner (:func:`build_as_run`) and the
     differentiable scan runner (:func:`build_as_diff`), so the two can
-    never drift.  A link's load is the SURVIVING rate of each
+    never drift.  It runs over the link table of :func:`_link_table`
+    (``path``, ``cap``).  A link's load is the SURVIVING rate of each
     transiting flow at that hop (loss upstream attenuates load
     downstream).  ``prog.surrogate`` (None = the exact legacy
     min-gate, bit-identical trace) smooths the per-link delivery clip
@@ -318,7 +359,6 @@ def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap2, lfrac_link):
     straight-through (hard bit-exact forward) when ``surrogate.ste``.
     """
     R, F = rate.shape
-    E2 = cap2.shape[0]
 
     def walk(c, h):
         lg, load = c
@@ -334,12 +374,14 @@ def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap2, lfrac_link):
         (lg, load), _ = jax.lax.scan(
             walk,
             (jnp.zeros((R, F), jnp.float32),
-             jnp.zeros((R, E2 + 1), jnp.float32)),
+             jnp.zeros((R, lfrac_link.shape[1]), jnp.float32)),
             hs,
         )
-    util = load[:, :E2] / cap2[None, :]
-    hard = _fluid_pad(
-        jnp.log(jnp.minimum(1.0, 1.0 / jnp.maximum(util, 1e-9)))
+    # a baked capacity divisor compiles to a multiply by its
+    # reciprocal; the gathered one is written so, for the same bits
+    util = load * (1.0 / cap)[None, :]
+    hard = _fluid_seal(
+        jnp.log(jnp.minimum(1.0, 1.0 / jnp.maximum(util, 1e-9))), cap
     )
     sur = prog.surrogate
     if sur is None:
@@ -349,17 +391,18 @@ def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap2, lfrac_link):
         # is the softplus smoothing at gate_temp (dtypes pinned f32 —
         # JXL002)
         t = jnp.float32(sur.gate_temp)
-        soft = _fluid_pad(
+        soft = _fluid_seal(
             -jax.nn.softplus(
                 jnp.log(jnp.maximum(util, jnp.float32(1e-9))) / t
             )
-            * t
+            * t,
+            cap,
         )
         new_lfrac = sur.blend(hard, soft)
     return new_lfrac, lg, util
 
 
-def _fluid_delay(prog: AsFlowsProgram, path, hs, util, cap2, dly2):
+def _fluid_delay(prog: AsFlowsProgram, path, hs, util, cap, dly):
     """M/M/1 queue + serialization + propagation delay accumulated
     along each flow's path from the settled utilizations (shared by
     both runners, like :func:`_fluid_round`)."""
@@ -368,10 +411,10 @@ def _fluid_delay(prog: AsFlowsProgram, path, hs, util, cap2, dly2):
     with jax.named_scope("tpudes.as_flows.delay"):
         rho = jnp.minimum(util, 0.99)
         q_delay = (
-            rho / (1.0 - rho) * (8.0 * prog.pkt_bytes / cap2)[None, :]
+            rho / (1.0 - rho) * (8.0 * prog.pkt_bytes / cap)[None, :]
         )
-        serial = (8.0 * prog.pkt_bytes / cap2)[None, :]
-        ldel = _fluid_pad(q_delay + serial + dly2[None, :])
+        serial = (8.0 * prog.pkt_bytes / cap)[None, :]
+        ldel = _fluid_seal(q_delay + serial + dly[None, :], cap)
 
         def acc_hop(dl, h):
             return dl + ldel[:, path[:, h]], None
@@ -380,6 +423,13 @@ def _fluid_delay(prog: AsFlowsProgram, path, hs, util, cap2, dly2):
             acc_hop, jnp.zeros((R, F), jnp.float32), hs
         )
     return dl
+
+
+#: carry layout of the relaxation, part of its checkpoint fingerprint:
+#: the per-link leaves are as wide as :func:`relax_links`.  A file
+#: written while they were always 2E + 1 wide (no tag in its
+#: fingerprint) is refused as a different study, not loaded.
+_AS_CARRY_LAYOUT = "link-table"
 
 #: result keys carrying a leading replica axis (sliced back after
 #: bucket padding); hops/unreachable are per-flow statics
@@ -469,8 +519,6 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
         from tpudes.traffic.device import avg_mult
 
         mult_fn = avg_mult(prog.traffic)
-    E = prog.edges.shape[0]
-    E2 = 2 * E
     cap = jnp.concatenate(
         [jnp.asarray(prog.rate_bps), jnp.asarray(prog.rate_bps)]
     ).astype(jnp.float32)
@@ -482,17 +530,19 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
     hs = jnp.arange(H, dtype=jnp.int32)
 
     def topo():
-        # Bellman-Ford, next hops and the path walk: replica-independent,
-        # outside the relaxation loop, under one device name
+        # Bellman-Ford, next hops, the path walk and the link table:
+        # replica-independent, outside the relaxation loop, under one
+        # device name
         with jax.named_scope("tpudes.as_flows.spf"):
             ddst, dist, nh_edge, nh_node = device_spf(prog, mesh)
             path, hops, arrived = _walk_paths(prog, ddst, nh_edge, nh_node)
             reached = (
                 dist[ddst, jnp.asarray(prog.src)] < INF
             ) & arrived
-        return path, hops, reached
+            links = _link_table(prog, path, cap, dly)
+        return links, hops, reached
 
-    def relax(carry, z, scale, rounds_end, path, reached, mult):
+    def relax(carry, z, scale, rounds_end, links, reached, mult):
         # per-replica offered rates: lognormal jitter around the
         # scale-multiplied nominal (z enters sharded over the
         # mesh's replica axis — every (R, ...) array downstream
@@ -502,19 +552,20 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
             prog.rate_jitter * z - 0.5 * prog.rate_jitter**2
         )
         rate = jnp.where(reached[None, :], rate, 0.0)
+        path, cap_l, dly_l = links
 
         # fluid fixed point: the round/delay cores are module-level
         # (shared with the differentiable runner, see _fluid_round)
         def body(c):
             i, lf, _, _ = c
-            lf2, lg2, util2 = _fluid_round(prog, path, hs, rate, cap, lf)
+            lf2, lg2, util2 = _fluid_round(prog, path, hs, rate, cap_l, lf)
             return i + 1, lf2, lg2, util2
 
         i, lfrac, lg, util = scoped_while_loop(
             "as_flows", lambda c: c[0] < rounds_end, body, carry
         )
 
-        dl = _fluid_delay(prog, path, hs, util, cap, dly)
+        dl = _fluid_delay(prog, path, hs, util, cap_l, dly_l)
         frac = jnp.where(reached[None, :], jnp.exp(lg), 0.0)
         outputs = dict(
             goodput_bps=rate * frac,
@@ -528,7 +579,7 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
         return (i, lfrac, lg, util), outputs, metrics
 
     def run(carry, z, scale, rounds_end, tr=None, horizon_us=None):
-        path, hops, reached = topo()
+        links, hops, reached = topo()
         # the workload's fluid multiplier: realized/nominal offered
         # ratio over the traced horizon — config- and replica-
         # independent, computed once like the SPF tables
@@ -538,14 +589,14 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
         )
         if n_cfg is None:
             carry, outputs, metrics = relax(
-                carry, z, scale, rounds_end, path, reached, mult
+                carry, z, scale, rounds_end, links, reached, mult
             )
         else:
             # SPF + path walk are config-independent: computed once,
             # closed over by the vmapped fixed point
             carry, outputs, metrics = jax.vmap(
                 lambda c, s: relax(
-                    c, z, s, rounds_end, path, reached, mult
+                    c, z, s, rounds_end, links, reached, mult
                 )
             )(carry, scale)
         outputs["hops"] = hops
@@ -604,30 +655,24 @@ def build_as_diff(prog: AsFlowsProgram, r_pad: int):
             else jnp.ones((F,), jnp.float32)
         )
         cap2 = jnp.concatenate([cap_bps, cap_bps]).astype(jnp.float32)
+        path, cap_l, dly_l = _link_table(prog, path, cap2, dly)
         rate = fbps[None, :] * mult[None, :] * scale * jnp.exp(
             prog.rate_jitter * z - 0.5 * prog.rate_jitter**2
         )
         rate = jnp.where(reached[None, :], rate, 0.0)
-        E2 = cap2.shape[0]
-        F_ = rate.shape[1]
-        carry0 = (
-            jnp.zeros((r_pad, E2 + 1), jnp.float32),
-            jnp.zeros((r_pad, F_), jnp.float32),
-            jnp.zeros((r_pad, E2), jnp.float32),
-        )
 
         # carry (lfrac, lg, util) exactly like the while-loop runner's
         # carry tail, so the final values are the same buffers (a
         # stacked-ys slice would cost a ULP on the max reduction)
         def body(c, _):
             lf, _, _ = c
-            lf2, lg2, util2 = _fluid_round(prog, path, hs, rate, cap2, lf)
+            lf2, lg2, util2 = _fluid_round(prog, path, hs, rate, cap_l, lf)
             return (lf2, lg2, util2), None
 
         (_, lg, util), _ = jax.lax.scan(
-            body, carry0, None, length=FP_ROUNDS
+            body, _as_carry(prog, r_pad), None, length=FP_ROUNDS
         )
-        dl = _fluid_delay(prog, path, hs, util, cap2, dly)
+        dl = _fluid_delay(prog, path, hs, util, cap_l, dly_l)
         frac = jnp.where(reached[None, :], jnp.exp(lg), 0.0)
         return dict(
             goodput_bps=rate * frac,
@@ -705,18 +750,12 @@ def run_as_flows(
         launch.args["n_flows"] = int(len(prog.src))
         launch.args["n_dests"] = int(len(np.unique(prog.dst)))
         launch.args["fp_rounds"] = FP_ROUNDS
+        # the width of the relaxation's link table (2E + 1: every link)
+        launch.args["relax_links"] = relax_links(prog)
 
     def build():
-        E2 = 2 * prog.edges.shape[0]
-        F = len(prog.src)
-
         def init(key):
-            carry = (
-                jnp.int32(0),
-                jnp.zeros((r_pad, E2 + 1), jnp.float32),
-                jnp.zeros((r_pad, F), jnp.float32),
-                jnp.zeros((r_pad, E2), jnp.float32),
-            )
+            carry = (jnp.int32(0),) + _as_carry(prog, r_pad)
             return (
                 _as_replica_draws(prog, key, r_pad),
                 stack_axis(carry, n_cfg),
@@ -772,6 +811,7 @@ def run_as_flows(
             else tuple(float(v) for v in rate_scale),
             None if prog.traffic is None
             else prog.traffic.param_key() + (float(prog.sim_s),),
+            _AS_CARRY_LAYOUT,
         ),
         block=block,
     )
@@ -805,14 +845,7 @@ def _trace_entries(
     run = build_as_run(prog, _TRACE_R, obs=obs)
     key = jax.random.PRNGKey(0)
     z = _as_replica_draws(prog, key, _TRACE_R)
-    E2 = 2 * prog.edges.shape[0]
-    F = len(prog.src)
-    carry = (
-        jnp.int32(0),
-        jnp.zeros((_TRACE_R, E2 + 1), jnp.float32),
-        jnp.zeros((_TRACE_R, F), jnp.float32),
-        jnp.zeros((_TRACE_R, E2), jnp.float32),
-    )
+    carry = (jnp.int32(0),) + _as_carry(prog, _TRACE_R)
     tr = None if prog.traffic is None else prog.traffic.operands()
     horizon = None if prog.traffic is None else jnp.int32(1_000_000)
     traced = {"scale": 2, "rounds_end": 3}
@@ -836,11 +869,12 @@ def _trace_entries(
 
 
 def _scale_axes():
-    """JXL007 scale axes for the SPF fixed-point runner: edge tables
-    are (R, 2E) with E linear in the node count of the BA topology,
-    and flow-path tables are (F, 2E).  Both axes budget 1.0 — this is
-    the linear-in-topology counterpoint to the wired engine's dense
-    quadratic tables in the --cost report."""
+    """JXL007 scale axes for the SPF fixed-point runner: the SPF's
+    edge tables are (D, 2E) with E linear in the node count of the BA
+    topology, and the relaxation's link tables (R, min(F·H, 2E + 1)),
+    linear in the flows and at most in the nodes.  Both axes budget
+    1.0 — this is the linear-in-topology counterpoint to the wired
+    engine's dense quadratic tables in the --cost report."""
     from tpudes.analysis.jaxpr.spec import ScaleAxis
 
     from tpudes.parallel.programs import toy_as_program
