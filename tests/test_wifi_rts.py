@@ -41,20 +41,42 @@ def test_threshold_gates_small_frames():
     assert rts == 0
 
 
-def test_rts_protected_graph_refuses_the_replica_lowering():
-    from tpudes.parallel.replicated import UnliftableScenarioError, lower_bss
+def test_rts_protected_graph_lifts_and_runs():
+    """The graph the replica lowering used to refuse (every frame above the
+    threshold) lifts to the per-node arm of the BSS step and runs there: every
+    exchange, the AP's echoes too, goes RTS -> CTS -> DATA -> ACK, and the
+    counts are the unprotected legacy step's on this graph."""
+    import jax
+    import numpy as np
+
+    from tpudes.parallel.replicated import (
+        lower_bss,
+        per_node_medium,
+        run_replicated_bss,
+    )
 
     reset_world()
-    sta_devices, ap_device, clients, _ = build_bss(4, 1.0)
+    sta_devices, ap_device, clients, _ = build_bss(4, 2.0)
     for i in range(4):
         sta_devices.Get(i).GetMac().SetAttribute("RtsCtsThreshold", 0)
     ap_device.GetMac().SetAttribute("RtsCtsThreshold", 0)
-    import pytest
-
-    with pytest.raises(UnliftableScenarioError, match="RTS"):
-        lower_bss(
-            [sta_devices.Get(i) for i in range(4)], ap_device, clients, 1.0
-        )
+    prog = lower_bss(
+        [sta_devices.Get(i) for i in range(4)], ap_device, clients, 2.0
+    )
+    reset_world()
+    assert per_node_medium(prog) and prog.echo and prog.rts_threshold == 0
+    out = run_replicated_bss(prog, 16, jax.random.PRNGKey(5))
+    assert out["all_done"]
+    # 4 stations x 10 requests a second from 1 s; the one at 34 m sits 17 dB
+    # over the noise, under what 54 Mbit/s data needs (its RTS at the 24 Mbit/s
+    # control rate get through): its 10 are retried to the limit and dropped,
+    # as the legacy step drops them without RTS
+    srv = np.asarray(out["srv_rx"])
+    assert ((srv >= 30) & (srv <= 32)).all()
+    assert (np.asarray(out["cli_rx"]).sum(1) >= 27).all()
+    assert (np.asarray(out["drops"]) >= 8).all()
+    sent = np.asarray(out["tx_by"]).sum(1)
+    assert (np.asarray(out["tx_rts"]) >= sent).all() and (sent >= 130).all()
 
 
 def test_nav_defers_channel_access():

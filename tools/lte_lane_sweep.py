@@ -5,6 +5,13 @@ section 6, PR 31, and the numbers behind ``lte_sm.SM_KERNEL_MAX_LANES``).
 
     python3 tools/lte_lane_sweep.py [--lanes 1,2,4,...] [--sim-s 10]
         [--profile 1,8,64] [--out chiprun_out/lte_lane_sweep.json]
+        [--lowerings mosaic,xla]
+
+The replica sweep of ROADMAP A7 (PERF.md section 5) is the XLA lowering
+alone at the replica counts of a Monte-Carlo launch:
+
+    python3 tools/lte_lane_sweep.py --lowerings xla
+        --lanes 64,256,512,1024,2048,4096 --profile 64,256,512,1024,2048,4096
 
 The ``lte.mc`` program (``examples/lena-simple.py --nEnbs=7
 --uesPerCell=30`` through ``JaxSimulatorImpl``) is launched with
@@ -15,8 +22,11 @@ numpy on the host; the per-TTI time is the best wall over the TTIs.
 For the lane counts of ``--profile`` one more launch runs under
 ``jax.profiler`` and is reduced by the benchmark's own
 ``benchmark/trace.py`` (``step_us`` = the outermost ``while`` per TTI,
-and the device operations by self time).  Refuses to run without a
-TPU: a time from a CPU run says nothing about either lowering.  The
+and the device operations by self time).  Each lowering's row carries
+the device's ``peak_bytes_in_use`` after its launches (the peak so far
+in the process: lane counts in rising order read each one's own).
+``bit_equal`` compares the lowerings where both ran.  Refuses to run
+without a TPU: a time from a CPU run says nothing about either lowering.  The
 last stdout line is the JSON table.
 """
 
@@ -78,6 +88,7 @@ def main(argv=None) -> int:
     ap.add_argument("--sim-s", type=float, default=10.0)
     ap.add_argument("--profile-sim-s", type=float, default=2.0)
     ap.add_argument("--reps", type=int, default=3)
+    ap.add_argument("--lowerings", default="mosaic,xla")
     ap.add_argument("--out", default="chiprun_out/lte_lane_sweep.json")
     args = ap.parse_args(argv)
 
@@ -104,7 +115,8 @@ def main(argv=None) -> int:
     for lanes in (int(x) for x in args.lanes.split(",")):
         replicas = None if lanes == 1 else lanes
         row, bits = {"lanes": lanes}, {}
-        for name, flag in LOWERINGS.items():
+        for name in args.lowerings.split(","):
+            flag = LOWERINGS[name]
             with mock.patch.dict(os.environ, {"TPUDES_PALLAS": flag}):
                 t0 = time.monotonic()
                 run_lte_sm(dataclasses.replace(base, n_ttis=1), key, replicas)
@@ -119,6 +131,8 @@ def main(argv=None) -> int:
                     compiled=compiled_step_lowering(base, key, replicas),
                     compile_s=compile_s, walls_s=walls,
                     us_per_tti=min(walls) / n_ttis * 1e6,
+                    peak_bytes=int((device.memory_stats() or {}).get(
+                        "peak_bytes_in_use", 0)),
                 )
                 if lanes in to_profile:
                     cell["profile"] = profiled_launch(
@@ -128,7 +142,8 @@ def main(argv=None) -> int:
                     )
             row[name] = cell
             print(json.dumps({"lanes": lanes, name: cell}), flush=True)
-        row["bit_equal"] = bool(np.array_equal(bits["mosaic"], bits["xla"]))
+        if len(bits) == 2:
+            row["bit_equal"] = bool(np.array_equal(bits["mosaic"], bits["xla"]))
         rows.append(row)
     result = dict(
         device=device.device_kind, sim_s=args.sim_s, reps=args.reps,

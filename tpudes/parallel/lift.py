@@ -35,14 +35,16 @@ def _iter_nodes():
 
 
 def _discover_bss(sim_end_s: float):
-    """Find an infrastructure-BSS shape (one AP, N STAs, echo clients)
-    in the global object graph and lower it."""
+    """Find a BSS shape in the global object graph and lower it: an
+    infrastructure BSS (one AP, N STAs, echo clients), or an IBSS (ad
+    hoc stations, one of them a PacketSink's node, OnOff senders to
+    it on the others)."""
     from tpudes.models.applications import UdpEchoClient
     from tpudes.models.wifi.device import WifiNetDevice
-    from tpudes.models.wifi.mac import ApWifiMac, StaWifiMac
+    from tpudes.models.wifi.mac import AdhocWifiMac, ApWifiMac, StaWifiMac
     from tpudes.parallel.replicated import lower_bss
 
-    aps, stas, clients, stray_clients = [], [], [], 0
+    aps, stas, adhoc, clients, stray_clients = [], [], [], [], 0
     bss_nodes = set()
     for node in _iter_nodes():
         for d in range(node.GetNDevices()):
@@ -55,18 +57,24 @@ def _discover_bss(sim_end_s: float):
                 elif isinstance(mac, StaWifiMac):
                     stas.append(dev)
                     bss_nodes.add(node)
-    for node in _iter_nodes():
-        for a in range(node.GetNApplications()):
-            app = node.GetApplication(a)
-            if isinstance(app, UdpEchoClient):
-                if node in bss_nodes:
-                    clients.append(app)
-                else:
-                    stray_clients += 1
+                elif isinstance(mac, AdhocWifiMac):
+                    adhoc.append(dev)
+    if adhoc and not aps and not stas:
+        sink, senders, apps = _ibss_shape(adhoc)
+        stas, aps, clients = senders, [sink], apps
+    else:
+        for node in _iter_nodes():
+            for a in range(node.GetNApplications()):
+                app = node.GetApplication(a)
+                if isinstance(app, UdpEchoClient):
+                    if node in bss_nodes:
+                        clients.append(app)
+                    else:
+                        stray_clients += 1
     if len(aps) != 1 or not stas:
         raise UnliftableScenarioError(
             f"not an infrastructure BSS (found {len(aps)} APs, "
-            f"{len(stas)} STAs)"
+            f"{len(stas)} STAs) nor an IBSS with one PacketSink"
         )
     if stray_clients:
         # a client on a non-BSS node (mixed wired/wireless topology)
@@ -85,6 +93,70 @@ def _discover_bss(sim_end_s: float):
     return "bss", prog, lambda: None
 
 
+def _ibss_shape(adhoc):
+    """``(sink device, other devices, OnOff apps)`` of an IBSS whose
+    traffic is OnOff senders into one PacketSink; anything else on
+    these nodes (another app, a sender to elsewhere, a second sink, a
+    node outside the IBSS that sends) refuses.  A one-packet echo
+    client that starts before every sender is ARP warm-up (upstream's
+    wifi-hidden-terminal sends one to resolve ARP before its flows),
+    skipped as the lowering skips the ARP it resolves."""
+    from tpudes.models.applications import (
+        OnOffApplication,
+        PacketSink,
+        UdpEchoClient,
+    )
+    from tpudes.models.internet.ipv4 import Ipv4L3Protocol
+
+    ibss = {dev.GetNode(): dev for dev in adhoc}
+    if len(ibss) != len(adhoc):
+        raise UnliftableScenarioError("a node with two ad hoc devices")
+    sinks, senders, warm_up = [], [], []
+    for node in _iter_nodes():
+        for a in range(node.GetNApplications()):
+            app = node.GetApplication(a)
+            if isinstance(app, PacketSink) and node in ibss:
+                sinks.append(node)
+            elif isinstance(app, OnOffApplication) and node in ibss:
+                senders.append(app)
+            elif (
+                isinstance(app, UdpEchoClient) and node in ibss
+                and int(app.max_packets) == 1
+            ):
+                warm_up.append(app)
+            else:
+                raise UnliftableScenarioError(
+                    f"{type(app).__name__} on node {node.GetId()}: an IBSS "
+                    "lifts with OnOff senders into one PacketSink only"
+                )
+    first = min((a.start_time.ticks for a in senders), default=0)
+    if any(a.start_time.ticks >= first for a in warm_up):
+        raise UnliftableScenarioError(
+            "an echo client that starts with the senders is traffic, "
+            "not ARP warm-up"
+        )
+    if len(set(sinks)) != 1:
+        raise UnliftableScenarioError(
+            f"an IBSS needs one PacketSink node (found {len(set(sinks))})"
+        )
+    sink = sinks[0]
+    ipv4 = sink.GetObject(Ipv4L3Protocol)
+    own = {
+        a.GetLocal().addr
+        for iface in (ipv4.interfaces[1:] if ipv4 is not None else [])
+        for a in iface.addresses
+    }
+    for app in senders:
+        remote = getattr(app.remote, "GetIpv4", None)
+        if remote is None or remote().addr not in own:
+            raise UnliftableScenarioError(
+                f"OnOff sender on node {app.GetNode().GetId()} does not "
+                "send to the PacketSink's node"
+            )
+    others = [dev for node, dev in ibss.items() if node is not sink]
+    return ibss[sink], others, senders
+
+
 def _attach_bss_traffic(prog):
     """The ISSUE-14 one-flip seam: ``--JaxTrafficModel=<model>`` swaps
     the lowered BSS program's STA arrivals onto the device traffic
@@ -100,6 +172,13 @@ def _attach_bss_traffic(prog):
     model = str(GlobalValue.GetValue("JaxTrafficModel"))
     if model == "off":
         return prog
+    from tpudes.parallel.replicated import per_node_medium
+
+    if per_node_medium(prog):
+        raise UnliftableScenarioError(
+            f"JaxTrafficModel={model}: the traffic stage rides the "
+            "single-medium BSS step only"
+        )
     from tpudes.traffic import TrafficProgram
 
     seed = int(GlobalValue.GetValue("JaxTrafficSeed"))

@@ -22,8 +22,11 @@ vector update.
 
 Scope: the infrastructure-BSS scenario (BASELINE.json config #3) — AP +
 N STAs, DCF MAC, Yans PHY with log-distance loss, NIST error model, UDP
-echo traffic, beacons.  HT (802.11n) graphs lift too: QoS AC_BE AIFS,
-HT-mixed preamble timing, and A-MPDU aggregation under an established
+echo traffic, beacons — and the IBSS of upstream's wifi-hidden-terminal
+study: ad hoc stations under a matrix loss table, OnOff CBR senders into
+one PacketSink, RTS/CTS above the MAC's threshold.  HT (802.11n) graphs
+lift too: QoS AC_BE AIFS, HT-mixed preamble timing, and A-MPDU
+aggregation under an established
 BlockAck session (every data exchange becomes backlog-sized A-MPDU +
 compressed BA, per-MPDU decode at the subframe bit share — the
 phy._end_rx_ampdu model vectorized — and a retry count per MPDU, as
@@ -55,12 +58,20 @@ Timing model vs the scalar DES (all deviations are sub-slot or rare):
   first preamble and drops the second as rx-busy.  Mutual interference
   keeps both psr values tiny, so the optimistic bias is small (ADVICE
   r2 low — documented deviation).
-- carrier sense is a single per-replica ``busy_until`` scalar: every
-  node senses every transmission, so no hidden-node regime is
-  representable (use the scalar DES or RTS/CTS studies for spread
-  topologies; ``lower_bss`` rejects topologies wider than the mutual
-  sensing range for this reason — for a MOBILE program the guard is
-  held over the whole trajectory).
+- carrier sense: a program whose every pair senses each other, with no
+  RTS/CTS and an echoing AP, keeps the legacy step's single per-replica
+  ``busy_until`` (every node senses every transmission; its jaxpr is what
+  it was before the per-node arm).  A static program with hidden pairs,
+  RTS/CTS, a PacketSink receiver or OnOff senders compiles the per-node
+  arm (:func:`_build_medium_step`): busy time and NAV per (replica, node),
+  backoff that freezes only on what the node itself senses, the RTS ->
+  CTS -> DATA -> ACK exchange with CTS and ACK timeouts, and the
+  interference each receiver's frame collects from every frame that
+  overlaps it, through the NIST chunk success rate.  A MOBILE program
+  keeps the single medium: ``lower_bss`` refuses a trajectory that leaves
+  mutual sensing range at any point, and RTS/CTS, a sink or OnOff senders
+  under motion.  RTS before an A-MPDU (the host MAC never sends one) and
+  frame capture stay out of scope too.
 
 Mobility (ISSUE-10): non-static node motion rides the scan as traced
 operands (``tpudes.ops.mobility`` — closed-form const-velocity /
@@ -107,8 +118,8 @@ MODELED_WARMUP_S = 0.25
 
 #: the documented-faithful fuzz region (see :mod:`tpudes.fuzz`): radii
 #: keep every STA pair inside mutual sensing range at the default 54
-#: Mbps PHY (the lower_bss hidden-node guard), horizons stay past the
-#: ~1.25 s warm-up boundary so the skipped association/ARP transient is
+#: Mbps PHY (the single-medium step, and the mobile guard), horizons stay
+#: past the ~1.25 s warm-up boundary so the skipped association/ARP transient is
 #: second-order, and traffic is the UDP-echo shape the parity tests pin
 FUZZ_ENVELOPE = FuzzEnvelope(
     engine="bss",
@@ -199,6 +210,28 @@ class BssProgram:
     #: checkpoint stay bit-exact).  A matching cbr program is pinned
     #: bit-equal to ``traffic=None`` (the ``traffic_off`` fuzz pair).
     traffic: object = None
+    #: (N, N) pair loss in dB, transmitter by row, receiver by column,
+    #: read from a MatrixPropagationLossModel; None = the log-distance
+    #: formula over ``positions``.  Its pairs below rx sensitivity are
+    #: hidden pairs: they do not sense each other (the per-node arm)
+    loss_db: np.ndarray | None = None
+    #: a data frame whose on-air bytes exceed this is RTS/CTS-protected
+    #: (the MAC's RtsCtsThreshold; the A-MPDU arm never protects)
+    rts_threshold: int = 65535
+    #: node 0's role: True = an AP whose echo server answers every
+    #: request; False = a PacketSink that only acknowledges (an IBSS
+    #: receiver: no echo, and no beacon unless ``start_us[0]`` says so)
+    echo: bool = True
+    #: OnOff clients: (N,) on and off durations in µs of each node's
+    #: send cycle (a send at the start of every on period, then one a
+    #: ``interval_us`` while on); None = plain CBR
+    on_us: np.ndarray | None = None
+    off_us: np.ndarray | None = None
+    #: carrier sense of the per-node arm: "pairs" = a node senses the
+    #: transmissions that reach it at rx sensitivity; "shared" = every
+    #: node senses every transmission, the single medium of the legacy
+    #: step (the control the hidden-terminal benchmark cell names)
+    carrier_sense: str = "pairs"
 
     @property
     def n(self) -> int:
@@ -231,26 +264,50 @@ def lower_bss(
 ) -> BssProgram:
     """Lower a constructed BSS object graph to a replicated program.
 
+    ``ap_device`` is the receiving node's device and becomes node 0: an
+    AP (ApWifiMac: beacons, the echo server answering every request)
+    or, in an IBSS (AdhocWifiMac), the PacketSink's node, which only
+    acknowledges.  ``sta_devices`` are the other nodes' devices and
+    ``echo_clients`` their sending applications: UdpEchoClients, or
+    OnOffApplications with constant on and off times, all to node 0.
+
     Reads positions from each node's mobility model, PHY attributes
     (power, sensitivity, noise figure, bandwidth) from the AP's
-    YansWifiPhy, the *configured* propagation model from the channel,
-    the data mode from the devices' station manager (ConstantRate), and
-    traffic from the UdpEchoClient apps.  Anything the BssProgram cannot
+    YansWifiPhy, the *configured* propagation model from the channel
+    (one LogDistancePropagationLossModel, or one
+    MatrixPropagationLossModel read pair by pair), the data mode from
+    the devices' station manager (ConstantRate), the MAC's
+    RtsCtsThreshold, and traffic from the client apps.  Pairs that
+    cannot sense each other (hidden pairs), RTS/CTS, the sink role
+    and OnOff senders compile the per-node arm of the step
+    (:func:`per_node_medium`).  Anything the BssProgram cannot
     faithfully represent raises :class:`UnliftableScenarioError`.
 
     Non-static mobility models lift too (``tpudes.ops.mobility``):
     node motion becomes traced operands of the scan and the pairwise
     loss matrix is recomputed inside the kernel every ``geom_stride``
     event-loop steps.  ``TPUDES_DEVICE_GEOM=0`` restores the loud
-    refusal (host-DES fallback for moving graphs).
+    refusal (host-DES fallback for moving graphs).  A moving graph
+    keeps the single medium: every pair must sense each other over
+    the whole trajectory, with no RTS/CTS and an echoing AP.
     """
+    from tpudes.models.applications import OnOffApplication, UdpEchoClient
     from tpudes.models.mobility import (
         MobilityModel,
         UnliftableMobilityError,
         device_mobility_program,
     )
-    from tpudes.models.propagation import LogDistancePropagationLossModel
-    from tpudes.models.wifi.mac import FCS_SIZE, MAC_HEADER_SIZE, control_answer_mode
+    from tpudes.models.propagation import (
+        LogDistancePropagationLossModel,
+        MatrixPropagationLossModel,
+    )
+    from tpudes.models.wifi.mac import (
+        FCS_SIZE,
+        MAC_HEADER_SIZE,
+        AdhocWifiMac,
+        ApWifiMac,
+        control_answer_mode,
+    )
     from tpudes.models.wifi.rate_control import ConstantRateWifiManager
     from tpudes.ops.mobility import device_geom_enabled
 
@@ -268,17 +325,13 @@ def lower_bss(
 
     ap_node = ap_device.GetNode()
     nodes = [ap_node] + [d.GetNode() for d in sta_devices]
+    mobs = [n.GetObject(MobilityModel) for n in nodes]
     positions = np.array(
-        [
-            (lambda p: (p.x, p.y, p.z))(n.GetObject(MobilityModel).GetPosition())
-            for n in nodes
-        ],
+        [(lambda p: (p.x, p.y, p.z))(m.GetPosition()) for m in mobs],
         dtype=np.float32,
     )
     sim_end_us = int(sim_end_s * 1e6)
-    mobile = any(
-        not n.GetObject(MobilityModel).is_static for n in nodes
-    )
+    mobile = any(not m.is_static for m in mobs)
     mobility = None
     if mobile:
         if not device_geom_enabled():
@@ -293,17 +346,42 @@ def lower_bss(
 
     phy = ap_device.GetPhy()
     mac = ap_device.GetMac()
+    macs = [mac] + [d.GetMac() for d in sta_devices]
+    if isinstance(mac, ApWifiMac):
+        if any(isinstance(m, AdhocWifiMac) for m in macs):
+            raise UnliftableScenarioError(
+                "an AP among ad hoc stations: one BSS shape a program"
+            )
+    elif not (
+        isinstance(mac, AdhocWifiMac)
+        and all(isinstance(m, AdhocWifiMac) for m in macs)
+    ):
+        raise UnliftableScenarioError(
+            "node 0 must be an AP, or every node an ad hoc (IBSS) station"
+        )
 
     # --- configured physics (ADVICE r2 low: read, don't default) ---------
     channel = phy.GetChannel()
     loss = getattr(channel, "_loss", None)
-    if not isinstance(loss, LogDistancePropagationLossModel) or loss.GetNext() is not None:
+    loss_db = None
+    if isinstance(loss, MatrixPropagationLossModel) and loss.GetNext() is None:
+        if mobility is not None:
+            raise UnliftableScenarioError(
+                "a matrix loss table under motion: the moving geometry "
+                "is log-distance only"
+            )
+        loss_db = np.array(
+            [[0.0 if a is b else -float(loss.DoCalcRxPower(0.0, a, b))
+              for b in mobs] for a in mobs],
+            dtype=np.float64,
+        )
+    elif not isinstance(loss, LogDistancePropagationLossModel) or loss.GetNext() is not None:
         raise UnliftableScenarioError(
-            f"replica axis supports a single LogDistancePropagationLossModel; "
-            f"channel has {type(loss).__name__}"
+            f"replica axis supports a single LogDistancePropagationLossModel "
+            f"or MatrixPropagationLossModel; channel has {type(loss).__name__}"
             + (" with a chained next model" if loss is not None and loss.GetNext() else "")
         )
-    if abs(float(loss.reference_distance) - 1.0) > 1e-9:
+    elif abs(float(loss.reference_distance) - 1.0) > 1e-9:
         raise UnliftableScenarioError(
             f"replica axis assumes ReferenceDistance=1 m (got {loss.reference_distance})"
         )
@@ -320,39 +398,73 @@ def lower_bss(
             "adaptive rate control diverges per replica"
         )
     data_mode = sm.get_data_mode(None)
-    ampdu_sizes = {
-        int(getattr(dev.GetMac(), "max_ampdu_size", 0))
-        for dev in [ap_device] + list(sta_devices)
-    }
-    qos_flags = {
-        bool(getattr(dev.GetMac(), "qos_supported", False))
-        for dev in [ap_device] + list(sta_devices)
-    }
-    if len(ampdu_sizes) > 1 or len(qos_flags) > 1:
+    ampdu_sizes = {int(getattr(m, "max_ampdu_size", 0)) for m in macs}
+    qos_flags = {bool(getattr(m, "qos_supported", False)) for m in macs}
+    rts_thresholds = {int(getattr(m, "rts_cts_threshold", 65535)) for m in macs}
+    if len(ampdu_sizes) > 1 or len(qos_flags) > 1 or len(rts_thresholds) > 1:
         raise UnliftableScenarioError(
             f"mixed per-device MAC configs (MaxAmpduSize {sorted(ampdu_sizes)}, "
-            f"QosSupported {sorted(qos_flags)}) cannot ride one vector MAC model"
+            f"QosSupported {sorted(qos_flags)}, RtsCtsThreshold "
+            f"{sorted(rts_thresholds)}) cannot ride one vector MAC model"
         )
     max_ampdu_size = ampdu_sizes.pop()
     qos = qos_flags.pop()
+    rts_threshold = rts_thresholds.pop()
 
     n = len(nodes)
     start = np.full((n,), INF, dtype=np.int64)
     interval = np.full((n,), INF, dtype=np.int64)
     stop = np.full((n,), INF, dtype=np.int64)
-    payloads = set()
+    on_us = np.full((n,), INF, dtype=np.int64)
+    off_us = np.zeros((n,), dtype=np.int64)
+    payloads, kinds = set(), set()
     for app in echo_clients:
         idx = nodes.index(app.GetNode())
+        if idx == 0:
+            raise UnliftableScenarioError(
+                "the receiving node sends: the replica axis models "
+                "traffic into node 0 only"
+            )
         start[idx] = int(app.start_time.ticks // 1000)
-        interval[idx] = max(1, int(app.interval.ticks // 1000))
         stop[idx] = (
             int(app.stop_time.ticks // 1000) if app.stop_time.ticks > 0 else INF
         )
+        kinds.add(type(app))
+        if isinstance(app, UdpEchoClient):
+            interval[idx] = max(1, int(app.interval.ticks // 1000))
+        elif isinstance(app, OnOffApplication):
+            on_s, off_s = (_constant_seconds(v, name) for v, name in (
+                (app.on_time, "OnTime"), (app.off_time, "OffTime")))
+            if int(app.max_bytes) or on_s <= 0.0:
+                raise UnliftableScenarioError(
+                    "OnOffApplication with MaxBytes or no on time: "
+                    "not a periodic sender"
+                )
+            iv_ticks = int(app.data_rate.CalculateBytesTxTime(
+                int(app.packet_size)).ticks)
+            on_ticks = int(round(on_s * 1e9))
+            interval[idx] = max(1, iv_ticks // 1000)
+            # the host sends at every on period's start and one an
+            # interval while on (strictly before its end): that many
+            # sends a period, whatever the µs clock rounds
+            sends = -(-on_ticks // iv_ticks)
+            on_us[idx] = (sends - 1) * int(interval[idx]) + 1
+            off_us[idx] = int(round((on_s + off_s) * 1e6)) - on_us[idx]
+        else:
+            raise UnliftableScenarioError(
+                f"{type(app).__name__} is not a sender the replica axis models"
+            )
         payloads.add(int(app.packet_size))
     if len(payloads) > 1:
         raise UnliftableScenarioError(
             f"replica axis models one on-air frame size; clients use {sorted(payloads)}"
         )
+    if len(kinds) > 1:
+        raise UnliftableScenarioError(
+            "echo clients and OnOff senders in one graph: one traffic "
+            "shape a program"
+        )
+    echo = isinstance(mac, ApWifiMac) and kinds != {OnOffApplication}
     payload = payloads.pop() if payloads else 0
     # AP slot: beacons
     if getattr(mac, "enable_beaconing", False) and int(mac.beacon_interval_us) > 0:
@@ -373,18 +485,15 @@ def lower_bss(
             payload + 8 + 20 + 8 + MAC_HEADER_SIZE
         )
         max_mpdus = max(1, min(MAX_AMPDU_FRAMES, max_ampdu_size // subframe_bytes))
-    # the MAC protects strictly-larger frames (size > threshold);
-    # A-MPDU exchanges never go through the RTS path (host
-    # _on_access_granted aggregates before the NeedRts check)
-    if max_mpdus <= 1 and int(getattr(mac, "rts_cts_threshold", 65535)) < data_bytes:
-        raise UnliftableScenarioError(
-            "RTS/CTS protection engages at this frame size; the replica "
-            "axis models the basic DATA/ACK exchange only"
-        )
     beacon_bytes = 50 + MAC_HEADER_SIZE + FCS_SIZE
     ack_mode = control_answer_mode(data_mode)
+    on_off = OnOffApplication in kinds
 
     tx_power_dbm = float(phy.tx_power_start + phy.tx_gain)
+    log_distance = {} if loss_db is not None else dict(
+        path_loss_exponent=float(loss.exponent),
+        reference_loss_db=float(loss.reference_loss),
+    )
     prog = BssProgram(
         positions=positions,
         data_mode_idx=data_mode.index,
@@ -398,8 +507,7 @@ def lower_bss(
         mobility=mobility,
         geom_stride=int(geom_stride),
         tx_power_dbm=tx_power_dbm,
-        path_loss_exponent=float(loss.exponent),
-        reference_loss_db=float(loss.reference_loss),
+        **log_distance,
         noise_figure_db=float(phy.noise_figure),
         bandwidth_hz=float(phy.channel_width) * 1e6,
         rx_sensitivity_dbm=float(phy.rx_sensitivity),
@@ -408,13 +516,20 @@ def lower_bss(
         aifs_us=(SIFS + 3 * SLOT) if qos else DIFS,
         max_mpdus=max_mpdus,
         subframe_bytes=subframe_bytes,
+        loss_db=loss_db,
+        rts_threshold=rts_threshold,
+        echo=echo,
+        on_us=np.minimum(on_us, INF).astype(np.int32) if on_off else None,
+        off_us=np.minimum(off_us, INF).astype(np.int32) if on_off else None,
     )
+    if max_mpdus > 1 and hidden_pairs(prog):
+        raise UnliftableScenarioError(
+            "hidden pairs under A-MPDU aggregation: the per-node medium "
+            "runs the single-MPDU exchange only"
+        )
 
-    # --- mutual-sensing guard (documented carrier-sense deviation): the
-    # vector model has one busy_until per replica, so every node must be
-    # able to sense every other; a spread topology with hidden pairs
-    # would silently diverge from the scalar DES.  A mobile topology
-    # must satisfy the guard over its WHOLE trajectory, sampled on a
+    # --- a moving graph keeps the single medium: every node must be
+    # able to sense every other over the WHOLE trajectory, sampled on a
     # dense grid through the same closed-form kernel the scan traces.
     if mobility is not None:
         from tpudes.ops.mobility import (
@@ -423,6 +538,12 @@ def lower_bss(
             warn_geom_stride,
         )
 
+        if rts_protected(prog) or not prog.echo or prog.on_us is not None:
+            raise UnliftableScenarioError(
+                "a moving graph with RTS/CTS, a non-echoing receiver or "
+                "OnOff senders: the moving geometry rides the single-"
+                "medium step only — run the scalar DES"
+            )
         # sample density derived from the max speed so no excursion
         # can slip between samples by more than ~0.5 m of relative
         # displacement (bounded by 1025 samples); walks additionally
@@ -461,13 +582,19 @@ def lower_bss(
             "lower_bss", mobility, int(geom_stride),
             _bss_nominal_step_s(prog),
         )
-    elif not bool((_pairwise_rx_dbm(prog) >= prog.rx_sensitivity_dbm).all()):
-        raise UnliftableScenarioError(
-            "topology has node pairs below rx sensitivity (hidden-node "
-            "regime); the single-medium carrier-sense model cannot "
-            "represent it — run the scalar DES"
-        )
     return prog
+
+
+def _constant_seconds(variable, name: str) -> float:
+    """An OnOff app's on or off time, which the lowering needs constant."""
+    from tpudes.core.rng import ConstantRandomVariable
+
+    if not isinstance(variable, ConstantRandomVariable):
+        raise UnliftableScenarioError(
+            f"OnOffApplication {name} is {type(variable).__name__}: the "
+            "replica axis models constant on and off times only"
+        )
+    return float(variable.GetValue())
 
 
 def _walk_worst_case_ok(prog: BssProgram, mobility) -> bool:
@@ -515,7 +642,10 @@ def _pairwise_rx_dbm(prog: BssProgram) -> np.ndarray:
     """(N, N) tx→rx power (dBm) under the program's log-distance physics,
     float64; diagonal entries are the (never-used) self-pairs at d=1 m.
     Single source of truth for both the build_bss_step physics tables and
-    lower_bss's mutual-sensing guard."""
+    lower_bss's mutual-sensing guard.  A matrix loss table replaces the
+    formula."""
+    if prog.loss_db is not None:
+        return prog.tx_power_dbm - np.asarray(prog.loss_db, np.float64)
     pos = prog.positions.astype(np.float64)
     d = np.sqrt(((pos[:, None, :] - pos[None, :, :]) ** 2).sum(-1))
     np.fill_diagonal(d, 1.0)
@@ -539,7 +669,7 @@ def _total_offered_arrivals(prog: BssProgram) -> int:
     return total
 
 
-def _estimate_max_steps(prog: BssProgram) -> int:
+def _estimate_max_steps(prog: BssProgram, per_node: bool) -> int:
     # one arrival event + up to 1+RETRY_LIMIT tx events per frame, plus
     # same-instant arrival/tx splits; generous slack.  A traffic
     # program replaces the CBR count with the workload's own offered
@@ -556,7 +686,596 @@ def _estimate_max_steps(prog: BssProgram) -> int:
             total,
             int(np.ceil(offered_packets(prog.traffic, horizon).sum())),
         )
+    if per_node:
+        # (per_node_medium(prog), which the caller knows) an attempt is
+        # at most RTS, CTS, DATA and ACK, a start and an end each, or a
+        # frame, its end and a timeout
+        return int(total * (1 + 8 * (1 + RETRY_LIMIT)) * 1.5) + 64
     return int(total * (3 + RETRY_LIMIT) * 1.5) + 64
+
+
+def sense_mask(prog: BssProgram) -> np.ndarray:
+    """(N, N) bool: whether node j (column) senses node i's (row)
+    transmissions, i.e. receives them at rx sensitivity or above; the
+    diagonal is False.  Under ``carrier_sense="shared"`` every node
+    senses every other."""
+    n = prog.n
+    if prog.carrier_sense == "shared":
+        mask = np.ones((n, n), bool)
+    else:
+        mask = _pairwise_rx_dbm(prog) >= prog.rx_sensitivity_dbm
+    np.fill_diagonal(mask, False)
+    return mask
+
+
+def hidden_pairs(prog: BssProgram) -> int:
+    """Node pairs of which at least one cannot sense the other (under
+    the program's physics, whatever ``carrier_sense`` says)."""
+    rx = _pairwise_rx_dbm(prog) >= prog.rx_sensitivity_dbm
+    both = rx & rx.T
+    return int((~both)[np.triu_indices(prog.n, 1)].sum())
+
+
+def rts_protected(prog: BssProgram) -> bool:
+    """Whether every data frame goes through RTS/CTS (the host MAC
+    protects frames strictly larger than the threshold; the A-MPDU
+    exchange never does)."""
+    return prog.max_mpdus <= 1 and prog.data_bytes > int(prog.rts_threshold)
+
+
+#: per_node_medium's answer by program object: a launch asks it twice,
+#: and the pair table of a 65-node program costs some 0.3 ms of host
+_PER_NODE: dict = {}
+
+
+def per_node_medium(prog: BssProgram) -> bool:
+    """Whether the program needs the per-node arm of the step: hidden
+    pairs, RTS/CTS, a receiver that does not echo, OnOff senders, or
+    the shared-medium control.  Every other program (the AP + echo
+    shape in mutual sensing range) compiles the legacy step, equation
+    for equation what it was."""
+    import weakref
+
+    held = _PER_NODE.get(id(prog))
+    if held is not None and held[0]() is prog:
+        return held[1]
+    answer = prog.max_mpdus <= 1 and (
+        rts_protected(prog)
+        or not prog.echo
+        or prog.on_us is not None
+        or prog.carrier_sense != "pairs"
+        or hidden_pairs(prog) > 0
+    )
+    if len(_PER_NODE) > 256:
+        _PER_NODE.clear()
+    _PER_NODE[id(prog)] = (weakref.ref(prog), answer)
+    return answer
+
+
+#: result fields of the per-node arm beside the legacy ones (``queue``
+#: comes back as ``backlog``)
+MEDIUM_FIELDS = (
+    "flow_rx", "tx_by", "tx_rts", "cts_timeout", "rx_lost_hidden", "queue",
+)
+
+#: frame kinds on the air in the per-node arm (indices of its tables)
+F_RTS, F_CTS, F_DATA, F_ACK, F_BEACON = range(1, 6)
+#: a node's own exchange: contending; RTS sent, CTS awaited; CTS heard,
+#: DATA due a SIFS after it; DATA sent, ACK awaited
+P_IDLE, P_WAIT_CTS, P_DATA_DUE, P_WAIT_ACK = range(4)
+
+
+def _medium_frames(prog: BssProgram) -> dict:
+    """Airtimes, modes, bit rates and NAV fields of the per-node arm's
+    frame kinds (index 0 is unused), and the response timeouts, as the
+    host MAC times them (``mac._send_rts``, ``_send_cts``,
+    ``_response_timeout_s``): RTS 20 B, CTS and ACK 14 B at the control
+    answer rate, the beacon at 6 Mbps.  Only RTS and CTS carry a NAV
+    (the host MAC leaves a data frame's duration field at 0)."""
+    from tpudes.ops.wifi_error import ALL_MODES
+
+    data_mode = ALL_MODES[prog.data_mode_idx]
+    ctrl_mode = ALL_MODES[prog.ack_mode_idx]
+    beacon_mode = MODES_BY_NAME["OfdmRate6Mbps"]
+    dur = [
+        0, _ppdu_us(20, ctrl_mode), _ppdu_us(14, ctrl_mode),
+        _ppdu_us(prog.data_bytes, data_mode), _ppdu_us(14, ctrl_mode),
+        _ppdu_us(prog.beacon_bytes, beacon_mode),
+    ]
+    mode = [
+        ctrl_mode, ctrl_mode, ctrl_mode, data_mode, ctrl_mode, beacon_mode,
+    ]
+    nav_rts = 3 * SIFS + dur[F_CTS] + dur[F_DATA] + dur[F_ACK]
+    return dict(
+        dur=dur,
+        mode_idx=[m.index for m in mode],
+        rate_bps=[float(m.data_rate_bps) for m in mode],
+        nav=[0, nav_rts, max(nav_rts - SIFS - dur[F_CTS], 0), 0, 0, 0],
+        cts_timeout=dur[F_RTS] + SIFS + dur[F_CTS] + SLOT + 4,
+        ack_timeout=dur[F_DATA] + SIFS + dur[F_ACK] + SLOT + 4,
+    )
+
+
+def _build_medium_step(prog: BssProgram, replicas: int):
+    """``(init_state, pending, step_fn)`` of the per-node arm: every
+    node has its own medium.
+
+    Time advances event by event, as in the legacy step, but an event
+    is any of: an application arrival; a contention grant (AIFS plus
+    the backoff after the later of the node's physical busy time, its
+    NAV and its own hold); a frame's end; a scheduled SIFS send (CTS,
+    DATA after a CTS, ACK); a CTS or ACK timeout.  Arrivals take a
+    step of their own when they come first, as in the legacy step;
+    what is on the air is reckoned from ``t_air``, the last step that
+    was not an arrival step, so a frame that ends in the µs of an
+    arrival is still decoded in that µs.  Every other event at the
+    instant is handled in one step, in this
+    order: the interference chunk that just ended is charged to every
+    frame being received; frames that end are decoded or lost (a
+    coin against the product of their chunks' NIST success rates),
+    and what they mean is applied (RTS -> CTS due, CTS -> DATA due,
+    DATA -> delivered once and ACK due, ACK -> success; RTS and CTS
+    overheard set the NAV); timeouts fail the exchange (retry count,
+    CW doubling, drop past the retry limit); then the instant's
+    transmissions start, a node that is not transmitting and not
+    locked onto a frame locks onto the strongest that starts and
+    reaches it, and every node that senses a start is busy until its
+    end.  A contender whose idle start moves is credited with the
+    slots it counted (a zero-backoff grant cut short draws anew).
+
+    The MAC is the host's (``models/wifi/mac.py``): one retry count for
+    RTS and DATA failures alike, 7 retries, no EIFS, a queue with no
+    limit.  Departures from the host PHY, as in the legacy step: a 1 µs
+    clock, a node senses exactly what reaches it at rx sensitivity (no energy
+    threshold), two frames that start in the same µs at one receiver
+    lock it onto the stronger, and a frame already on the air when a
+    receiver frees up is interference only, as on the host."""
+    from tpudes.parallel.runtime import step_keys
+
+    n = prog.n
+    R = replicas
+    fr = _medium_frames(prog)
+    AIFS = int(prog.aifs_us)
+    RTS = rts_protected(prog)
+    ON_OFF = prog.on_us is not None
+
+    rx_dbm_np = _pairwise_rx_dbm(prog)
+    rx_w_np = 10.0 ** ((rx_dbm_np - 30.0) / 10.0)
+    np.fill_diagonal(rx_w_np, 0.0)
+    sense_np = sense_mask(prog)
+    rx_w = jnp.asarray(rx_w_np, jnp.float32)                 # (N tx, N rx)
+    # a frame reaches a receiver (can be locked onto) at sensitivity;
+    # physical carrier sense follows the program's sense mask
+    reach = jnp.asarray(
+        (rx_dbm_np >= prog.rx_sensitivity_dbm) & ~np.eye(n, dtype=bool)
+    )
+    sense = jnp.asarray(sense_np)
+    # the sender of frame i cannot sense node k (row i, column k)
+    unsensed = jnp.asarray(~sense_np.T & ~np.eye(n, dtype=bool))
+    noise_w = float(thermal_noise_w(prog.bandwidth_hz, prog.noise_figure_db))
+    modes = sorted(set(fr["mode_idx"][1:]))
+
+    def by_kind(table, kind, dtype=jnp.int32):
+        """``table[kind]`` for a per-kind table, as selects (no gather)"""
+        out = jnp.zeros(kind.shape, dtype)
+        for k in range(1, len(table)):
+            out = jnp.where(kind == k, jnp.asarray(table[k], dtype), out)
+        return out
+
+    def chunk_success(sinr, span_us, kind):
+        """NIST success of a frame's stretch of ``span_us`` at ``sinr``, at
+        its kind's mode: each mode's closed form, selected by kind"""
+        from tpudes.ops.wifi_error import ALL_MODES, chunk_success_rate
+
+        out = jnp.ones(sinr.shape, jnp.float32)
+        for idx in modes:
+            m = ALL_MODES[idx]
+            rate = jnp.float32(m.data_rate_bps * 1e-6)
+            ok = chunk_success_rate(sinr, rate * span_us, m.constellation,
+                                    m.rate_class)
+            of_mode = np.array([i == idx for i in fr["mode_idx"]])
+            out = jnp.where(by_kind(of_mode, kind, bool), ok, out)
+        return out
+
+    start0 = jnp.asarray(prog.start_us, jnp.int32)
+    interval = jnp.asarray(prog.interval_us, jnp.int32)
+    stop = jnp.asarray(prog.stop_us, jnp.int32)
+    if ON_OFF:
+        on = jnp.asarray(prog.on_us, jnp.int32)
+        cycle = jnp.asarray(
+            np.minimum(
+                prog.on_us.astype(np.int64) + prog.off_us.astype(np.int64),
+                INF,
+            ),
+            jnp.int32,
+        )
+    node = jnp.arange(n)
+    is_ap = node == 0
+    echo = bool(prog.echo)
+
+    def init_state():
+        zi = jnp.zeros((R, n), jnp.int32)
+        return dict(
+            t=jnp.zeros((R,), jnp.int32),
+            t_air=jnp.zeros((R,), jnp.int32),   # the air reckoned up to
+            next_arr=jnp.broadcast_to(start0, (R, n)).astype(jnp.int32),
+            queue=zi,                 # frames waiting at each sender
+            ap_pend=zi,               # echoes waiting at the AP, per STA
+            bcn_pend=jnp.zeros((R,), jnp.int32),
+            backoff=zi,
+            hold=zi,                  # own tx end / timeout: no contending before
+            immediate=jnp.zeros((R, n), bool),
+            cw=jnp.full((R, n), CW_MIN, jnp.int32),
+            retries=zi,
+            cs=zi,                    # physical carrier sense: busy until
+            nav=zi,                   # virtual carrier sense: busy until
+            phase=zi,                 # own exchange (P_*)
+            due=jnp.full((R, n), INF, jnp.int32),   # its timeout / DATA send
+            xdst=zi,                  # its destination
+            got=jnp.zeros((R, n), bool),            # its frame delivered once
+            resp_t=jnp.full((R, n), INF, jnp.int32),  # SIFS response due
+            resp_kind=zi,
+            resp_dst=zi,
+            tx_end=zi,                # own transmission: on the air until
+            tx_kind=zi,
+            tx_dst=zi,
+            tx_hid=jnp.zeros((R, n), bool),  # overlapped by an unsensed node
+            rx_src=jnp.full((R, n), -1, jnp.int32),  # frame locked onto
+            rx_p=jnp.ones((R, n), jnp.float32),      # its success so far
+            srv_rx=jnp.zeros((R,), jnp.int32),
+            cli_rx=zi,
+            flow_rx=zi,               # data frames of each sender delivered
+            tx_by=zi,                 # data frames each node sent
+            tx_data=jnp.zeros((R,), jnp.int32),
+            tx_rts=jnp.zeros((R,), jnp.int32),
+            cts_timeout=jnp.zeros((R,), jnp.int32),
+            rx_lost_hidden=jnp.zeros((R,), jnp.int32),
+            drops=jnp.zeros((R,), jnp.int32),
+            step=jnp.int32(0),
+        )
+
+    def has_frame(s):
+        sta = (s["queue"] > 0) & ~is_ap[None, :]
+        ap = is_ap[None, :] & (
+            (s["bcn_pend"] > 0)
+            | (jnp.sum(s["ap_pend"], axis=1, dtype=jnp.int32) > 0)
+        )[:, None]
+        return sta | ap
+
+    def idle_start(s):
+        return jnp.maximum(jnp.maximum(s["cs"], s["nav"]), s["hold"])
+
+    def event_times(s):
+        """(contention grant times, earliest other event) per replica."""
+        with jax.named_scope("tpudes.bss.sense"):
+            base = idle_start(s)
+            t = s["t"][:, None]
+            grant = jnp.where(
+                s["immediate"], jnp.maximum(t, base),
+                base + AIFS + s["backoff"] * SLOT,
+            )
+            grant = jnp.where(
+                has_frame(s) & (s["phase"] == P_IDLE),
+                jnp.maximum(grant, t), INF,
+            )
+            due = jnp.where(s["phase"] != P_IDLE, s["due"], INF)
+            ends = jnp.where(
+                s["tx_end"] > s["t_air"][:, None], s["tx_end"], INF
+            )
+            other = jnp.minimum(
+                jnp.minimum(grant, due), jnp.minimum(s["resp_t"], ends)
+            )
+            return grant, jnp.min(other, axis=1)
+
+    def pick(onehot, table):
+        """(R, N) rows of per-node ``table`` (R, N) selected by a (R, N,
+        N) one-hot over its last axis."""
+        return jnp.sum(jnp.where(onehot, table[:, None, :], 0), axis=2)
+
+    def step_fn(s, key, sim_end, geom=None, tr=None, tr_keys=None):
+        rkeys = step_keys("bss", key, s["step"], R)
+
+        def draw(kk):
+            k_back, k_coin = jax.random.split(kk)
+            return (
+                jax.random.uniform(k_back, (n,), jnp.float32),
+                jax.random.uniform(k_coin, (n,), jnp.float32),
+            )
+
+        u_back, u_coin = jax.vmap(draw)(rkeys)
+        t0 = s["t"]
+        grant_t, t_other = event_times(s)
+        ta = jnp.min(s["next_arr"], axis=1)
+        live = t0 < sim_end
+        now = jnp.where(live, jnp.minimum(ta, t_other), sim_end)
+        past_end = now >= sim_end
+        arrived = live & (ta <= t_other) & (ta < INF) & ~past_end
+        act = live & (t_other < ta) & (t_other < INF) & ~past_end
+        now_c = now[:, None]
+        act_c = act[:, None]
+        frame = has_frame(s)
+        base0 = idle_start(s)
+
+        # ---------- arrivals (a step of their own) ----------
+        is_arr = arrived[:, None] & (s["next_arr"] == now_c)
+        queue = s["queue"] + jnp.where(is_arr & ~is_ap[None, :], 1, 0)
+        bcn = s["bcn_pend"] + jnp.sum(
+            jnp.where(is_arr & is_ap[None, :], 1, 0), axis=1,
+            dtype=jnp.int32,
+        )
+        adv = s["next_arr"] + interval[None, :]
+        if ON_OFF:
+            # a send at every on period's start, then one an interval
+            # while on: past the on time the next send opens the next cycle
+            since = s["next_arr"] - start0[None, :]
+            opened = start0[None, :] + (since // cycle[None, :]) * cycle[None, :]
+            adv = jnp.where(
+                adv - opened >= on[None, :], opened + cycle[None, :], adv
+            )
+        adv = jnp.where(s["next_arr"] >= INF, INF, adv)
+        adv = jnp.where(adv >= stop[None, :], INF, adv)
+        next_arr = jnp.where(is_arr, adv, s["next_arr"])
+        frame_after = jnp.where(is_arr & ~is_ap[None, :], queue > 0, frame)
+        frame_after = jnp.where(
+            is_arr & is_ap[None, :],
+            ((bcn > 0) | (jnp.sum(s["ap_pend"], 1, dtype=jnp.int32) > 0))[
+                :, None
+            ],
+            frame_after,
+        )
+        became_hol = is_arr & ~frame & frame_after
+        imm = became_hol & (now_c >= base0 + AIFS)
+        drawn = (u_back * (s["cw"] + 1).astype(jnp.float32)).astype(jnp.int32)
+        backoff = jnp.where(became_hol & ~imm, drawn, s["backoff"])
+        immediate = jnp.where(became_hol, imm, s["immediate"])
+
+        # ---------- the instant's frames, responses and timeouts -------
+        t_air = s["t_air"]
+        on_air = s["tx_end"] > t_air[:, None]           # during (t_air, now]
+        src = s["rx_src"]
+        locked = src >= 0
+        src_1h = node[None, None, :] == src[:, :, None]  # (R, rx, tx)
+        with jax.named_scope("tpudes.bss.sense"):
+            # the chunk (t_air, now] at the interference it had: every other
+            # frame on the air that reaches nobody's decoder but adds
+            # its power (exact f32 sums of a few terms, no matmul)
+            pw = jnp.where(
+                on_air[:, :, None], rx_w[None], 0.0
+            )                                            # (R, tx, rx)
+            sig = jnp.sum(
+                jnp.where(src_1h, jnp.swapaxes(pw, 1, 2), 0.0), axis=2
+            )
+            interf = jnp.sum(pw, axis=1) - sig
+            kind_rx = pick(src_1h, s["tx_kind"])
+            span_us = (now - t_air).astype(jnp.float32)[:, None]
+            chunk = chunk_success(sig / (noise_w + interf), span_us, kind_rx)
+            rx_p = jnp.where(
+                act_c & locked & (span_us > 0), s["rx_p"] * chunk, s["rx_p"]
+            )
+            # a sender overlapped by a node it cannot sense
+            hid_now = jnp.any(
+                unsensed[None] & on_air[:, None, :], axis=2
+            )
+            tx_hid = s["tx_hid"] | (act_c & on_air & hid_now)
+
+        # frames that end now, decoded or lost at the node locked on them
+        ending = act_c & on_air & (s["tx_end"] == now_c)
+        resolve = locked & pick(src_1h, ending.astype(jnp.int32)).astype(bool)
+        ok = resolve & (u_coin < rx_p)
+        dst_rx = pick(src_1h, s["tx_dst"])
+        mine = ok & (dst_rx == node[None, :])
+        heard = ok & (dst_rx != node[None, :])
+        rx_src = jnp.where(resolve, -1, src)
+        # per sender: its frame ended and its destination decoded it
+        decoded_by = jnp.any(
+            (src_1h & mine[:, :, None]), axis=1
+        )                                                # (R, tx)
+        lost = ending & ~decoded_by & (
+            (s["tx_kind"] == F_RTS) | (s["tx_kind"] == F_DATA)
+        )
+        rx_lost_hidden = s["rx_lost_hidden"] + jnp.sum(
+            lost & tx_hid, axis=1, dtype=jnp.int32
+        )
+        tx_hid = jnp.where(ending, False, tx_hid)
+
+        resp_t, resp_kind, resp_dst = s["resp_t"], s["resp_kind"], s["resp_dst"]
+        phase, due, nav = s["phase"], s["due"], s["nav"]
+        if RTS:
+            with jax.named_scope("tpudes.bss.rts"):
+                # RTS for me: CTS a SIFS later; CTS for me: DATA a SIFS
+                # later; RTS or CTS overheard: the NAV
+                cts_due = mine & (kind_rx == F_RTS)
+                resp_t = jnp.where(cts_due, now_c + SIFS, resp_t)
+                resp_kind = jnp.where(cts_due, F_CTS, resp_kind)
+                resp_dst = jnp.where(cts_due, src, resp_dst)
+                cts_in = mine & (kind_rx == F_CTS) & (phase == P_WAIT_CTS)
+                phase = jnp.where(cts_in, P_DATA_DUE, phase)
+                due = jnp.where(cts_in, now_c + SIFS, due)
+                nav = jnp.maximum(
+                    nav,
+                    jnp.where(
+                        heard & ((kind_rx == F_RTS) | (kind_rx == F_CTS)),
+                        now_c + by_kind(fr["nav"], kind_rx), 0,
+                    ),
+                )
+        # DATA for me: delivered (once a frame: the sender's flag), ACK due
+        data_in = mine & (kind_rx == F_DATA)
+        resp_t = jnp.where(data_in, now_c + SIFS, resp_t)
+        resp_kind = jnp.where(data_in, F_ACK, resp_kind)
+        resp_dst = jnp.where(data_in, src, resp_dst)
+        delivered = jnp.any(src_1h & data_in[:, :, None], axis=1)  # (R, tx)
+        first = delivered & ~s["got"]
+        got = s["got"] | delivered
+        first_i = first.astype(jnp.int32)
+        srv_rx = s["srv_rx"] + jnp.sum(
+            jnp.where(is_ap[None, :], 0, first_i), axis=1, dtype=jnp.int32
+        )
+        xdst_1h = node[None, :] == s["xdst"][:, 0:1]     # the AP's destination
+        cli_rx = s["cli_rx"] + jnp.where(xdst_1h, first_i[:, 0:1], 0)
+        flow_rx = s["flow_rx"] + first_i
+        ap_pend = s["ap_pend"]
+        if echo:
+            ap_pend = ap_pend + jnp.where(is_ap[None, :], 0, first_i)
+
+        # outcomes of the own exchange: ACK heard, or a timeout
+        success = mine & (kind_rx == F_ACK) & (phase == P_WAIT_ACK)
+        timeout = act_c & (
+            (phase == P_WAIT_CTS) | (phase == P_WAIT_ACK)
+        ) & (due == now_c)
+        cts_timeout = s["cts_timeout"] + jnp.sum(
+            timeout & (phase == P_WAIT_CTS), axis=1, dtype=jnp.int32
+        )
+        exceeded = timeout & (s["retries"] + 1 > RETRY_LIMIT)
+        done = success | exceeded
+        done_i = done.astype(jnp.int32)
+        drops = s["drops"] + jnp.sum(exceeded, axis=1, dtype=jnp.int32)
+        queue = queue - jnp.where(is_ap[None, :], 0, done_i)
+        ap_pend = ap_pend - jnp.where(xdst_1h, done_i[:, 0:1], 0)
+        retries = jnp.where(
+            done, 0, s["retries"] + timeout.astype(jnp.int32)
+        )
+        cw = jnp.where(
+            done, CW_MIN,
+            jnp.where(timeout, jnp.minimum(2 * (s["cw"] + 1) - 1, CW_MAX),
+                      s["cw"]),
+        )
+        got = jnp.where(done, False, got)
+        ended = success | timeout
+        phase = jnp.where(ended, P_IDLE, phase)
+        redraw = (u_back * (cw + 1).astype(jnp.float32)).astype(jnp.int32)
+        backoff = jnp.where(ended, redraw, backoff)
+        immediate = jnp.where(ended, False, immediate)
+        hold = jnp.where(timeout, now_c, s["hold"])
+
+        # ---------- the instant's transmissions start ----------
+        respond = act_c & (resp_t == now_c)
+        data_go = act_c & (phase == P_DATA_DUE) & (due == now_c)
+        granted = (
+            act_c & frame & (s["phase"] == P_IDLE) & (grant_t == now_c)
+            & ~respond
+        )
+        beacon = granted & is_ap[None, :] & (s["bcn_pend"] > 0)[:, None]
+        echo_dst = jnp.argmax(s["ap_pend"] > 0, axis=1).astype(jnp.int32)
+        # the AP retries the frame it sent; STAs send to node 0
+        dst_new = jnp.where(
+            is_ap[None, :],
+            jnp.where(s["retries"][:, 0:1] > 0, s["xdst"][:, 0:1],
+                      echo_dst[:, None]),
+            0,
+        )
+        opener = F_RTS if RTS else F_DATA
+        kind_go = jnp.where(
+            respond, resp_kind,
+            jnp.where(data_go, F_DATA, jnp.where(beacon, F_BEACON, opener)),
+        )
+        go = respond | data_go | granted
+        dst_go = jnp.where(
+            respond, resp_dst, jnp.where(data_go, s["xdst"], dst_new)
+        )
+        end_go = now_c + by_kind(fr["dur"], kind_go)
+        tx_end = jnp.where(go, end_go, s["tx_end"])
+        tx_kind = jnp.where(go, kind_go, s["tx_kind"])
+        tx_dst = jnp.where(go, dst_go, s["tx_dst"])
+        resp_t = jnp.where(respond, INF, resp_t)
+        hold = jnp.where(go, jnp.maximum(hold, end_go), hold)
+        sends_data = go & (kind_go == F_DATA)
+        opens = granted & ~beacon
+        xdst = jnp.where(opens, dst_new, s["xdst"])
+        if RTS:
+            with jax.named_scope("tpudes.bss.rts"):
+                phase = jnp.where(opens, P_WAIT_CTS, phase)
+                due = jnp.where(opens, now_c + fr["cts_timeout"], due)
+        phase = jnp.where(sends_data, P_WAIT_ACK, phase)
+        due = jnp.where(sends_data, now_c + fr["ack_timeout"], due)
+        # a beacon needs no answer: done when it starts (the legacy step's
+        # accounting), the AP contends again after its airtime
+        bcn = bcn - jnp.sum(beacon, axis=1, dtype=jnp.int32)
+        cw = jnp.where(beacon, CW_MIN, cw)
+        backoff = jnp.where(beacon, redraw, backoff)
+        immediate = jnp.where(go, False, immediate)
+        tx_data = s["tx_data"] + jnp.sum(sends_data, axis=1, dtype=jnp.int32)
+        tx_by = s["tx_by"] + sends_data.astype(jnp.int32)
+        tx_rts = s["tx_rts"] + jnp.sum(
+            go & (kind_go == F_RTS), axis=1, dtype=jnp.int32
+        )
+        with jax.named_scope("tpudes.bss.sense"):
+            # a transmitter stops receiving; a free node locks onto the
+            # strongest frame that starts and reaches it
+            rx_src = jnp.where(go, -1, rx_src)
+            cand = go[:, :, None] & reach[None]          # (R, tx, rx)
+            power = jnp.where(cand, rx_w[None], -1.0)
+            strongest = jnp.argmax(power, axis=1).astype(jnp.int32)
+            lock = jnp.any(cand, axis=1) & (rx_src < 0) & ~go
+            rx_src = jnp.where(lock, strongest, rx_src)
+            rx_p = jnp.where(lock, 1.0, rx_p)
+            # every node that senses a start is busy until its end
+            cs = jnp.maximum(
+                s["cs"],
+                jnp.max(
+                    jnp.where(go[:, :, None] & sense[None],
+                              end_go[:, :, None], 0),
+                    axis=1,
+                ),
+            )
+            # a contender whose idle start moved is credited with the
+            # slots it counted; a zero-backoff grant cut short draws anew
+            base1 = jnp.maximum(jnp.maximum(cs, nav), hold)
+            waiting = act_c & frame & (phase == P_IDLE) & ~ended & ~go
+            moved = waiting & (base1 > base0)
+            counted = jnp.maximum((now_c - base0 - AIFS) // SLOT, 0)
+            backoff = jnp.where(
+                moved & ~immediate, jnp.maximum(backoff - counted, 0),
+                backoff,
+            )
+            backoff = jnp.where(moved & immediate, drawn, backoff)
+            immediate = jnp.where(moved, False, immediate)
+
+        return dict(
+            t=jnp.maximum(now, t0),
+            t_air=jnp.where(act, now, t_air),
+            next_arr=next_arr,
+            queue=jnp.maximum(queue, 0),
+            ap_pend=jnp.maximum(ap_pend, 0),
+            bcn_pend=jnp.maximum(bcn, 0),
+            backoff=backoff,
+            hold=hold,
+            immediate=immediate,
+            cw=cw,
+            retries=retries,
+            cs=cs,
+            nav=nav,
+            phase=phase,
+            due=due,
+            xdst=xdst,
+            got=got,
+            resp_t=resp_t,
+            resp_kind=resp_kind,
+            resp_dst=resp_dst,
+            tx_end=tx_end,
+            tx_kind=tx_kind,
+            tx_dst=tx_dst,
+            tx_hid=tx_hid,
+            rx_src=rx_src,
+            rx_p=rx_p,
+            srv_rx=srv_rx,
+            cli_rx=cli_rx,
+            flow_rx=flow_rx,
+            tx_by=tx_by,
+            tx_data=tx_data,
+            tx_rts=tx_rts,
+            cts_timeout=cts_timeout,
+            rx_lost_hidden=rx_lost_hidden,
+            drops=drops,
+            step=s["step"] + 1,
+        )
+
+    def pending(s, sim_end):
+        _, t_other = event_times(s)
+        ta = jnp.min(s["next_arr"], axis=1)
+        return (s["t"] < sim_end) & (jnp.minimum(ta, t_other) < sim_end)
+
+    step_fn.traffic_keys = None
+    return init_state, pending, step_fn
 
 
 def build_bss_step(
@@ -602,7 +1321,14 @@ def build_bss_step(
 
     ``obs=True`` (the ``TpudesObs`` knob) adds a cumulative per-replica
     retransmission counter to the carry; a disabled run compiles the
-    exact pre-obs program."""
+    exact pre-obs program.
+
+    A program with hidden pairs, RTS/CTS, a receiver that does not
+    echo or OnOff senders (:func:`per_node_medium`) compiles the
+    per-node arm instead (:func:`_build_medium_step`); every other
+    program compiles what follows, unchanged."""
+    if per_node_medium(prog):
+        return _build_medium_step(prog, replicas)
     n = prog.n
     R = replicas
     from tpudes.ops.wifi_error import ALL_MODES
@@ -1376,6 +2102,13 @@ def _bss_unpack(host: dict, replicas: int, obs: bool, prog=None) -> dict:
     if "tx_mpdus" in host:
         # aggregated exchanges only: MPDUs carried by the tx_data PPDUs
         result["tx_mpdus"] = host["tx_mpdus"][:R]
+    if "tx_rts" in host:
+        # the per-node arm's counters; ``queue`` is the backlog each
+        # sender still holds at the horizon
+        result.update({
+            ("backlog" if k == "queue" else k): host[k][:R]
+            for k in MEDIUM_FIELDS
+        })
     if obs:
         from tpudes.obs.flowmon import FM_KEYS
 
@@ -1476,6 +2209,16 @@ def run_replicated_bss(
       ``tx_data``  (R,)   data-frame transmission attempts
       ``drops``    (R,)   frames dropped at retry limit
       ``tx_mpdus`` (R,)   MPDUs those PPDUs carried (``max_mpdus > 1`` only)
+
+    and, from a program of the per-node arm (:func:`per_node_medium`):
+      ``flow_rx`` (R,N) data frames of each sender delivered (once each)
+      ``tx_by``   (R,N) data frames each node sent
+      ``tx_rts`` (R,)   RTS frames sent
+      ``cts_timeout`` (R,) RTS that no CTS answered in time
+      ``rx_lost_hidden`` (R,) RTS and DATA frames their destination did
+                        not decode while a node their sender cannot
+                        sense was on the air
+      ``backlog`` (R,N) frames each sender still holds at the horizon
       ``steps``    int    vector event-loop iterations executed
       ``all_done`` bool   every replica reached sim_end (sanity flag)
 
@@ -1535,9 +2278,10 @@ def run_replicated_bss(
             dataclasses.replace(prog, traffic=tp) for tp in traffic_sweep
         ]
     )
+    medium = per_node_medium(prog)
     if max_steps is None:
         max_steps = max(
-            _estimate_max_steps(dataclasses.replace(p, sim_end_us=v))
+            _estimate_max_steps(dataclasses.replace(p, sim_end_us=v), medium)
             for v in ends
             for p in sweep_progs
         )
@@ -1547,16 +2291,23 @@ def run_replicated_bss(
     # extra loop iterations the padding may cause cannot corrupt real
     # replicas
     L = Launch("bss", key, replicas, mesh, n_cfg)
-    if prog.max_mpdus > 1:
+    # the per-node arm keeps no FlowMonitor columns: TpudesObs leaves it
+    # the program it is
+    obs = L.obs and not medium
+    if prog.max_mpdus > 1 or medium:
         from tpudes.obs import spans
 
         launch = spans.current()
         if launch is not None and launch.name == "launch":
-            launch.args["max_mpdus"] = int(prog.max_mpdus)
+            if medium:
+                launch.args["hidden_pairs"] = hidden_pairs(prog)
+                launch.args["rts_threshold"] = int(prog.rts_threshold)
+            else:
+                launch.args["max_mpdus"] = int(prog.max_mpdus)
 
     def build():
         init_state, _, fn = build_bss_advance(
-            prog, L.r_pad, obs=L.obs, n_cfg=n_cfg,
+            prog, L.r_pad, obs=obs, n_cfg=n_cfg,
             geom_per_step=geom_per_step, sweep=sweep,
         )
         return (
@@ -1599,7 +2350,7 @@ def run_replicated_bss(
     # ONE executable); the runner is mesh-independent, sharding flows
     # from the init program's outputs
     L.prepare(
-        lambda: (_prog_cache_key(prog), L.r_pad, L.obs, n_cfg, mobile,
+        lambda: (_prog_cache_key(prog), L.r_pad, obs, n_cfg, mobile,
                  geom_per_step, sweep if n_cfg is not None else None),
         build, operands,
     )
@@ -1617,7 +2368,9 @@ def run_replicated_bss(
         names = ("srv_rx", "cli_rx", "tx_data", "drops", "step")
         if prog.max_mpdus > 1:
             names += ("tx_mpdus",)
-        if L.obs:
+        if medium:
+            names += MEDIUM_FIELDS
+        if obs:
             from tpudes.obs.flowmon import FM_KEYS
 
             names += ("retx",) + FM_KEYS
@@ -1637,7 +2390,7 @@ def run_replicated_bss(
         call,
         chunk_bounds(max_steps, chunk_steps or max_steps),
         fetch,
-        lambda host: _bss_unpack(host, replicas, L.obs, prog),
+        lambda host: _bss_unpack(host, replicas, obs, prog),
         once=record_geometry if mobile else None,
         checkpoint=checkpoint,
         identity=lambda: _prog_cache_key(prog) + (
@@ -1666,6 +2419,17 @@ def _trace_prog(**over):
 
     prog = toy_bss_program(n_sta=2, sim_end_us=20_000)
     return dataclasses.replace(prog, **over) if over else prog
+
+
+def _trace_hidden_prog():
+    """The canonical program with its two stations hidden from each other
+    behind a loss table and RTS/CTS on: the per-node arm."""
+    import dataclasses
+
+    loss = np.full((3, 3), 60.0)
+    loss[1, 2] = loss[2, 1] = 200.0
+    np.fill_diagonal(loss, 0.0)
+    return dataclasses.replace(_trace_prog(), loss_db=loss, rts_threshold=100)
 
 
 def _trace_entries(
@@ -1771,6 +2535,9 @@ def _trace_flips():
         # manifest's own flips pin that model/param flips inside the
         # family stay compile-free
         "traffic": flip(traffic=_flip_traffic()),
+        # RTS/CTS and the shared-medium control compile the per-node arm
+        "rts_threshold": flip(rts_threshold=100),
+        "carrier_sense": flip(carrier_sense="shared"),
         # excluded-by-design fields must leave every trace identical:
         # the horizon is a traced operand (one executable per program
         # across every sim_end / step budget)
@@ -1795,6 +2562,10 @@ def trace_manifest():
             # must pass the registered SparseSite contract (JXL008)
             TraceVariant(
                 "obs", lambda: _trace_entries(_trace_prog(), obs=True)
+            ),
+            # the per-node arm: hidden pairs, RTS/CTS, NAV
+            TraceVariant(
+                "hidden", lambda: _trace_entries(_trace_hidden_prog(), scale=False)
             ),
         ],
         flips=_trace_flips,
