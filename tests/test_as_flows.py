@@ -10,6 +10,7 @@ device names and span arguments (which change no equation and no bit).
 
 import contextlib
 import copy
+import dataclasses
 import heapq
 import json
 import os
@@ -506,8 +507,6 @@ def test_the_link_table_leaves_only_links_no_path_crosses(gate_temp):
     compact table's where the table holds the link and 0 where it does not, so the
     engine's max_util is the max over every link; and the done hops add nothing to a
     flow's delivery, under the hard gate and under a soft one whose value at 0 is not 0."""
-    import dataclasses
-
     from tpudes.diff import Surrogacy
     from tpudes.parallel.as_flows import (
         FP_ROUNDS, _as_carry, _as_replica_draws, _link_table, _walk_paths, build_as_run,
@@ -597,18 +596,22 @@ def test_the_launch_span_names_the_topology():
 
 
 def test_the_scopes_change_no_equation_and_no_bit(monkeypatch):
-    """The runner's jaxpr holds the program's equations (131 at its top, 284
-    walked, one `while` of 14: the trace toy's F·H = 32 < 2E + 1, so the
-    link table is built with the paths), and a run traced with every scope
-    taken out gives the same bits."""
+    """The runner's jaxpr holds the program's equations (132 at its top, 291
+    walked, two `while`s at its top: the relaxation's of 14, which holds
+    the load walk's, and the delay sum's; the trace toy's F·H = 32 < 2E + 1,
+    so the link table is built with the paths), and a run traced with every
+    scope taken out gives the same bits."""
     from tpudes.analysis.jaxpr.trace import walk_eqns
     from tpudes.parallel import as_flows as af
 
     (entry,) = af._trace_entries(af._trace_prog())
     jaxpr = jax.make_jaxpr(entry.fn)(*entry.args).jaxpr
-    (loop,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    loops = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+    assert len(loops) == 2
+    (loop,) = [e for e in loops if any(
+        q.primitive.name == "while" for q in e.params["body_jaxpr"].jaxpr.eqns)]
     assert (len(jaxpr.eqns), len(list(walk_eqns(jaxpr))),
-            len(loop.params["body_jaxpr"].jaxpr.eqns)) == (131, 284, 14)
+            len(loop.params["body_jaxpr"].jaxpr.eqns)) == (132, 291, 14)
 
     run, args = _toy_run()
     scoped = jax.tree_util.tree_map(np.asarray, run(*args))
@@ -619,3 +622,147 @@ def test_the_scopes_change_no_equation_and_no_bit(monkeypatch):
     for a, b in zip(jax.tree_util.tree_leaves(scoped),
                     jax.tree_util.tree_leaves(unscoped)):
         np.testing.assert_array_equal(a, b)
+
+
+# ------------------------------------------------- the hop walks stop at the longest path
+# The relaxation's load walk and the delay sum run max(hops) hops, not max_hops: every
+# hop past the longest path is the done-hop sentinel in every row, which adds +0.0.
+
+
+def _line_prog(n, max_hops):
+    """A line graph 0-1-...-(n-1) whose one flow crosses it end to end, two
+    flows over the 10 Mbps links so the gate gates."""
+    edges = np.stack([np.arange(n - 1), np.arange(1, n)], axis=1).astype(np.int32)
+    return AsFlowsProgram(
+        n=n, edges=edges, delay_s=np.full(n - 1, 1e-3), rate_bps=np.full(n - 1, 10e6),
+        src=np.array([0, 1], np.int32), dst=np.array([n - 1, n - 2], np.int32),
+        flow_bps=np.array([8e6, 6e6]), pkt_bytes=512, sim_s=1.0,
+        max_hops=max_hops, spf_rounds=2 * n, rate_jitter=0.3,
+    )
+
+
+def _split_prog(wander):
+    """Components 0-1-2-3, 4-5 and the lone node 6, and an unreachable flow:
+    6 -> 0, which has no first hop, or 0 -> 5, which wanders its component
+    for all max_hops hops (a next hop toward an unreachable destination is
+    any incident link), so the walk then runs its whole bound."""
+    edges = np.array([[0, 1], [1, 2], [2, 3], [4, 5]], np.int32)
+    return AsFlowsProgram(
+        n=7, edges=edges, delay_s=np.full(4, 1e-3), rate_bps=np.full(4, 10e6),
+        src=np.array([0, 0 if wander else 6, 4], np.int32),
+        dst=np.array([3, 5 if wander else 0, 5], np.int32),
+        flow_bps=np.array([12e6, 5e6, 3e6]), pkt_bytes=512, sim_s=1.0,
+        max_hops=8, spf_rounds=12, rate_jitter=0.3,
+    )
+
+
+def _walk_toys():
+    from tpudes.parallel.programs import toy_as_program
+
+    ba = toy_as_program(n_nodes=200, n_flows=16, spf_rounds=24)
+    return {
+        "ba-200": dataclasses.replace(ba, flow_bps=np.full(16, 3e7)),
+        "line-at-max-hops": _line_prog(7, 6),
+        "unreachable": _split_prog(wander=False),
+        "unreachable-wanders": _split_prog(wander=True),
+    }
+
+
+def _walk_tables(prog):
+    from tpudes.parallel.as_flows import _walk_paths
+
+    ddst, dist, nh_edge, nh_node = device_spf(prog)
+    path, hops, arrived = _walk_paths(prog, ddst, nh_edge, nh_node)
+    reached = (dist[ddst, jnp.asarray(prog.src)] < 1e30) & arrived
+    return path, hops, reached
+
+
+WALK_TOYS = ["ba-200", "line-at-max-hops", "unreachable", "unreachable-wanders"]
+
+
+@pytest.mark.parametrize("toy", WALK_TOYS)
+def test_the_walks_cut_at_the_longest_path_change_no_bit(toy):
+    from tpudes.parallel.as_flows import (
+        FP_ROUNDS, _as_carry, _as_replica_draws, _fluid_delay, _fluid_round, _link_table,
+        _walk_trip,
+    )
+
+    prog = _walk_toys()[toy]
+    R = 16
+    path, hops, reached = _walk_tables(prog)
+    n_walk = _walk_trip(hops)
+    cap2 = jnp.asarray(np.tile(prog.rate_bps, 2), jnp.float32)
+    dly2 = jnp.asarray(np.tile(prog.delay_s, 2), jnp.float32)
+    lpath, cap, dly = _link_table(prog, path, cap2, dly2)
+    z = _as_replica_draws(prog, jax.random.PRNGKey(3), R)
+    rate = jnp.asarray(prog.flow_bps, jnp.float32) * jnp.exp(
+        prog.rate_jitter * z - 0.5 * prog.rate_jitter**2)
+    rate = jnp.where(reached[None, :], rate, 0.0)
+
+    @jax.jit
+    def relax(n):
+        lf, leaves = _as_carry(prog, R)[0], []
+        for _ in range(FP_ROUNDS):
+            lf, lg, util = _fluid_round(prog, lpath, n, rate, cap, lf)
+            leaves += [lf, lg, util]
+        return leaves + [_fluid_delay(prog, lpath, n, util, cap, dly), util.max(axis=1)]
+
+    cut = relax(n_walk)
+    full = relax(jnp.int32(prog.max_hops))
+    assert int(n_walk) == int(np.max(np.asarray(hops)))
+    # the line and the wandering flow run the walk's whole bound
+    assert (int(n_walk) == prog.max_hops) == (toy in ("line-at-max-hops", "unreachable-wanders"))
+    if toy.startswith("unreachable"):
+        assert not bool(reached[1])
+        assert int(hops[1]) == (prog.max_hops if toy == "unreachable-wanders" else 0)
+    lg = np.asarray(cut[3 * FP_ROUNDS - 2])
+    assert (lg < 0).any()                        # the gate gated
+    for a, b in zip(cut, full):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("toy", WALK_TOYS)
+def test_a_path_row_holds_links_for_its_hops_then_the_sentinel(toy):
+    prog = _walk_toys()[toy]
+    E2 = 2 * prog.edges.shape[0]
+    path, hops, _ = _walk_tables(prog)
+    path, hops = np.asarray(path), np.asarray(hops)
+    for row, n in zip(path, hops):
+        assert (row[:n] < E2).all() and (row[n:] == E2).all()
+
+
+def test_a_sweep_runs_the_walks_to_an_unbatched_bound():
+    """A three-point rate_scale sweep vmaps the relaxation over its configs; the
+    trip count is computed outside that vmap, so both hop walks stay plain
+    loops with a scalar counter and bound (a per-point bound would make them
+    masked loops that run to the slowest point)."""
+    from tpudes.analysis.jaxpr.trace import walk_eqns
+    from tpudes.parallel.as_flows import _as_carry, _as_replica_draws, build_as_run
+    from tpudes.parallel.programs import toy_as_program
+    from tpudes.parallel.runtime import stack_axis
+
+    prog, R, C = toy_as_program(n_nodes=40, n_flows=4, spf_rounds=12), 4, 3
+    carry = stack_axis((jnp.int32(0),) + _as_carry(prog, R), C)
+    args = (carry, _as_replica_draws(prog, jax.random.PRNGKey(5), R),
+            jnp.asarray([0.5, 1.0, 2.0], jnp.float32), jnp.int32(4))
+    jaxpr = jax.make_jaxpr(build_as_run(prog, R, n_cfg=C))(*args).jaxpr
+    walks = [e for e in walk_eqns(jaxpr) if e.primitive.name == "while" and not any(
+        q.primitive.name == "while" for q in e.params["body_jaxpr"].jaxpr.eqns)]
+    assert len(walks) == 2
+    for w in walks:
+        # the carry: (counter, bound, state...)
+        counter, bound = w.invars[w.params["cond_nconsts"] + w.params["body_nconsts"]:][:2]
+        for a in (counter.aval, bound.aval):
+            assert a.dtype == jnp.int32 and a.shape == (), a
+
+
+@pytest.mark.parametrize("rate_scale", [None, [0.5, 1.0, 2.0]], ids=["single", "sweep"])
+def test_the_unpack_span_counts_the_walks_hops(rate_scale):
+    from tpudes.obs import spans
+    from tpudes.parallel.programs import toy_as_program
+
+    prog = toy_as_program(n_nodes=40, n_flows=4, spf_rounds=12)
+    out = run_as_flows(prog, jax.random.PRNGKey(1), 8, rate_scale=rate_scale)
+    hops = (out if rate_scale is None else out[0])["hops"]
+    unpack = [s for s in spans.snapshot() if s.name == "result.unpack"][-1]
+    assert unpack.args["walk_hops"] == int(np.max(hops)) < prog.max_hops

@@ -353,7 +353,11 @@ SPARSE_SITES: tuple = (
         engine="as_flows", entry="*/run",
         primitive="dynamic_slice", mode="clip",
         provenance=("const", "operand"),
-        note="epoch window starts from the scan counter",
+        note="epoch window starts from the scan counter; the hop "
+             "walks' path column path[:, h] from the fori_loop "
+             "counter, rooted at the loop carry (operand) since the "
+             "walks stop at the traced longest path, h < max(hops) <= "
+             "max_hops",
     ),
     SparseSite(
         site="as_flows.relax_scatter",
@@ -389,7 +393,9 @@ SPARSE_SITES: tuple = (
         engine="diff", entry="*",
         primitive="dynamic_slice", mode="clip",
         provenance=("const", "operand"),
-        note="as_flows.epoch_window through the loss wrapper",
+        note="as_flows.epoch_window through the loss wrapper (the "
+             "walks there run a static max_hops: a fori_loop scan "
+             "whose counter rides the carry)",
     ),
     SparseSite(
         site="diff.as_loss_scatter",

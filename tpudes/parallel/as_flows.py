@@ -12,8 +12,9 @@ sparse CBR traffic × 1024 Monte-Carlo replicas — lowered TPU-first:
   all (F, H) link indices static across replicas.
 - **Replica axis = traffic uncertainty**: flow endpoints are fixed per
   run (RngRun-seeded, as upstream's RngRun sweeps); per-replica draws
-  scale each flow's offered rate.  Link loads accumulate by H
-  scatter-adds of the (R, F) rate matrix.
+  scale each flow's offered rate.  Link loads accumulate by
+  max(hops) scatter-adds of the (R, F) rate matrix (the longest path,
+  not the walk's bound H).
 - **Flow-level (fluid) outcome model**, the documented deviation from
   the packet oracle: per-link delivery min(1, capacity/load) compounds
   along the path; queueing delay is M/M/1 ρ/(1-ρ) per transited link.
@@ -346,7 +347,7 @@ def _as_carry(prog: AsFlowsProgram, r_pad: int):
     )
 
 
-def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap, lfrac_link):
+def _fluid_round(prog: AsFlowsProgram, path, n_walk, rate, cap, lfrac_link):
     """ONE fluid fixed-point round — the walk/load/delivery core shared
     by the while-loop runner (:func:`build_as_run`) and the
     differentiable scan runner (:func:`build_as_diff`), so the two can
@@ -357,25 +358,26 @@ def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap, lfrac_link):
     min-gate, bit-identical trace) smooths the per-link delivery clip
     ``min(1, cap/load)`` into a softplus gate in the log domain —
     straight-through (hard bit-exact forward) when ``surrogate.ste``.
+
+    The walk runs hops ``[0, n_walk)``: see :func:`_walk_trip`.
     """
     R, F = rate.shape
 
-    def walk(c, h):
+    def walk(h, c):
         lg, load = c
         e_h = path[:, h]                       # (F,)
         load = load.at[:, e_h].add(rate * jnp.exp(lg))
         lg = lg + lfrac_link[:, e_h]
-        return (lg, load), None
+        return lg, load
 
     # the hop walk's scatter-add: the relaxation's bulk, named apart
     # from the utilisation, log and gate below (they read under the
     # loop body's own scope)
     with jax.named_scope("tpudes.as_flows.load"):
-        (lg, load), _ = jax.lax.scan(
-            walk,
+        lg, load = jax.lax.fori_loop(
+            0, n_walk, walk,
             (jnp.zeros((R, F), jnp.float32),
              jnp.zeros((R, lfrac_link.shape[1]), jnp.float32)),
-            hs,
         )
     # a baked capacity divisor compiles to a multiply by its
     # reciprocal; the gathered one is written so, for the same bits
@@ -402,10 +404,10 @@ def _fluid_round(prog: AsFlowsProgram, path, hs, rate, cap, lfrac_link):
     return new_lfrac, lg, util
 
 
-def _fluid_delay(prog: AsFlowsProgram, path, hs, util, cap, dly):
+def _fluid_delay(prog: AsFlowsProgram, path, n_walk, util, cap, dly):
     """M/M/1 queue + serialization + propagation delay accumulated
-    along each flow's path from the settled utilizations (shared by
-    both runners, like :func:`_fluid_round`)."""
+    along each flow's path, hops ``[0, n_walk)``, from the settled
+    utilizations (shared by both runners, like :func:`_fluid_round`)."""
     R = util.shape[0]
     F = path.shape[0]
     with jax.named_scope("tpudes.as_flows.delay"):
@@ -416,13 +418,26 @@ def _fluid_delay(prog: AsFlowsProgram, path, hs, util, cap, dly):
         serial = (8.0 * prog.pkt_bytes / cap)[None, :]
         ldel = _fluid_seal(q_delay + serial + dly[None, :], cap)
 
-        def acc_hop(dl, h):
-            return dl + ldel[:, path[:, h]], None
+        def acc_hop(h, dl):
+            return dl + ldel[:, path[:, h]]
 
-        dl, _ = jax.lax.scan(
-            acc_hop, jnp.zeros((R, F), jnp.float32), hs
+        dl = jax.lax.fori_loop(
+            0, n_walk, acc_hop, jnp.zeros((R, F), jnp.float32)
         )
     return dl
+
+
+def _walk_trip(hops):
+    """The hop walks' trip count: the longest path, ``max(hops)``, an
+    int32 scalar.  A row of the path table is its flow's links for its
+    first ``hops`` entries and the done-hop sentinel after them (a walk
+    that is done stays done), so hop ``max(hops)`` and every later one
+    is the sentinel in every row: a hop that scatters into the
+    sentinel's column (capacity inf, utilisation ``load * 0``) and adds
+    its sealed +0.0 (:func:`_fluid_seal`) to ``lg`` and the delay sum,
+    which are never -0.0.  Stopping there changes no bit of any output
+    or carry leaf."""
+    return jnp.max(hops).astype(jnp.int32)
 
 
 #: carry layout of the relaxation, part of its checkpoint fingerprint:
@@ -437,6 +452,12 @@ _AS_R_LEAD = ("goodput_bps", "delay_s", "delivered_frac", "max_util")
 
 
 def _as_unpack(host: dict, replicas: int) -> dict:
+    from tpudes.obs import spans
+
+    unpack = spans.current()
+    if unpack is not None and unpack.name == "result.unpack":
+        # the iterations each hop walk ran (_walk_trip), of max_hops
+        unpack.args["walk_hops"] = int(np.max(host["hops"]))
     return {
         k: (np.asarray(v)[:replicas] if k in _AS_R_LEAD else np.asarray(v))
         for k, v in host.items()
@@ -526,13 +547,12 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
         [jnp.asarray(prog.delay_s), jnp.asarray(prog.delay_s)]
     ).astype(jnp.float32)
     fbps = jnp.asarray(prog.flow_bps, jnp.float32)
-    R, F, H = r_pad, len(prog.src), prog.max_hops
-    hs = jnp.arange(H, dtype=jnp.int32)
+    F = len(prog.src)
 
     def topo():
-        # Bellman-Ford, next hops, the path walk and the link table:
-        # replica-independent, outside the relaxation loop, under one
-        # device name
+        # Bellman-Ford, next hops, the path walk, the link table and the
+        # hop walks' trip count: replica-independent, outside the
+        # relaxation loop, under one device name
         with jax.named_scope("tpudes.as_flows.spf"):
             ddst, dist, nh_edge, nh_node = device_spf(prog, mesh)
             path, hops, arrived = _walk_paths(prog, ddst, nh_edge, nh_node)
@@ -540,9 +560,10 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
                 dist[ddst, jnp.asarray(prog.src)] < INF
             ) & arrived
             links = _link_table(prog, path, cap, dly)
-        return links, hops, reached
+            n_walk = _walk_trip(hops)
+        return links, n_walk, hops, reached
 
-    def relax(carry, z, scale, rounds_end, links, reached, mult):
+    def relax(carry, z, scale, rounds_end, links, n_walk, reached, mult):
         # per-replica offered rates: lognormal jitter around the
         # scale-multiplied nominal (z enters sharded over the
         # mesh's replica axis — every (R, ...) array downstream
@@ -558,14 +579,16 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
         # (shared with the differentiable runner, see _fluid_round)
         def body(c):
             i, lf, _, _ = c
-            lf2, lg2, util2 = _fluid_round(prog, path, hs, rate, cap_l, lf)
+            lf2, lg2, util2 = _fluid_round(
+                prog, path, n_walk, rate, cap_l, lf
+            )
             return i + 1, lf2, lg2, util2
 
         i, lfrac, lg, util = scoped_while_loop(
             "as_flows", lambda c: c[0] < rounds_end, body, carry
         )
 
-        dl = _fluid_delay(prog, path, hs, util, cap_l, dly_l)
+        dl = _fluid_delay(prog, path, n_walk, util, cap_l, dly_l)
         frac = jnp.where(reached[None, :], jnp.exp(lg), 0.0)
         outputs = dict(
             goodput_bps=rate * frac,
@@ -579,7 +602,7 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
         return (i, lfrac, lg, util), outputs, metrics
 
     def run(carry, z, scale, rounds_end, tr=None, horizon_us=None):
-        links, hops, reached = topo()
+        links, n_walk, hops, reached = topo()
         # the workload's fluid multiplier: realized/nominal offered
         # ratio over the traced horizon — config- and replica-
         # independent, computed once like the SPF tables
@@ -589,14 +612,15 @@ def build_as_run(prog: AsFlowsProgram, r_pad: int, n_cfg: int | None = None,
         )
         if n_cfg is None:
             carry, outputs, metrics = relax(
-                carry, z, scale, rounds_end, links, reached, mult
+                carry, z, scale, rounds_end, links, n_walk, reached, mult
             )
         else:
             # SPF + path walk are config-independent: computed once,
-            # closed over by the vmapped fixed point
+            # closed over by the vmapped fixed point (an unbatched trip
+            # count keeps the hop walks plain loops, not masked ones)
             carry, outputs, metrics = jax.vmap(
                 lambda c, s: relax(
-                    c, z, s, rounds_end, links, reached, mult
+                    c, z, s, rounds_end, links, n_walk, reached, mult
                 )
             )(carry, scale)
         outputs["hops"] = hops
@@ -617,6 +641,11 @@ def build_as_diff(prog: AsFlowsProgram, r_pad: int):
       cannot differentiate a ``while_loop``; the legacy runner runs
       exactly FP_ROUNDS rounds, so the forward values are BIT-EQUAL —
       pinned in tests/test_diff.py);
+    - the hop walks run a static ``prog.max_hops`` hops (a
+      ``fori_loop`` with static bounds is a ``scan``, which reverse
+      mode differentiates), where the while-loop runner stops at the
+      traced longest path (:func:`_walk_trip`; the hops past it add
+      +0.0, so the values are the same bits);
     - per-flow nominal rates (``fbps``) and per-edge link capacities
       (``cap_bps``) are lifted from build-time closures to TRACED
       OPERANDS, the runtime operands KPI losses differentiate w.r.t.;
@@ -639,7 +668,6 @@ def build_as_diff(prog: AsFlowsProgram, r_pad: int):
 
         mult_fn = avg_mult(prog.traffic)
     F = len(prog.src)
-    hs = jnp.arange(prog.max_hops, dtype=jnp.int32)
     dly = jnp.concatenate(
         [jnp.asarray(prog.delay_s), jnp.asarray(prog.delay_s)]
     ).astype(jnp.float32)
@@ -666,13 +694,15 @@ def build_as_diff(prog: AsFlowsProgram, r_pad: int):
         # stacked-ys slice would cost a ULP on the max reduction)
         def body(c, _):
             lf, _, _ = c
-            lf2, lg2, util2 = _fluid_round(prog, path, hs, rate, cap_l, lf)
+            lf2, lg2, util2 = _fluid_round(
+                prog, path, prog.max_hops, rate, cap_l, lf
+            )
             return (lf2, lg2, util2), None
 
         (_, lg, util), _ = jax.lax.scan(
             body, _as_carry(prog, r_pad), None, length=FP_ROUNDS
         )
-        dl = _fluid_delay(prog, path, hs, util, cap_l, dly_l)
+        dl = _fluid_delay(prog, path, prog.max_hops, util, cap_l, dly_l)
         frac = jnp.where(reached[None, :], jnp.exp(lg), 0.0)
         return dict(
             goodput_bps=rate * frac,
